@@ -44,13 +44,14 @@ from benchmarks.common import print_table, write_artifact  # noqa: E402
 from repro.checkpoint.manager import CheckpointManager  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.core import constant, mixed_optimizer  # noqa: E402
+from repro.launch.mesh import make_data_mesh  # noqa: E402
 from repro.models import init_params  # noqa: E402
 from repro.train.dp_step import init_dp_state, make_dp_train_step  # noqa: E402
 
 
 def bench_ckpt_stall(arch: str, batch: int, seq: int, iters: int):
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_data_mesh(n_dev)
     cfg = get_config(arch).reduced()
     params = init_params(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 0,

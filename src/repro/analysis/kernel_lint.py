@@ -3,15 +3,18 @@
 Kernels are linted at the *trace* level (``kernels/introspect.py``
 collects every ``pallas_call`` with its grid and block specs; nothing
 executes), over a representative sweep of bucket shapes — square, wide,
-tall, lane-unaligned d_out (the pad path) and a fan-in large enough to
-force ``pick_block_n`` to shrink.  Three checks per launch:
+tall, lane-unaligned d_out (the pad path), the widest fan-in a published
+GPT-2 block bucket has, and an embedding-sized fan-in that the routing
+rule sends to XLA.  Three checks per launch:
 
-* **vmem-budget** — the fp32 residency implied by the block specs
-  (blocks + scratch, 4 B/elt) must fit ``VMEM_BUDGET``; for the RMNP
-  stripe kernels the block shapes are additionally cross-checked against
-  ``pick_block_n``'s own stripe accounting (``_fits``), so the accounting
-  and the actual specs cannot drift apart again (the seed's shrink and
-  grow loops disagreed with each other).
+* **vmem-budget** — the launch's VMEM demand must fit its scoped limit.
+  For the RMNP stripe kernels the demand is the kernel's own accounting
+  (``rmnp_update.stripe_vmem_bytes``: blocks double-buffered at their
+  dtypes plus the body's fp32 temporaries) read off the launch's block
+  specs, and the plan recomputed from the launch's shapes must give the
+  block the launch actually uses (128-lane aligned), so the accounting
+  and the specs cannot drift apart.  Other kernels set no limit, so
+  their double-buffered blocks must fit Mosaic's default.
 * **grid-covers-array** — every non-SMEM operand's index map, evaluated
   over the grid, must tile the full array with no uncovered gap and no
   block starting fully out of bounds.
@@ -30,58 +33,61 @@ from repro.analysis.findings import Finding, Severity
 from repro.analysis.framework import AnalysisPass, register_pass
 
 # (L, d_in, d_out) stacked-bucket operand shapes the lint traces with:
-# square, MLP-wide, MLP-tall, lane-unaligned d_out (pad path), and a
-# fan-in big enough that pick_block_n must shrink below 128 lanes
+# square, MLP-wide, MLP-tall, lane-unaligned d_out (pad path), the
+# gpt2-large down-projection fan-in, and an embedding fan-in (XLA-routed)
 LINT_SHAPES: Tuple[Tuple[int, int, int], ...] = (
     (4, 768, 768),
     (2, 768, 3072),
     (2, 3072, 768),
     (3, 64, 80),
-    (1, 16384, 256),
+    (1, 5120, 256),
+    (1, 50432, 128),
 )
 
-# kernel-name fragment -> the stripe count pick_block_n budgets for it
-# (see kernels/rmnp_update._fits: 4 = g, v in + v_new, d out; 6 adds the
-# weight block in/out of fused apply plus the in-register d stripe)
-STRIPE_ACCOUNTING: Tuple[Tuple[str, int], ...] = (
-    ("_kernel3d_apply", 6),
-    ("_kernel3d", 4),
-)
+# Mosaic's scoped-VMEM limit on v5e for a launch that sets none
+DEFAULT_VMEM_LIMIT = 16 * 2**20
 
 
-def _stripes_for(name: str) -> Optional[int]:
-    for frag, stripes in STRIPE_ACCOUNTING:
-        if frag in name:
-            return stripes
-    return None
+def _temps_for(name: str) -> Optional[int]:
+    """Body temporaries the stripe accounting charges a kernel, by name."""
+    from repro.kernels import rmnp_update as rm
+
+    return {"rmnp_rownorm": rm.PRECOND_TEMPS,
+            "rmnp_rownorm_apply": rm.APPLY_TEMPS}.get(name)
 
 
 def _trace_targets():
-    """(label, thunk) pairs tracing each public kernel entry point over
-    the lint shapes.  Imports live here so the analysis package imports
-    without jax until a pass actually runs."""
+    """(label, thunk, xla_routed) triples tracing each public kernel entry
+    point over the lint shapes; ``xla_routed`` is the routing rule's own
+    verdict, so an XLA-routed shape is expected to launch nothing.
+    Imports live here so the analysis package imports without jax until a
+    pass actually runs."""
     import jax.numpy as jnp
 
     from repro.kernels import ops as kops
+    from repro.kernels import rmnp_update as rm
 
     targets = []
     for (ll, d_in, d_out) in LINT_SHAPES:
         g = jnp.zeros((ll, d_in, d_out), jnp.float32)
         targets.append((
             f"rmnp_bucket_update[{ll}x{d_in}x{d_out}]",
-            lambda g=g: kops.rmnp_bucket_update(g, g, beta=0.95)))
+            lambda g=g: kops.rmnp_bucket_update(g, g, beta=0.95),
+            rm.rownorm_plan(g, g) is None))
         targets.append((
             f"rmnp_bucket_update_apply[{ll}x{d_in}x{d_out}]",
             lambda g=g: kops.rmnp_bucket_update_apply(
-                g, g, g, 0.1, 0.1, beta=0.95)))
+                g, g, g, 0.1, 0.1, beta=0.95),
+            rm.rownorm_apply_plan(g, g, g) is None))
     for (ll, m, _n) in ((4, 256, 0), (2, 512, 0)):
         x = jnp.zeros((ll, m, m), jnp.float32)
         targets.append((
             f"ns_step[{ll}x{m}x{m}]",
-            lambda x=x: kops.ns_step(x, a=3.0, b=-4.0, c=1.2)))
+            lambda x=x: kops.ns_step(x, a=3.0, b=-4.0, c=1.2), False))
     a = jnp.zeros((256, 512), jnp.float32)
     b = jnp.zeros((512, 256), jnp.float32)
-    targets.append(("matmul[256x512x256]", lambda: kops.matmul(a, b)))
+    targets.append(("matmul[256x512x256]", lambda: kops.matmul(a, b),
+                    False))
     return targets
 
 
@@ -121,11 +127,12 @@ class KernelLintPass(AnalysisPass):
 
     def run(self, _artifacts=None) -> List[Finding]:
         from repro.kernels import introspect
-        from repro.kernels.rmnp_update import VMEM_BUDGET, _fits
+        from repro.kernels import rmnp_update as rm
 
         out: List[Finding] = []
         n_launches = 0
-        for label, thunk in _trace_targets():
+        targets = _trace_targets()
+        for label, thunk, xla_routed in targets:
             try:
                 launches = introspect.collect_kernel_launches(thunk)
             except Exception as e:  # trace failure is itself a finding
@@ -135,46 +142,62 @@ class KernelLintPass(AnalysisPass):
                     message=f"{label}: tracing raised {type(e).__name__}: "
                             f"{e}", location=label))
                 continue
+            if xla_routed:
+                code, sev = (("xla-routed", Severity.INFO) if not launches
+                             else ("routed-shape-launched", Severity.ERROR))
+                out.append(Finding(
+                    pass_name=self.name, severity=sev, code=code,
+                    message=f"{label}: the VMEM plan routes this shape to "
+                            f"XLA; {len(launches)} pallas_call traced",
+                    location=label))
+                continue
             if not launches:
                 out.append(Finding(
                     pass_name=self.name, severity=Severity.WARNING,
                     code="no-launches",
-                    message=f"{label}: no pallas_call traced (reference "
-                            f"fallback?) — kernel not linted",
+                    message=f"{label}: no pallas_call traced for a shape "
+                            f"the VMEM plan routes to the kernel",
                     location=label))
                 continue
             for launch in launches:
                 n_launches += 1
                 where = f"{label}/{launch.name}"
-                resident = launch.vmem_block_bytes(4)
-                if resident > VMEM_BUDGET:
+                temps = _temps_for(launch.name)
+                blocks3 = [b for b in launch.blocks
+                           if b.memspace != "smem"
+                           and len(b.block_shape) == 3]
+                if temps is not None and blocks3:
+                    d_in = blocks3[0].block_shape[-2] or 1
+                    bn = blocks3[0].block_shape[-1] or 1
+                    sizes = [np.dtype(b.dtype).itemsize for b in blocks3]
+                    need = rm.stripe_vmem_bytes(d_in, bn, sizes, temps)
+                    limit = rm.VMEM_LIMIT_CAP
+                    plan = rm.plan_stripes(d_in, blocks3[0].array_shape[-1],
+                                           sizes, temps)
+                    if plan is None or plan.block_n != bn \
+                            or bn % rm.LANE:
+                        out.append(Finding(
+                            pass_name=self.name,
+                            severity=Severity.ERROR,
+                            code="stripe-accounting-overrun",
+                            message=(f"{where}: block ({d_in}, {bn}) is "
+                                     f"not the {rm.LANE}-lane-aligned "
+                                     f"block plan_stripes gives this "
+                                     f"launch ({plan}) — the accounting "
+                                     f"and the launch spec disagree"),
+                            location=where))
+                else:
+                    need = launch.vmem_block_bytes()
+                    limit = DEFAULT_VMEM_LIMIT
+                if need > limit:
                     out.append(Finding(
                         pass_name=self.name, severity=Severity.ERROR,
                         code="vmem-over-budget",
-                        message=(f"{where}: block specs imply "
-                                 f"{resident / 2**20:.1f} MiB fp32 VMEM "
-                                 f"residency per program, over the "
-                                 f"{VMEM_BUDGET / 2**20:.0f} MiB budget"),
+                        message=(f"{where}: launch needs "
+                                 f"{need / 2**20:.1f} MiB of VMEM per "
+                                 f"program, over its "
+                                 f"{limit / 2**20:.0f} MiB limit"),
                         location=where))
-                stripes = _stripes_for(launch.name)
-                if stripes is not None:
-                    blocks3 = [b for b in launch.blocks
-                               if b.memspace != "smem"
-                               and len(b.block_shape) == 3]
-                    if blocks3:
-                        d_in = blocks3[0].block_shape[-2] or 1
-                        bn = blocks3[0].block_shape[-1] or 1
-                        if not _fits(d_in, bn, stripes):
-                            out.append(Finding(
-                                pass_name=self.name,
-                                severity=Severity.ERROR,
-                                code="stripe-accounting-overrun",
-                                message=(f"{where}: block ({d_in}, {bn}) "
-                                         f"fails _fits at the kernel's "
-                                         f"own stripe count {stripes} — "
-                                         f"pick_block_n accounting and "
-                                         f"the launch spec disagree"),
-                                location=where))
                 for blk in launch.blocks:
                     if blk.memspace == "smem":
                         continue
@@ -208,5 +231,5 @@ class KernelLintPass(AnalysisPass):
         out.append(Finding(
             pass_name=self.name, severity=Severity.INFO, code="summary",
             message=f"linted {n_launches} launches across "
-                    f"{len(_trace_targets())} trace targets"))
+                    f"{len(targets)} trace targets"))
         return out

@@ -77,6 +77,7 @@ def _fixture():
     import jax.numpy as jnp
 
     from repro.configs import get_config
+    from repro.launch.mesh import make_data_mesh
     from repro.models import init_params
     from repro.train.dp_step import init_dp_state
 
@@ -96,7 +97,7 @@ def _fixture():
     _FIXTURE.update(
         cfg=cfg, params=params, comp=comp,
         batch={"tokens": toks, "labels": toks},
-        mesh=jax.make_mesh((N_DEV,), ("data",)))
+        mesh=make_data_mesh(N_DEV))
     return _FIXTURE
 
 
@@ -155,7 +156,6 @@ def lower_combo(combo: Combo, *, break_mode: Optional[str] = None) -> Artifacts:
     base_step = make_dp_train_step(fx["cfg"], opt, fx["mesh"], **kwargs)
 
     if break_mode == "gather-momentum":
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.distributed.sharding import bucket_specs
@@ -175,8 +175,8 @@ def lower_combo(combo: Combo, *, break_mode: Optional[str] = None) -> Artifacts:
                     return jax.lax.all_gather(shard, "data", axis=0,
                                               tiled=True)
 
-                return shard_map(gather, mesh=fx["mesh"], in_specs=spec,
-                                 out_specs=P(), check_rep=False)(v)
+                return jax.shard_map(gather, mesh=fx["mesh"], in_specs=spec,
+                                     out_specs=P(), check_vma=False)(v)
 
             m = dict(m)
             m["_gathered_momentum_norm"] = sum(

@@ -588,6 +588,10 @@ class CheckpointManager:
                             f"leaf {leaf['path']!r} shard rank {rank} "
                             f"(stored {int(sh['crc32']):#010x}, recomputed "
                             f"{crc:#010x}) — bit-rot or torn write")
+                    if piece.dtype != out.dtype and piece.dtype.kind == "V":
+                        # npz keeps a dtype numpy does not know (bfloat16)
+                        # as raw bytes: reinterpret, never convert
+                        piece = piece.view(out.dtype)
                     idx = tuple(slice(a, b) for a, b in sh["index"])
                     out[idx] = piece
                 arrays.append(out)

@@ -109,17 +109,26 @@ def reshard_error(state: CompressionState, n_old: int,
 # quantizer
 # ---------------------------------------------------------------------------
 
+def _quantize_blocks(xb: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """fp32 ``(..., _BLOCK)`` -> (int8 ``(..., _BLOCK)``, fp32 scales
+    ``(...)``): one scale per trailing block."""
+    scale = jnp.max(jnp.abs(xb), axis=-1, keepdims=True) / 127.0
+    q = jnp.clip(jnp.round(xb / jnp.maximum(scale, 1e-30)), -127, 127)
+    return q.astype(jnp.int8), scale[..., 0]
+
+
+def _dequantize_blocks(q: jax.Array, scale: jax.Array) -> jax.Array:
+    return q.astype(jnp.float32) * scale[..., None]
+
+
 def quantize_blockwise(flat: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """fp32 (n,) with n % _BLOCK == 0 -> (int8 (n,), fp32 scales (n/_BLOCK,))."""
-    xb = flat.reshape(-1, _BLOCK)
-    scale = jnp.max(jnp.abs(xb), axis=1, keepdims=True) / 127.0
-    q = jnp.clip(jnp.round(xb / jnp.maximum(scale, 1e-30)), -127, 127)
-    return q.astype(jnp.int8).reshape(-1), scale[:, 0]
+    q, scale = _quantize_blocks(flat.reshape(-1, _BLOCK))
+    return q.reshape(-1), scale
 
 
 def dequantize_blockwise(q: jax.Array, scale: jax.Array) -> jax.Array:
-    xb = q.reshape(-1, _BLOCK).astype(jnp.float32) * scale[:, None]
-    return xb.reshape(-1)
+    return _dequantize_blocks(q.reshape(-1, _BLOCK), scale).reshape(-1)
 
 
 # ---------------------------------------------------------------------------
@@ -285,13 +294,22 @@ def compressed_reduce_scatter_leaf(v_chunks: jax.Array, axis_name: str,
     n = 1
     for s in cshape:
         n *= s
-    flat = v_chunks.astype(jnp.float32).reshape(n_dev, -1)
+    v = v_chunks.astype(jnp.float32)
     pad = (-n) % _BLOCK
     if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    q, scale = jax.vmap(quantize_blockwise)(flat)
-    deq = jax.vmap(dequantize_blockwise)(q, scale)
-    resid = (flat - deq)[:, :n].reshape(v_chunks.shape)
+        xb = jnp.pad(v.reshape(n_dev, n), ((0, 0), (0, pad)))
+        xb = xb.reshape(n_dev, -1, _BLOCK)
+    else:
+        # blocks straight from the chunked layout, never through a 1-D
+        # flatten: the TPU compiler lowers a tiled -> 1-D reshape piece by
+        # piece, so its compile time and host memory grow with the bucket
+        # (minutes and tens of GB for a gpt2-large FFN bucket)
+        xb = v.reshape(n_dev, n // _BLOCK, _BLOCK)
+    q, scale = _quantize_blocks(xb)
+    resid = xb - _dequantize_blocks(q, scale)
+    if pad:
+        resid = resid.reshape(n_dev, -1)[:, :n]
+    resid = resid.reshape(v_chunks.shape)
 
     if wire_fault is not None:
         q, scale = wire_fault(q, scale)
@@ -299,7 +317,8 @@ def compressed_reduce_scatter_leaf(v_chunks: jax.Array, axis_name: str,
                                 tiled=False)
     s_recv = jax.lax.all_to_all(scale, axis_name, split_axis=0,
                                 concat_axis=0, tiled=False)
-    chunk_sum = jnp.sum(jax.vmap(dequantize_blockwise)(q_recv, s_recv),
-                        axis=0)
-    mean_shard = chunk_sum[:n].reshape(cshape) / n_dev
+    chunk_sum = jnp.sum(_dequantize_blocks(q_recv, s_recv), axis=0)
+    if pad:
+        chunk_sum = chunk_sum.reshape(-1)[:n]
+    mean_shard = chunk_sum.reshape(cshape) / n_dev
     return mean_shard, resid

@@ -21,6 +21,7 @@ import itertools
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
+import numpy as np
 
 FULL_EVAL_LIMIT = 4096  # grid points; above this, sample corners only
 
@@ -46,11 +47,10 @@ class KernelLaunch(NamedTuple):
     def blocks(self) -> Tuple[BlockInfo, ...]:
         return self.in_blocks + self.out_blocks
 
-    def vmem_block_bytes(self, bytes_per_elt: int = 4) -> int:
-        """Resident block bytes per grid program at ``bytes_per_elt``
-        (default 4: the kernels' fp32 math dtype — the conservative
-        residency the ``pick_block_n`` accounting budgets for), VMEM
-        blocks plus scratch."""
+    def vmem_block_bytes(self) -> int:
+        """VMEM the launch's pipelined blocks hold per grid program: every
+        non-SMEM block double-buffered at its own dtype, plus scratch —
+        the block part of ``rmnp_update.stripe_vmem_bytes``."""
         total = 0
         for b in self.blocks:
             if b.memspace == "smem":
@@ -58,12 +58,12 @@ class KernelLaunch(NamedTuple):
             n = 1
             for d in b.block_shape:
                 n *= (d or 1)
-            total += n * bytes_per_elt
-        for shape, _dtype in self.scratch_shapes:
+            total += 2 * n * np.dtype(b.dtype).itemsize
+        for shape, dtype in self.scratch_shapes:
             n = 1
             for d in shape:
                 n *= d
-            total += n * bytes_per_elt
+            total += n * np.dtype(dtype).itemsize
         return total
 
 
@@ -76,14 +76,22 @@ def _memspace(block_aval) -> str:
     return "any"
 
 
+def _block_dim(d) -> Optional[int]:
+    # an int, None (squeezed), or a jax block-dim object (Blocked carries
+    # block_size; Squeezed has none)
+    if d is None or isinstance(d, int):
+        return d
+    return getattr(d, "block_size", None)
+
+
 def _block_info(bm, origin_fallback: str) -> BlockInfo:
-    sd = bm.array_shape_dtype
+    aval = bm.array_aval
     return BlockInfo(
         origin=str(getattr(bm, "origin", "") or origin_fallback),
-        block_shape=tuple(bm.block_shape),
-        array_shape=tuple(sd.shape),
-        dtype=str(sd.dtype),
-        memspace=_memspace(getattr(bm, "block_aval", "")),
+        block_shape=tuple(_block_dim(d) for d in bm.block_shape),
+        array_shape=tuple(aval.shape),
+        dtype=str(aval.dtype),
+        memspace=_memspace(bm.transformed_block_aval),
         index_map=bm.index_map_jaxpr)
 
 
@@ -100,8 +108,7 @@ def _from_eqn(eqn) -> KernelLaunch:
             aval = var.aval
             scratch.append((tuple(getattr(aval, "shape", ())),
                             str(getattr(aval, "dtype", ""))))
-    name_info = eqn.params.get("name_and_src_info")
-    name = getattr(name_info, "name", None) or str(name_info or "pallas_call")
+    name = eqn.params.get("name") or "pallas_call"
     return KernelLaunch(
         name=name, grid=tuple(gm.grid),
         in_blocks=tuple(infos[:n_in]),

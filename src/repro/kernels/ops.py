@@ -1,8 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run in interpret mode for correctness
-testing; on TPU they compile to Mosaic.  ``_interpret()`` picks automatically.
-Leading batch dims (layer stacks, expert stacks) are vmapped.
+Off the TPU the kernels run in interpret mode for correctness testing; on
+TPU they compile to Mosaic.  ``_interpret()`` picks automatically.
+Leading batch dims (layer stacks, expert stacks) are vmapped.  The RMNP
+entry points route a shape to the kernel or to the jnp reference by the
+kernel's own VMEM plan (``rmnp_update.plan_stripes``): embedding-sized
+fan-ins whose stripe cannot fit VMEM take the XLA path.
 """
 from __future__ import annotations
 
@@ -13,10 +16,6 @@ from repro.kernels import matmul as _mm
 from repro.kernels import newton_schulz as _ns
 from repro.kernels import rmnp_update as _rm
 
-# kernels fall back to the jnp reference above this fan-in (VMEM stripes
-# would degenerate) — embedding-sized matrices take the XLA path.
-_MAX_KERNEL_FAN_IN = 32768
-
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
@@ -25,7 +24,7 @@ def _interpret() -> bool:
 def rmnp_momentum_rownorm(g, v, *, beta: float, eps: float = 1e-8):
     """Fused momentum EMA + row (fan-in) l2 normalization.
     g, v: (..., d_in, d_out) fp32.  Returns (v_new, d)."""
-    if g.shape[-2] > _MAX_KERNEL_FAN_IN:
+    if _rm.rownorm_plan(g, v) is None:
         from repro.kernels.ref import rmnp_momentum_rownorm_ref
         return rmnp_momentum_rownorm_ref(g, v, beta=beta, eps=eps)
     return _rm.rmnp_momentum_rownorm_2d(g, v, beta=beta, eps=eps,
@@ -41,7 +40,7 @@ def rmnp_bucket_update(g, v, *, beta: float, eps: float = 1e-8):
     buffers are donated where it actually helps — at the train-step jit
     boundary (``donate_argnums`` on the outer step), where the old bucket's
     allocation is reused for the new one."""
-    if g.shape[-2] > _MAX_KERNEL_FAN_IN:
+    if _rm.rownorm_plan(g, v) is None:
         from repro.kernels.ref import rmnp_momentum_rownorm_ref
         return rmnp_momentum_rownorm_ref(g, v, beta=beta, eps=eps)
     return _rm.rmnp_momentum_rownorm_2d(g, v, beta=beta, eps=eps,
@@ -58,7 +57,7 @@ def rmnp_bucket_update_apply(g, v, w, scale, wd, *, beta: float,
     dtype; w: matching weights (math fp32, output in w.dtype); scale/wd are
     traced fp32 scalars (scale folds lr * rms_lr_scale).  Returns
     (v_new, w_new)."""
-    if g.shape[-2] > _MAX_KERNEL_FAN_IN:
+    if _rm.rownorm_apply_plan(g, v, w) is None:
         from repro.kernels.ref import rmnp_rownorm_apply_ref
         return rmnp_rownorm_apply_ref(g, v, w, scale, wd, beta=beta, eps=eps)
     scalars = jnp.stack([jnp.asarray(scale, jnp.float32),

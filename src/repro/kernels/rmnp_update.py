@@ -23,41 +23,91 @@ scalar (lr-scale, weight-decay) and emits the updated weights directly:
 so the fp32 ``d`` bucket is never materialized in HBM and the separate
 ``apply_updates`` tree pass disappears — the optimizer becomes a single
 memory pass over (g, v, w).
+
+Every launch is planned by :func:`plan_stripes`: its VMEM accounting sets
+the lane block (never below 128 lanes), the launch's scoped-VMEM limit,
+and whether a shape takes the kernel at all — a stripe that cannot fit
+at 128 lanes (an embedding-sized fan-in) takes the XLA path instead
+(``kernels/ops.py``).
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK_N = 128
-VMEM_BUDGET = 12 * 2**20  # bytes of fp32 VMEM we allow per operand set
+LANE = 128                    # Mosaic's lane tile: every block_n is a multiple
+MAX_BLOCK_N = 512             # widest lane block the grow phase goes to
+GROW_BUDGET = 16 * 2**20      # grow the block only while it needs at most this
+VMEM_LIMIT_CAP = 96 * 2**20   # most scoped VMEM a launch may ask for (v5e
+#                               has 128 MiB per core)
+
+# fp32 (d_in, block_n) temporaries the kernel bodies hold beyond their
+# pipelined blocks (the upcast loads and v_new; the apply body also d and
+# w_new) — measured against Mosaic's own VMEM demand in v5e compiles
+PRECOND_TEMPS = 1
+APPLY_TEMPS = 2
 
 
-def _fits(d_in: int, bn: int, stripes: int = 4) -> bool:
-    """Shared VMEM accounting for pick_block_n.  ``stripes`` counts the fp32
-    (d_in, bn) blocks each grid program holds: 4 for the precondition-only
-    kernel (inputs g, v and outputs v_new, d) and 6 for fused-apply (g, v, w
-    in; v_new, w_new out; plus the in-register d stripe).  The shrink and
-    grow phases must use this same accounting: the seed shrank against 3
-    stripes at 4 B/elt but grew against 8 B/elt, i.e. neither loop counted
-    the real residency."""
-    return stripes * d_in * bn * 4 <= VMEM_BUDGET
+class StripePlan(NamedTuple):
+    block_n: int
+    vmem_limit: int   # bytes, passed as the launch's vmem_limit_bytes
 
 
-def pick_block_n(d_in: int, n: int, stripes: int = 4) -> int:
-    """Largest lane-aligned block whose ``stripes`` fp32 stripes fit the
-    budget: shrink until the block fits, then grow while the *doubled* block
-    still fits (and divides d_out evenly, so growth never adds padding)."""
-    bn = DEFAULT_BLOCK_N
-    while bn > 8 and not _fits(d_in, bn, stripes):
-        bn //= 2
-    while bn * 2 <= 512 and _fits(d_in, bn * 2, stripes) and n % (bn * 2) == 0:
+def stripe_vmem_bytes(d_in: int, bn: int, itemsizes: Sequence[int],
+                      temps: int) -> int:
+    """VMEM one grid program of a column-stripe kernel holds: every
+    pipelined ``(d_in, bn)`` block (inputs and outputs, ``itemsizes`` in
+    bytes per element) double-buffered at its own dtype, plus ``temps``
+    fp32 body temporaries of the same shape."""
+    return d_in * bn * (2 * sum(itemsizes) + 4 * temps)
+
+
+def _vmem_limit(need: int) -> int:
+    # headroom for Mosaic's internal scratch and layout padding
+    return need + need // 4 + 2 * 2**20
+
+
+def plan_stripes(d_in: int, n: int, itemsizes: Sequence[int],
+                 temps: int) -> Optional[StripePlan]:
+    """The one rule that routes a ``(d_in, n)`` stripe kernel: ``None`` when
+    even a ``LANE``-wide block cannot fit ``VMEM_LIMIT_CAP`` (the caller
+    then takes the XLA path), else the widest lane-aligned block that stays
+    within ``GROW_BUDGET`` and divides ``n`` (growth never adds padding),
+    with the scoped-VMEM limit its launch needs."""
+    bn = LANE
+    if _vmem_limit(stripe_vmem_bytes(d_in, bn, itemsizes, temps)) > \
+            VMEM_LIMIT_CAP:
+        return None
+    while (bn * 2 <= MAX_BLOCK_N and n % (bn * 2) == 0
+           and stripe_vmem_bytes(d_in, bn * 2, itemsizes, temps)
+           <= GROW_BUDGET):
         bn *= 2
-    return max(8, bn)
+    return StripePlan(bn, _vmem_limit(
+        stripe_vmem_bytes(d_in, bn, itemsizes, temps)))
+
+
+def _itemsizes(*dtypes) -> tuple:
+    return tuple(jnp.dtype(d).itemsize for d in dtypes)
+
+
+def rownorm_plan(g, v) -> Optional[StripePlan]:
+    """Plan of the precondition-only kernel: g, v in; v_new, d out."""
+    return plan_stripes(g.shape[-2], g.shape[-1],
+                        _itemsizes(g.dtype, v.dtype, v.dtype, jnp.float32),
+                        PRECOND_TEMPS)
+
+
+def rownorm_apply_plan(g, v, w) -> Optional[StripePlan]:
+    """Plan of the fused-apply kernel: g, v, w in; v_new, w_new out."""
+    return plan_stripes(g.shape[-2], g.shape[-1],
+                        _itemsizes(g.dtype, v.dtype, w.dtype, v.dtype,
+                                   w.dtype),
+                        APPLY_TEMPS)
 
 
 def _kernel3d(g_ref, v_ref, v_out_ref, d_ref, *, beta: float, eps: float):
@@ -69,8 +119,9 @@ def _kernel3d(g_ref, v_ref, v_out_ref, d_ref, *, beta: float, eps: float):
     d_ref[0] = v_new / (norm + eps)
 
 
-def _stripe_call(kernel, operands, out_dtypes, *, block_n: int, stripes: int,
-                 interpret: bool, scalars=None):
+def _stripe_call(kernel, name: str, operands, out_dtypes,
+                 plan: Optional[StripePlan], *, interpret: bool,
+                 scalars=None):
     """Shared scaffolding for the column-stripe kernels: flatten leading
     dims (layer / expert stacks, bucket slices) into the outer grid axis,
     zero-pad d_out to the block, run one program per (l, stripe), slice the
@@ -79,11 +130,16 @@ def _stripe_call(kernel, operands, out_dtypes, *, block_n: int, stripes: int,
     norm is local garbage) and never escape the slice."""
     lead = operands[0].shape[:-2]
     d_in, n = operands[0].shape[-2:]
+    if plan is None:
+        raise ValueError(
+            f"a ({d_in}, {n}) stripe does not fit {VMEM_LIMIT_CAP >> 20} MiB "
+            f"of VMEM even at {LANE} lanes — route it to the XLA path "
+            f"(kernels/ops.py)")
     L = 1
     for s in lead:
         L *= s
     ops3 = [o.reshape(L, d_in, n) for o in operands]
-    bn = block_n or pick_block_n(d_in, n, stripes=stripes)
+    bn = plan.block_n
     pad = (-n) % bn
     if pad:
         ops3 = [jnp.pad(o, ((0, 0), (0, 0), (0, pad))) for o in ops3]
@@ -101,7 +157,10 @@ def _stripe_call(kernel, operands, out_dtypes, *, block_n: int, stripes: int,
         out_specs=[spec] * len(out_dtypes),
         out_shape=[jax.ShapeDtypeStruct((L, d_in, n_p), dt)
                    for dt in out_dtypes],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=plan.vmem_limit),
         interpret=interpret,
+        name=name,
     )(*ops3)
     if pad:
         outs = [o[:, :, :n] for o in outs]
@@ -109,13 +168,13 @@ def _stripe_call(kernel, operands, out_dtypes, *, block_n: int, stripes: int,
 
 
 def _rownorm_2d(g, v, *, beta: float, eps: float = 1e-8,
-                block_n: int = 0, interpret: bool = False):
+                interpret: bool = False):
     """g: (..., d_in, d_out) fp32; v: same shape, fp32 or bf16 momentum
     storage -> (v_new in v.dtype, d fp32)."""
     return _stripe_call(
-        functools.partial(_kernel3d, beta=beta, eps=eps),
-        [g, v], [v.dtype, jnp.float32],
-        block_n=block_n, stripes=4, interpret=interpret)
+        functools.partial(_kernel3d, beta=beta, eps=eps), "rmnp_rownorm",
+        [g, v], [v.dtype, jnp.float32], rownorm_plan(g, v),
+        interpret=interpret)
 
 
 # momentum donation happens at the *train-step* jit boundary
@@ -123,7 +182,7 @@ def _rownorm_2d(g, v, *, beta: float, eps: float = 1e-8,
 # jit would be dropped inside an outer jit, and the eager path pads d_out so
 # the buffers could not alias anyway
 rmnp_momentum_rownorm_2d = functools.partial(
-    jax.jit, static_argnames=("beta", "eps", "block_n", "interpret"))(_rownorm_2d)
+    jax.jit, static_argnames=("beta", "eps", "interpret"))(_rownorm_2d)
 
 
 def _kernel3d_apply(scal_ref, g_ref, v_ref, w_ref, v_out_ref, w_out_ref,
@@ -143,7 +202,7 @@ def _kernel3d_apply(scal_ref, g_ref, v_ref, w_ref, v_out_ref, w_out_ref,
 
 
 def _rownorm_apply_2d(g, v, w, scalars, *, beta: float, eps: float = 1e-8,
-                      block_n: int = 0, interpret: bool = False):
+                      interpret: bool = False):
     """Single-pass fused apply.  g: (..., d_in, d_out) fp32; v: momentum in
     its storage dtype (fp32 or bf16); w: weights (any float dtype, math in
     fp32, output in w.dtype); scalars: (2,) fp32 ``[scale, weight_decay]``
@@ -151,9 +210,9 @@ def _rownorm_apply_2d(g, v, w, scalars, *, beta: float, eps: float = 1e-8,
     no fp32 ``d`` buffer is ever written."""
     return _stripe_call(
         functools.partial(_kernel3d_apply, beta=beta, eps=eps),
-        [g, v, w], [v.dtype, w.dtype],
-        block_n=block_n, stripes=6, interpret=interpret, scalars=scalars)
+        "rmnp_rownorm_apply", [g, v, w], [v.dtype, w.dtype],
+        rownorm_apply_plan(g, v, w), interpret=interpret, scalars=scalars)
 
 
 rmnp_rownorm_apply_2d = functools.partial(
-    jax.jit, static_argnames=("beta", "eps", "block_n", "interpret"))(_rownorm_apply_2d)
+    jax.jit, static_argnames=("beta", "eps", "interpret"))(_rownorm_apply_2d)

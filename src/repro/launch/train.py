@@ -11,13 +11,14 @@ ratios).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import signal
 import time
 import warnings
 from pathlib import Path
-from typing import Optional
+from typing import Any, Dict, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -25,19 +26,35 @@ import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.core import (cosine_with_warmup, global_dominance, make_optimizer,
                         optimizer_names)
 from repro.core.types import tree_paths
 from repro.data.pipeline import make_stream
 from repro.distributed import elastic
 from repro.distributed.sharding import axis_rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import init_params
 from repro.train import faults
-from repro.train.step import make_train_step
+from repro.train.step import kernel_routes, make_train_step
 
 
-def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
+@dataclasses.dataclass
+class StepReport:
+    """What :func:`train` learns about its compiled step, for a caller that
+    passes one in: the compile time, the compiled HLO text (kernel custom
+    calls can be counted in it), the compiler's memory analysis (bytes per
+    device of the step program), and per shape bucket the RMNP kernel
+    launch it traces to (``None``: the bucket takes the XLA path)."""
+    compile_s: float = 0.0
+    hlo_text: str = ""
+    memory: Any = None
+    routes: Dict[str, Any] = dataclasses.field(default_factory=dict)
+
+
+def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
+          steps: int = 100,
           batch: int = 8, seq: int = 128, lr_matrix: float = 2e-3,
           lr_adamw: float = 1e-3, reduced: bool = True, seed: int = 0,
           ckpt_dir: str = "", ckpt_every: int = 0, log_every: int = 10,
@@ -52,7 +69,9 @@ def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
           inject_fault: str = "", anomaly_spike_k: float = 6.0,
           anomaly_skip_budget: int = 3, anomaly_rewind_budget: int = 2,
           anomaly_lr_backoff: float = 0.5, anomaly_health_window: int = 2,
-          anomaly_skip_batch: bool = False):
+          anomaly_skip_batch: bool = False,
+          devices: Optional[Sequence] = None,
+          report: Optional[StepReport] = None):
     """``stop_at`` simulates a crash: train to that step (schedules still
     span ``steps``) and exit WITHOUT the final checkpoint.  ``kill_at`` is
     harsher fault injection: SIGKILL the process mid-loop at that step —
@@ -104,12 +123,23 @@ def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
     NaN/Inf/wire-bit-flip fault for the resilience proofs; injected faults
     are disarmed on rewind (transient-fault model — the abort rung covers
     faults that keep firing).  ``clip_norm <= 0`` disables gradient
-    clipping (metrics keep reporting)."""
-    cfg = get_config(arch)
+    clipping (metrics keep reporting).
+
+    ``arch`` is a registered config name or a ``ModelConfig`` (such as a
+    registered one cut in depth).  ``devices`` are the devices the data
+    mesh spans (default: every visible device).  The step is compiled
+    ahead of time from the state's shapes, and the state is then created
+    directly in the shardings the compiled step expects.  ``report`` (a
+    :class:`StepReport`) receives the compile time, the compiled HLO, its
+    memory analysis and the kernel routing.  Every logged history entry carries ``step_s``,
+    the host time from dispatching that step to its outputs being ready
+    (a step's own time when every step is logged)."""
+    cfg = arch if isinstance(arch, ModelConfig) else get_config(arch)
     if reduced:
         cfg = cfg.reduced()
 
-    mesh = make_local_mesh(data=len(jax.devices()))
+    devices = list(devices) if devices is not None else jax.devices()
+    mesh = make_local_mesh(data=len(devices), devices=devices)
     n_dev = mesh.shape["data"]
     fault_spec = faults.parse_fault(inject_fault) if inject_fault else None
     if fault_spec is not None:
@@ -131,8 +161,23 @@ def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
 
     opt = build_opt(n_dev if zero2 else 1)
 
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    opt_state = opt.init(params)
+    from repro.train.dp_step import init_dp_state
+
+    def init_state(shardings=None):
+        """Fresh (params, opt_state[, comp_state]), built by one jitted
+        init straight into ``shardings`` (the compiled step's input
+        shardings) — nothing is first materialized whole on one device."""
+        def init(key):
+            p = init_params(cfg, key)
+            s = opt.init(p)
+            return (p, s, init_dp_state(p, n_dev)) if zero2 else (p, s)
+        return jax.jit(init, out_shardings=shardings)(
+            jax.random.PRNGKey(seed))
+
+    # abstract state: the step is compiled from shapes alone, and the real
+    # state is then created directly in the shardings the step expects
+    state_abs = jax.eval_shape(init_state)
+    params, opt_state = state_abs[0], state_abs[1]
     start_step, data_step = 0, 0
     layout = elastic.state_layout(opt, params, mesh_size=n_dev,
                                   rule=optimizer,
@@ -148,20 +193,29 @@ def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
             fn = make_dp_train_step(
                 cfg, opt_, mesh, shard_state=True, zero2=True,
                 compress=compress, accum=accum, overlap=overlap,
-                opt_state=opt_state, clip_norm=clip_norm, guard=guard,
+                opt_state=state_abs[1], clip_norm=clip_norm, guard=guard,
                 fault=fault, remat="none" if reduced else "full")
         else:
             fn = make_train_step(cfg, opt_, num_microbatches=accum,
                                  clip_norm=clip_norm, guard=guard,
                                  fault=fault,
                                  remat="none" if reduced else "full")
-        return jax.jit(fn, donate_argnums=(0, 1))
+        # the zero2 step's error-feedback state is donated as well: kept,
+        # it would live twice across every step (4 GB more per chip at
+        # gpt2-large), and any stale reference would pin a third copy
+        return jax.jit(fn, donate_argnums=(0, 1, 2) if zero2 else (0, 1))
 
-    if zero2:
-        from repro.train.dp_step import init_dp_state
-        comp_state = init_dp_state(params, n_dev)
-    else:
-        comp_state = None
+    def compile_step(jit_step_, args):
+        t_c = time.perf_counter()
+        with mesh, axis_rules(mesh):
+            compiled_ = jit_step_.lower(*args).compile()
+        compile_s = time.perf_counter() - t_c
+        print(f"[train] step compiled in {compile_s:.1f} s", flush=True)
+        if report is not None:
+            report.compile_s = compile_s
+            report.hlo_text = compiled_.as_text()
+            report.memory = compiled_.memory_analysis()
+        return compiled_
 
     if log_every and (fused or fused_apply or zero2 or use_kernel):
         from repro.train.step import optimizer_launches
@@ -169,6 +223,25 @@ def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
         detail = (f" ({len(opt_state.buckets)} shape buckets)"
                   if hasattr(opt_state, "buckets") else "")
         print(f"[train] preconditioner kernel launches/step: {n}{detail}")
+    if use_kernel and optimizer == "rmnp" and (fused or fused_apply or zero2):
+        routes = kernel_routes(opt, params)
+        if report is not None:
+            report.routes = routes
+        for key, launch in routes.items():
+            print(f"[train] bucket {key}: "
+                  + ("xla" if launch is None else
+                     f"kernel {launch.name} grid {launch.grid}"), flush=True)
+
+    jit_step = build_step(opt, fault_spec)
+    batch_abs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+                 for k, v in next(make_stream(cfg, seq, batch,
+                                              seed=seed)).items()}
+    compiled = compile_step(jit_step, tuple(state_abs) + (
+        batch_abs, jax.ShapeDtypeStruct((), jnp.int32)))
+    state_shardings = compiled.input_shardings[0][:len(state_abs)]
+    state = init_state(state_shardings)
+    params, opt_state = state[0], state[1]
+    comp_state = state[2] if zero2 else None
 
     mgr = CheckpointManager(ckpt_dir) if ckpt_dir else None
     latest = mgr.latest_step() if mgr is not None else None
@@ -198,9 +271,13 @@ def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
             else:
                 (params, opt_state), start_step, data_step = restored
             print(f"[train] resumed from step {start_step}")
+        # restored host arrays go onto the compiled step's shardings
+        state = jax.device_put((params, opt_state, comp_state) if zero2
+                               else (params, opt_state), state_shardings)
+        params, opt_state = state[0], state[1]
+        comp_state = state[2] if zero2 else None
 
     stream = make_stream(cfg, seq, batch, seed=seed, start_step=data_step)
-    jit_step = build_step(opt, fault_spec)
 
     hang_guard = None
     if watchdog_deadline:
@@ -242,11 +319,6 @@ def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
     lr_scale = 1.0
     pending_good: list = []    # (ckpt_step) awaiting the health window
     bad_data_steps: set = set()  # data positions of skipped steps (replay)
-    # the live state's shardings, captured after the first executed step: a
-    # rewind restore yields host arrays; device_put onto the captured
-    # shardings re-enters the live loop's executable instead of tracing a
-    # fresh uncommitted-input variant
-    state_shardings = None
 
     history = []
     t0 = time.time()
@@ -261,20 +333,18 @@ def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
                       f"data step {stream.step - 1}", flush=True)
             np_batch = next(stream)
             jbatch = {k: jnp.asarray(v) for k, v in np_batch.items()}
+            args = (((params, opt_state, comp_state) if zero2
+                     else (params, opt_state)) + (jbatch, jnp.int32(step)))
+            if compiled is None:
+                compiled = compile_step(jit_step, args)
             if hang_guard is not None:
                 hang_guard.arm()
                 t_step = time.time()
+            t_dispatch = time.perf_counter()
             if zero2:
-                params, opt_state, comp_state, metrics = jit_step(
-                    params, opt_state, comp_state, jbatch, jnp.int32(step))
+                params, opt_state, comp_state, metrics = compiled(*args)
             else:
-                params, opt_state, metrics = jit_step(
-                    params, opt_state, jbatch, jnp.int32(step))
-            if state_shardings is None:
-                state_shardings = jax.tree_util.tree_map(
-                    lambda x: x.sharding,
-                    (params, opt_state, comp_state) if zero2
-                    else (params, opt_state))
+                params, opt_state, metrics = compiled(*args)
             if hang_guard is not None:
                 # host snapshot into the manager's double buffer BEFORE
                 # recording: the emergency save must never read live
@@ -310,8 +380,9 @@ def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
                     if good is not None:
                         mgr.wait()
                         state, data_step = mgr.restore(good, state_template)
-                        if state_shardings is not None:
-                            state = jax.device_put(state, state_shardings)
+                        # a restore yields host arrays: put them back on
+                        # the step's own shardings
+                        state = jax.device_put(state, state_shardings)
                         if zero2:
                             # every rank's EF residual rides the sharded
                             # checkpoint (device-axis CompressionState), so
@@ -322,17 +393,15 @@ def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
                         rewind_to = good
                     else:
                         # no good checkpoint yet: restart from init
-                        params = init_params(cfg, jax.random.PRNGKey(seed))
-                        opt_state = opt.init(params)
-                        if zero2:
-                            from repro.train.dp_step import init_dp_state
-                            comp_state = init_dp_state(params, n_dev)
+                        state = init_state(state_shardings)
+                        params, opt_state = state[0], state[1]
+                        comp_state = state[2] if zero2 else None
                         rewind_to, data_step = 0, 0
                     if fault_spec is not None:
                         print("[train] rewind: disarming the injected "
                               "fault (transient-fault model)", flush=True)
                         fault_spec = None
-                    jit_step = build_step(opt, fault_spec)
+                    jit_step, compiled = build_step(opt, fault_spec), None
                     stream = make_stream(cfg, seq, batch, seed=seed,
                                          start_step=data_step)
                     print(f"[train] anomaly ladder: rewind #"
@@ -347,8 +416,11 @@ def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
                         f"exhausted at step {step}: "
                         f"{monitor.post_mortem()}")
             if log_every and (step % log_every == 0 or step == steps - 1):
+                jax.block_until_ready((params, metrics))
+                step_s = time.perf_counter() - t_dispatch
                 m = {k: float(v) for k, v in metrics.items()}
                 m["step"] = step
+                m["step_s"] = step_s
                 m["wall_s"] = round(time.time() - t0, 2)
                 if dominance_every and step % dominance_every == 0 and \
                         optimizer != "adamw":
@@ -358,7 +430,8 @@ def train(arch: str, optimizer: str = "rmnp", steps: int = 100,
                     m.update({k: float(v) for k, v in dom.items()})
                 history.append(m)
                 print(f"[train] step={step} loss={m['loss']:.4f} "
-                      f"gnorm={m['grad_norm']:.3f} clip={m['clip_rate']:.0f}"
+                      f"gnorm={m['grad_norm']:.3f} clip={m['clip_rate']:.0f} "
+                      f"step_s={step_s:.4f}"
                       + (f" r_avg={m['r_avg']:.2f}" if "r_avg" in m else ""),
                       flush=True)
             if mgr is not None and ckpt_every and (step + 1) % ckpt_every == 0:
@@ -517,6 +590,7 @@ def main():
                     help="on rewind replay, drop the batches that fed "
                          "guard-skipped steps (suspected data poisoning)")
     args = ap.parse_args()
+    enable_compile_cache()
     engine = args.engine
     if args.fused or args.fused_apply:
         alias = "--fused-apply" if args.fused_apply else "--fused"

@@ -56,7 +56,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -70,7 +69,7 @@ from repro.distributed.compression import (
     from_local as compression_from_local,
     local_view as compression_local_view,
 )
-from repro.distributed.sharding import bucket_specs
+from repro.distributed.sharding import axis_rules, bucket_specs
 from repro.train import faults, pipeline
 
 
@@ -267,15 +266,18 @@ def _wrap(local_step, mesh, axis_name, state_spec):
         # the global (n_dev, ...) array — host saves then carry every
         # rank's residual, making int8-wire restores bitwise
         comp_state = compression_local_view(comp_state)
-        params, opt_state, comp_state, metrics = local_step(
-            params, opt_state, comp_state, batch, step)
+        # the body is per-rank code inside the manual region: the model's
+        # logical sharding constraints must not name the mesh's axes there
+        with axis_rules(None):
+            params, opt_state, comp_state, metrics = local_step(
+                params, opt_state, comp_state, batch, step)
         return params, opt_state, compression_from_local(comp_state), metrics
 
-    return shard_map(
+    return jax.shard_map(
         sharded_step, mesh=mesh,
         in_specs=(rep, state_spec, comp_spec, batch_spec, rep),
         out_specs=(rep, state_spec, comp_spec, rep),
-        check_rep=False)
+        check_vma=False)
 
 
 def init_dp_state(params, n_dev: int = 1):

@@ -153,12 +153,11 @@ def wire_fault_for(spec: Optional[FaultSpec], bucket_key: str, step,
     def corrupt(q, scale):
         hit = jnp.logical_and(_hit(spec, step),
                               jax.lax.axis_index(axis_name) == 0)
-        flat = scale.reshape(-1)
-        s0 = flat[0]
+        first = (0,) * scale.ndim
+        s0 = scale[first]
         flipped = jax.lax.bitcast_convert_type(
             jax.lax.bitcast_convert_type(s0, jnp.uint32)
             ^ jnp.uint32(1 << 30), jnp.float32)
-        flat = flat.at[0].set(jnp.where(hit, flipped, s0))
-        return q, flat.reshape(scale.shape)
+        return q, scale.at[first].set(jnp.where(hit, flipped, s0))
 
     return corrupt

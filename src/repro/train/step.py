@@ -39,6 +39,32 @@ def optimizer_launches(opt: Optimizer, params, step: int = 0) -> int:
         fn, abstract(params), state, abstract(params), jnp.int32(step))
 
 
+def kernel_routes(opt: Optimizer, params, step: int = 0) -> dict:
+    """Per shape bucket of ``opt``'s plan, the RMNP stripe-kernel launch
+    its update traces to (``kernels.introspect.KernelLaunch``), or ``None``
+    where the kernel's VMEM plan sent the bucket to the XLA path.  Pure
+    tracing, like :func:`optimizer_launches`."""
+    from repro.kernels import introspect
+    from repro.kernels.rmnp_update import MAX_BLOCK_N
+
+    def abstract(t):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
+    state = jax.eval_shape(opt.init, params)
+    fn = opt.update_apply if opt.update_apply is not None else opt.update
+    launches = [ln for ln in introspect.collect_kernel_launches(
+        fn, abstract(params), state, abstract(params), jnp.int32(step))
+        if ln.name.startswith("rmnp_rownorm")]
+
+    def of_bucket(ln, b):
+        # stripe operands are (L, d_in, d_out padded to the lane block)
+        L, d_in, n_p = ln.in_blocks[-1].array_shape
+        return (L == b.padded and d_in == b.d_in
+                and b.d_out <= n_p < b.d_out + MAX_BLOCK_N)
+    return {b.key: next((ln for ln in launches if of_bucket(ln, b)), None)
+            for b in opt.bucket_plan(params).buckets}
+
+
 def optimizer_fp32_buffers(opt: Optimizer, params, shape,
                            step: int = 0) -> int:
     """Number of full-size fp32 buffers of exactly ``shape`` the optimizer

@@ -87,7 +87,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.core import bucketing, constant, mixed_optimizer  # noqa: E402
@@ -96,6 +96,7 @@ from repro.core.types import tree_paths  # noqa: E402
 from repro.distributed.compression import exact_reduce_scatter  # noqa: E402
 from repro.distributed.sharding import bucket_specs  # noqa: E402
 from repro.kernels.ops import count_buffer_eqns  # noqa: E402
+from repro.launch.mesh import make_data_mesh  # noqa: E402
 
 # synthetic tree: bucket 8x16 has L=8 (divisible by 4), bucket 8x24 has
 # L=3 (uneven AND < N), bucket 16x8 has L=6 (uneven, > N) -> padded
@@ -117,7 +118,7 @@ def make(seed, shapes=None):
 
 def synthetic_four_way():
     assert len(jax.devices()) >= 4, f"need 4 CPU devices, got {jax.devices()}"
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_data_mesh(4)
     params, grads = make(0), make(1)
     opt_sh = rmnp(constant(0.1), beta=0.9, shard_axis="data", shard_size=4)
     opt_rep = rmnp(constant(0.1), beta=0.9, fused_apply=True)
@@ -150,7 +151,7 @@ def synthetic_four_way():
     # ZeRO-1: full gradient operand, sharded (padded) momentum
     step_z1 = jax.jit(shard_map(
         lambda g, s, p: opt_sh.update_apply(g, s, p, 0), mesh=mesh,
-        in_specs=(P(), sspec, P()), out_specs=(P(), sspec), check_rep=False))
+        in_specs=(P(), sspec, P()), out_specs=(P(), sspec), check_vma=False))
     check("zero1", *step_z1(grads, state, params))
 
     # ZeRO-2: reduce-scatter the gradient buckets into the shard
@@ -163,7 +164,7 @@ def synthetic_four_way():
 
     step_z2 = jax.jit(shard_map(
         z2, mesh=mesh, in_specs=(P(), sspec, P()), out_specs=(P(), sspec),
-        check_rep=False))
+        check_vma=False))
     check("zero2", *step_z2(grads, state, params))
     print("synthetic 4-way: OK (zero1 + zero2 bitwise, uneven buckets "
           "padded+sharded)")
@@ -173,7 +174,7 @@ def synthetic_traced_buffers():
     """With bf16 params, any full-(padded L, d_in, d_out) fp32 equation is a
     gradient-path intermediate.  ZeRO-2 must have none — the mean-gradient
     bucket never exists per rank — while ZeRO-1 gathers it (>= 1)."""
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_data_mesh(4)
     opt_sh = rmnp(constant(0.1), beta=0.9, shard_axis="data", shard_size=4)
     params = jax.tree_util.tree_map(lambda p: p.astype(jnp.bfloat16), make(0))
     grads = make(1)
@@ -195,7 +196,7 @@ def synthetic_traced_buffers():
     plan = opt_sh.bucket_plan(params)
     for fn, name, expect_zero in ((z1, "zero1", False), (z2, "zero2", True)):
         step = shard_map(fn, mesh=mesh, in_specs=(P(), sspec, P()),
-                         out_specs=(P(), sspec), check_rep=False)
+                         out_specs=(P(), sspec), check_vma=False)
         for b in plan.buckets:
             # the shard_map eqn's own outvars are *global-view* avals of the
             # (physically sharded) outputs, not per-rank buffers — the walk
@@ -215,7 +216,7 @@ def dp_step_two_way():
     from repro.models import init_params
     from repro.train.dp_step import init_dp_state, make_dp_train_step
 
-    mesh = jax.make_mesh((2,), ("data",))
+    mesh = make_data_mesh(2)
     cfg = get_config("gpt2-60m").reduced()
     params = init_params(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab)
@@ -263,7 +264,7 @@ def dp_step_two_way_zero2():
     from repro.models import init_params
     from repro.train.dp_step import init_dp_state, make_dp_train_step
 
-    mesh = jax.make_mesh((2,), ("data",))
+    mesh = make_data_mesh(2)
     cfg = get_config("gpt2-60m").reduced()
     params = init_params(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab)
@@ -343,7 +344,7 @@ def dp_step_pipelined_four_way():
     from repro.models import init_params
     from repro.train.dp_step import init_dp_state, make_dp_train_step
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_data_mesh(4)
     cfg = get_config("gpt2-60m").reduced()
     params = init_params(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (16, 16), 0, cfg.vocab)
@@ -458,7 +459,7 @@ def rule_family_four_way():
     from repro.core.engine import matrix_optimizer
     from repro.core.rules import make_rule, per_leaf_reference, rule_names
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_data_mesh(4)
     params, grads0, grads1 = make(0), make(1), make(2)
     sizes = None
     for name in rule_names():
@@ -486,7 +487,7 @@ def rule_family_four_way():
 
         step_z2 = jax.jit(shard_map(
             z2, mesh=mesh, in_specs=(P(), sspec, P(), P()),
-            out_specs=(P(), sspec), check_rep=False))
+            out_specs=(P(), sspec), check_vma=False))
         p1, s1 = step_z2(grads0, state, params, jnp.int32(0))
         p2, s2 = step_z2(grads1, s1, p1, jnp.int32(1))
 
@@ -521,7 +522,7 @@ def rule_family_overlap_report():
     from repro.models import init_params
     from repro.train.dp_step import init_dp_state, make_dp_train_step
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_data_mesh(4)
     cfg = get_config("gpt2-60m").reduced()
     params = init_params(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (16, 16), 0, cfg.vocab)
@@ -557,7 +558,7 @@ def dp_step_shard_size_mismatch():
     from repro.models import init_params
     from repro.train.dp_step import make_dp_train_step
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_data_mesh(4)
     cfg = get_config("gpt2-60m").reduced()
     params = init_params(cfg, jax.random.PRNGKey(0))
     opt = mixed_optimizer("rmnp", constant(1e-2), constant(1e-2),
@@ -580,7 +581,7 @@ def two_phase_clip_bitwise():
     from repro.core.mixed import clip_by_global_norm
     from repro.train.pipeline import two_phase_clip
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_data_mesh(4)
     # bucket 8x16: 4 leaves of lead 2 -> padded 8, csize 2: each leaf is
     # exactly one rank's chunk.  Plus a couple of 1-D "rest" leaves.  Each
     # rank carries a *different* gradient tree (stacked along a leading
@@ -606,7 +607,7 @@ def two_phase_clip_bitwise():
 
     scale, gnorm, mean = jax.jit(shard_map(
         clipped, mesh=mesh, in_specs=(P("data"),),
-        out_specs=(P(), P(), P()), check_rep=False))(stacked)
+        out_specs=(P(), P(), P()), check_vma=False))(stacked)
     # replicated reference: clip_by_global_norm on the same mean gradient,
     # with a clip norm BELOW gnorm so the clip is active
     _, ref_stats = clip_by_global_norm(mean, 1.0)
@@ -663,7 +664,7 @@ def elastic_phase(args):
 
     n_dev = len(jax.devices())
     assert n_dev == args.devices, (n_dev, args.devices)
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_data_mesh(n_dev)
 
     def build_opt(n):
         return matrix_optimizer(make_rule(args.rule, beta=0.9, ns_steps=2),
@@ -722,7 +723,7 @@ def elastic_phase(args):
     step = jax.jit(shard_map(step_fn, mesh=mesh,
                              in_specs=(P(), sspec, P("data"), P(), P()),
                              out_specs=(P(), sspec, P("data")),
-                             check_rep=False))
+                             check_vma=False))
 
     for t in range(start, args.steps):
         g = _int_grads(t)
@@ -773,6 +774,8 @@ def _run_phase(phase_argv, n_dev, timeout=600):
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={n_dev}",
                JAX_PLATFORMS="cpu",
+               # the launcher's persistent compile cache stays off in tests
+               JAX_ENABLE_COMPILATION_CACHE="false",
                PYTHONPATH=os.pathsep.join(
                    [str(Path(__file__).resolve().parents[1] / "src"),
                     os.environ.get("PYTHONPATH", "")]).rstrip(os.pathsep))
@@ -885,7 +888,7 @@ def _ckpt_build(rule, n_dev=4):
         compressed_reduce_scatter_leaf, init_compression_state)
 
     assert len(jax.devices()) >= n_dev, jax.devices()
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_data_mesh(n_dev)
     opt = matrix_optimizer(make_rule(rule, beta=0.9, ns_steps=2),
                            constant(0.05), fused_apply=True,
                            shard_axis="data", shard_size=n_dev)
@@ -912,7 +915,7 @@ def _ckpt_build(rule, n_dev=4):
     step = jax.jit(shard_map(step_fn, mesh=mesh,
                              in_specs=(P("data"), sspec, P("data"), P(), P()),
                              out_specs=(P(), sspec, P("data")),
-                             check_rep=False))
+                             check_vma=False))
 
     def advance(st3, t):
         p, s, c = st3
@@ -1146,7 +1149,7 @@ def _guard_run(rule, compress, *, guard, fault, steps, accum=1,
     from repro.train.dp_step import init_dp_state, make_dp_train_step
     from repro.train import pipeline
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_data_mesh(4)
     cfg = get_config("gpt2-60m").reduced()
     params = init_params(cfg, jax.random.PRNGKey(0))
     opt = mixed_optimizer(rule, constant(1e-2), constant(1e-2),
@@ -1272,7 +1275,7 @@ def guard_overlap_report():
     from repro.models import init_params
     from repro.train.dp_step import init_dp_state, make_dp_train_step
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_data_mesh(4)
     cfg = get_config("gpt2-60m").reduced()
     params = init_params(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (16, 16), 0, cfg.vocab)
@@ -1303,6 +1306,8 @@ def _run_launch(extra, n_dev=4, timeout=900):
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={n_dev}",
                JAX_PLATFORMS="cpu",
+               # the launcher's persistent compile cache stays off in tests
+               JAX_ENABLE_COMPILATION_CACHE="false",
                PYTHONPATH=os.pathsep.join(
                    [str(Path(__file__).resolve().parents[1] / "src"),
                     os.environ.get("PYTHONPATH", "")]).rstrip(os.pathsep))

@@ -438,7 +438,11 @@ class TestKernelIntrospection:
                               for b in blocks)
         for b in blocks:
             assert introspect.block_coverage(ln, b)["covers"]
-        assert ln.vmem_block_bytes(4) > 0
+        # blocks double-buffered at their dtype: the block part of the
+        # kernel's own accounting (four fp32 (64, 256) blocks, no temps)
+        from repro.kernels.rmnp_update import stripe_vmem_bytes
+        assert ln.name == "rmnp_rownorm"
+        assert ln.vmem_block_bytes() == stripe_vmem_bytes(64, 256, [4] * 4, 0)
 
     def test_gappy_grid_detected(self):
         import jax.numpy as jnp

@@ -10,6 +10,7 @@ from repro.distributed.compression import (
     _BLOCK, CompressionState, compressed_mean, dequantize_blockwise,
     init_compression_state, quantize_blockwise,
 )
+from repro.launch.mesh import make_data_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -69,10 +70,10 @@ def test_compressed_mean_long_run_no_drift():
     accumulated compressed mean drifts from the exact mean by ~one bf16 ulp
     *per step* (linear in T); with it the tracking error stays bounded by
     the final error buffer — a few quantization steps, independent of T."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     rng = np.random.default_rng(7)
     # values with plenty of bf16-invisible mantissa bits
     g = {"w": jnp.asarray(rng.standard_normal(2 * _BLOCK) * 0.37 + 1.1,
@@ -81,7 +82,7 @@ def test_compressed_mean_long_run_no_drift():
 
     step = jax.jit(shard_map(
         lambda gg, s: compressed_mean(gg, s, "data", 1), mesh=mesh,
-        in_specs=(P(), P()), out_specs=(P(), P()), check_rep=False))
+        in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False))
 
     steps = 200
     total_sent = np.zeros(g["w"].shape, np.float64)
@@ -105,18 +106,18 @@ def test_compressed_reduce_scatter_matches_mean_shard():
     must equal the corresponding chunk of the compressed mean (identical
     quantizer, no bf16 gather stage -> *exactly* the local fp32 sum), and
     the residual must reconstruct v - deq."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.compression import compressed_reduce_scatter_leaf
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     rng = np.random.default_rng(3)
     v = jnp.asarray(rng.standard_normal((1, 3, 8, 16)), jnp.float32)
 
     out, resid = jax.jit(shard_map(
         lambda x: compressed_reduce_scatter_leaf(x, "data", 1), mesh=mesh,
-        in_specs=(P(),), out_specs=(P(), P()), check_rep=False))(v)
+        in_specs=(P(),), out_specs=(P(), P()), check_vma=False))(v)
     assert out.shape == v.shape[1:]
     q, s = quantize_blockwise(
         jnp.pad(v.reshape(-1), (0, (-v.size) % _BLOCK)))
@@ -128,10 +129,10 @@ def test_compressed_reduce_scatter_matches_mean_shard():
 
 
 def test_compressed_mean_skip_leaves_untouched():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     rng = np.random.default_rng(11)
     grads = {"mat/w": jnp.asarray(rng.standard_normal(_BLOCK), jnp.float32),
              "norm": jnp.asarray(rng.standard_normal(_BLOCK), jnp.float32)}
@@ -140,7 +141,7 @@ def test_compressed_mean_skip_leaves_untouched():
         lambda g, s: compressed_mean(g, s, "data", 1,
                                      skip=lambda p: p.startswith("mat")),
         mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-        check_rep=False))(grads, state)
+        check_vma=False))(grads, state)
     # skipped leaf: passed through bit-identically, error untouched
     np.testing.assert_array_equal(np.asarray(out["mat/w"]),
                                   np.asarray(grads["mat/w"]))
@@ -150,10 +151,10 @@ def test_compressed_mean_skip_leaves_untouched():
 
 
 def test_compressed_mean_close_to_exact():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     grads = {"w": jnp.asarray(
         np.random.default_rng(1).standard_normal((64, 48)), jnp.float32)}
     state = init_compression_state(grads)
@@ -163,7 +164,7 @@ def test_compressed_mean_close_to_exact():
 
     out, new_state = shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-        check_rep=False)(grads, state)
+        check_vma=False)(grads, state)
     err = np.asarray(out["w"] - grads["w"])
     # bf16 gather + int8 quantization: relative error small but nonzero
     assert np.abs(err).max() < 0.05 * np.abs(np.asarray(grads["w"])).max()
@@ -179,7 +180,7 @@ def test_dp_step_trains(tmp_path):
     from repro.train.dp_step import init_dp_state, make_dp_train_step
 
     cfg = get_config("llama-60m").reduced()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     opt = mixed_optimizer("rmnp", cosine_with_warmup(1e-2, 60),
                           cosine_with_warmup(3e-3, 60))
     losses = {}

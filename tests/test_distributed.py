@@ -11,6 +11,7 @@ import pytest
 
 from repro.distributed.elastic import reshard
 from repro.distributed.monitor import StepTimeMonitor, Watchdog
+from repro.launch.mesh import make_data_mesh
 
 
 class TestStepTimeMonitor:
@@ -56,7 +57,7 @@ class TestWatchdog:
 
 class TestElastic:
     def test_reshard_roundtrip_values(self):
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         tree = {"a": jnp.arange(12.0).reshape(3, 4),
                 "b": {"c": jnp.ones((5,))}}
         out = reshard(tree, mesh)

@@ -36,6 +36,7 @@ import pytest
 from repro.core import apply_updates, constant, mixed_optimizer
 from repro.core.bucketing import build_plan
 from repro.core.rmnp import rmnp
+from repro.launch.mesh import make_data_mesh
 from repro.train.step import optimizer_fp32_buffers, optimizer_launches
 
 RAGGED_SHAPES = {
@@ -264,7 +265,7 @@ class TestZeroSharding:
         from repro.configs import get_config
         from repro.train.dp_step import make_dp_train_step
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         cfg = get_config("gpt2-60m").reduced()
         two_pass = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
                                    fused=True)
@@ -275,7 +276,7 @@ class TestZeroSharding:
         from repro.configs import get_config
         from repro.train.dp_step import make_dp_train_step
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         cfg = get_config("gpt2-60m").reduced()
         opt = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
                               fused_apply=True, shard_axis="data")
@@ -289,7 +290,7 @@ class TestZeroSharding:
         from repro.configs import get_config
         from repro.train.dp_step import make_dp_train_step
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         cfg = get_config("gpt2-60m").reduced()
         opt = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
                               fused_apply=True)
@@ -304,7 +305,7 @@ class TestZeroSharding:
         (under momentum/nu) must stay replicated, not get a ZeRO spec."""
         from repro.distributed.sharding import bucket_specs
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         shapes = dict(RAGGED_SHAPES)
         params = make_tree(shapes)
         # 'conv' token routes this 3-D leaf to AdamW (full-shape mu/nu)
@@ -323,7 +324,7 @@ class TestZeroSharding:
     def test_bucket_specs_uneven_replicates(self):
         from repro.distributed.sharding import bucket_specs
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         opt = rmnp(constant(0.1), fused_apply=True)
         state = opt.init(make_tree(RAGGED_SHAPES))
         specs = bucket_specs(state, mesh)
@@ -528,6 +529,23 @@ class TestTrainStepDispatch:
                                    seq=16, fused_apply=True, log_every=2)
         assert hasattr(opt_state, "buckets") and opt_state.buckets
         assert all(np.isfinite(h["loss"]) for h in hist)
+
+    @pytest.mark.parametrize("zero2", [False, True],
+                             ids=["replicated", "zero2"])
+    def test_kernel_train_through_launcher(self, zero2):
+        """The launcher's own mesh and the Pallas kernel together: every
+        bucket takes the kernel, and both the replicated and the ZeRO-2
+        (shard_map) steps train."""
+        from repro.launch.train import StepReport, train
+
+        report = StepReport()
+        _, _, hist = train("gpt2-60m", "rmnp", steps=2, batch=2, seq=16,
+                           use_kernel=True, fused_apply=True, zero2=zero2,
+                           compress=False, log_every=1, report=report)
+        assert len(hist) == 2
+        assert all(np.isfinite(h["loss"]) for h in hist)
+        assert report.routes and all(ln is not None
+                                     for ln in report.routes.values())
 
     def test_pjit_step_uses_update_apply(self):
         """make_train_step must route through update_apply when present:

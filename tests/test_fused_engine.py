@@ -8,7 +8,8 @@ Invariants under test:
     padding remainders, and leading axes;
   * kernel launches per optimizer step equal the number of shape buckets
     (fused) vs the number of matrix leaves (per-leaf);
-  * pick_block_n's grow/shrink phases use one consistent VMEM accounting.
+  * plan_stripes' one VMEM accounting picks a lane block of at least 128
+    lanes, the launch's VMEM limit, and the kernel-or-XLA routing.
 """
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,9 @@ from _hypothesis_support import given, settings, st
 from repro.core import apply_updates, constant, mixed_optimizer
 from repro.core.bucketing import build_plan, gather, init_buckets, scatter
 from repro.core.rmnp import rmnp
-from repro.kernels.rmnp_update import VMEM_BUDGET, pick_block_n
+from repro.kernels.rmnp_update import (APPLY_TEMPS, LANE, MAX_BLOCK_N,
+                                       PRECOND_TEMPS, VMEM_LIMIT_CAP,
+                                       plan_stripes, stripe_vmem_bytes)
 from repro.train.step import optimizer_launches
 
 # ragged mix: two shared buckets (8x16 with a scan stack, 16x8) + a loner,
@@ -242,52 +245,84 @@ class TestLaunchCounts:
 
 
 class TestPickBlockN:
-    """The grow and shrink phases must share one VMEM accounting that counts
-    the real residency — 4 fp32 blocks (g, v, v_new, d) per program (the
-    seed shrank against 3 stripes at 4 B/elt but grew against 8 B/elt)."""
+    """One VMEM accounting plans every stripe launch (``plan_stripes``):
+    each pipelined block double-buffered at its own dtype plus the body's
+    fp32 temporaries.  Blocks never drop below 128 lanes; a stripe that
+    cannot fit the scoped-VMEM cap even at 128 lanes is routed to XLA."""
 
-    def _fits(self, d_in, bn):
-        return 4 * d_in * bn * 4 <= VMEM_BUDGET
+    PRECOND = ([4, 4, 4, 4], PRECOND_TEMPS)      # g, v in; v_new, d out
+    APPLY = ([4, 4, 4, 4, 4], APPLY_TEMPS)       # g, v, w in; v_new, w_new
+
+    def _plan(self, d_in, n, kind=PRECOND):
+        return plan_stripes(d_in, n, *kind)
 
     @pytest.mark.parametrize("d_in,n", [(8, 8), (64, 1024), (64, 1600),
                                         (1024, 4096), (8192, 512),
                                         (32768, 128), (300, 257)])
     def test_block_within_budget_and_aligned(self, d_in, n):
-        bn = pick_block_n(d_in, n)
-        assert bn >= 8 and (bn & (bn - 1)) == 0        # power-of-two lanes
-        assert self._fits(d_in, bn) or bn == 8
+        plan = self._plan(d_in, n)
+        need128 = stripe_vmem_bytes(d_in, LANE, *self.PRECOND)
+        if plan is None:
+            # routed to XLA only when even the 128-lane floor overflows
+            assert need128 > VMEM_LIMIT_CAP * 3 // 4
+            return
+        bn = plan.block_n
+        assert bn % LANE == 0 and bn <= MAX_BLOCK_N
+        assert (bn // LANE) & (bn // LANE - 1) == 0     # 128 * 2^k lanes
+        need = stripe_vmem_bytes(d_in, bn, *self.PRECOND)
+        assert need < plan.vmem_limit <= VMEM_LIMIT_CAP
 
     def test_grow_fires_when_budget_allows(self):
         # small fan-in, evenly divisible d_out: the doubled block fits the
-        # budget, so the grow phase must take it all the way to the 512 cap
-        assert pick_block_n(64, 1024) == 512
+        # grow budget, so the grow phase must take it all the way to 512
+        assert self._plan(64, 1024).block_n == 512
 
     def test_grow_respects_divisibility(self):
         # 1600 = 128 * 12.5: growth to 256 would add padding, so stay at 128
-        assert pick_block_n(64, 1600) == 128
+        assert self._plan(64, 1600).block_n == 128
 
     def test_shrink_respects_budget(self):
-        bn = pick_block_n(32768, 4096)
-        assert self._fits(32768, bn)
-        assert bn < 128
+        """No shrink below the 128-lane floor: the gpt2-large down-projection
+        fan-in keeps a 128-lane kernel block, while an embedding fan-in is
+        routed to XLA by the same plan (no pallas_call traced)."""
+        from repro.kernels import ops
+        from repro.kernels.ops import count_pallas_calls
+
+        assert self._plan(5120, 1280, self.APPLY).block_n == LANE
+        assert self._plan(50432, 1280, self.APPLY) is None
+        for d_in, launches in ((5120, 1), (50432, 0)):
+            g = jax.ShapeDtypeStruct((1, d_in, 128), jnp.float32)
+            n = count_pallas_calls(
+                lambda g, v, w: ops.rmnp_bucket_update_apply(
+                    g, v, w, 0.1, 0.0, beta=0.9), g, g, g)
+            assert n == launches, (d_in, n)
 
     @pytest.mark.parametrize("d_in,n", [(64, 1024), (1024, 4096),
                                         (8192, 512), (32768, 4096)])
     def test_stripe_count_parameterizes_budget(self, d_in, n):
-        """The fused-apply kernel holds 6 fp32 stripes (g, v, w in; v_new,
-        w_new out; d in-register) vs the precondition-only kernel's 4, so
-        its blocks can only be smaller-or-equal at the same budget."""
-        bn4 = pick_block_n(d_in, n, stripes=4)
-        bn6 = pick_block_n(d_in, n, stripes=6)
-        assert bn6 <= bn4
-        assert 6 * d_in * bn6 * 4 <= VMEM_BUDGET or bn6 == 8
+        """The fused-apply kernel holds more VMEM per lane (a third input
+        and output block, one more fp32 temporary) than the precondition-
+        only kernel, so its block is never wider and it is routed to XLA
+        no later."""
+        pre = self._plan(d_in, n, self.PRECOND)
+        app = self._plan(d_in, n, self.APPLY)
+        if pre is None:
+            assert app is None
+        elif app is not None:
+            assert app.block_n <= pre.block_n
+            assert app.vmem_limit >= stripe_vmem_bytes(d_in, app.block_n,
+                                                       *self.APPLY)
 
     def test_stripe_budget_shrinks_block(self):
-        # d_in * bn budget is 786432 elements at 4 stripes, 524288 at 6:
-        # 12288-fan-in fits a 64-wide block under 4 stripes but needs 32
-        # under 6 — the apply kernel's extra residency must shrink blocks
-        assert pick_block_n(12288, 4096, stripes=4) == 64
-        assert pick_block_n(12288, 4096, stripes=6) == 32
+        # at a 14336 fan-in the precondition-only stripe still fits the
+        # cap at 128 lanes but the apply kernel's extra residency does not:
+        # the routing rule sends the apply launch to XLA first
+        assert self._plan(14336, 4096, self.PRECOND).block_n == LANE
+        assert self._plan(14336, 4096, self.APPLY) is None
+        # momentum stored in bf16 halves its blocks, so the same fan-in
+        # fits the apply kernel again
+        assert plan_stripes(14336, 4096, [4, 2, 4, 2, 4],
+                            APPLY_TEMPS).block_n == LANE
 
 
 class TestDominanceParity:

@@ -262,7 +262,7 @@ def test_overlap_report_on_real_sharded_update():
     """Compiled single-device shard_map program: the per-bucket chains of
     update_apply_sharded produce update gathers for every bucket and no
     serialization edges."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import constant
@@ -270,8 +270,9 @@ def test_overlap_report_on_real_sharded_update():
     from repro.core.rmnp import rmnp
     from repro.distributed.compression import exact_reduce_scatter
     from repro.launch.hlo_cost import collective_overlap_report
+    from repro.launch.mesh import make_data_mesh
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     opt = rmnp(constant(0.1), beta=0.9, shard_axis="data", shard_size=1)
     params = {"a/w": jnp.ones((4, 8, 16), jnp.float32),
               "b/w": jnp.ones((2, 8, 24), jnp.float32)}
@@ -286,7 +287,7 @@ def test_overlap_report_on_real_sharded_update():
         return opt.update_apply_sharded(shards, g, s, p, 0)
 
     fn = shard_map(step, mesh=mesh, in_specs=(P(), P(), P()),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     hlo = jax.jit(fn).lower(grads, state, params).compile().as_text()
     r = collective_overlap_report(
         hlo, [(b.key, b.d_in, b.d_out) for b in plan.buckets])

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
@@ -16,6 +16,7 @@ from repro.core.bucketing import (
     accumulate_chunks, build_plan, gather_chunks, init_chunk_acc,
 )
 from repro.core.types import tree_paths
+from repro.launch.mesh import make_data_mesh
 from repro.models import init_params
 from repro.train.dp_step import init_dp_state, make_dp_train_step
 
@@ -117,7 +118,7 @@ class TestTwoPhaseClip:
         from repro.distributed.compression import exact_reduce_scatter
         from repro.train.pipeline import two_phase_clip
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         shapes = {"a/w": (2, 8, 16), "b/w": (8, 16), "c/w": (3, 8, 24)}
         grads = _tree(shapes, seed=2)
         grads["norm_1d"] = jax.random.normal(jax.random.PRNGKey(7), (11,))
@@ -135,7 +136,7 @@ class TestTwoPhaseClip:
 
         scale, gnorm, ok, flags = jax.jit(shard_map(
             run, mesh=mesh, in_specs=(P(),), out_specs=(P(), P(), P(), P()),
-            check_rep=False))(grads)
+            check_vma=False))(grads)
         assert bool(ok) and bool(np.all(np.asarray(flags)))
         assert flags.shape == (len(grads),)  # one finite flag per leaf
         _, ref = clip_by_global_norm(grads, 1.0)
@@ -158,7 +159,7 @@ class TestDpStepPipelined:
         toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                   cfg.vocab)
         batch = {"tokens": toks, "labels": toks}
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         opt = mixed_optimizer("rmnp", constant(1e-2), constant(1e-2),
                               shard_axis="data", shard_size=1)
         return cfg, params, batch, mesh, opt
@@ -220,7 +221,7 @@ class TestUpdateApplyBucketContract:
         from repro.core.rmnp import rmnp
         from repro.distributed.compression import exact_reduce_scatter
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         opt = rmnp(constant(0.1), beta=0.9, shard_axis="data", shard_size=1)
         shapes = {"a/w": (2, 8, 16), "b/w": (8, 16), "c/w": (3, 8, 24)}
         params = _tree(shapes, seed=0)
@@ -251,7 +252,7 @@ class TestUpdateApplyBucketContract:
         def run(fn):
             return jax.jit(shard_map(
                 fn, mesh=mesh, in_specs=(P(), P(), P()), out_specs=(P(), P()),
-                check_rep=False))(grads, state, params)
+                check_vma=False))(grads, state, params)
         p_ref, s_ref = run(via_sharded)
         p_bkt, v_bkt = run(via_bucket)
         for k in p_ref:

@@ -87,14 +87,19 @@ class TestDataPipeline:
 
 
 class TestCheckpoint:
-    def test_roundtrip(self, tmp_path):
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_roundtrip(self, tmp_path, dtype):
+        # npz stores bfloat16 (a dtype numpy does not know) as raw bytes;
+        # restore must give it back as bfloat16, bit for bit
         mgr = CheckpointManager(str(tmp_path), async_save=False)
-        state = {"w": jnp.arange(12.0).reshape(3, 4), "n": jnp.ones((2,))}
+        state = {"w": (jnp.arange(12.0).reshape(3, 4) / 7).astype(dtype),
+                 "n": jnp.ones((2,))}
         mgr.save(7, state, data_step=70)
         out = mgr.restore_latest(state)
         assert out is not None
         restored, step, data_step = out
         assert step == 7 and data_step == 70
+        assert restored["w"].dtype == state["w"].dtype
         np.testing.assert_array_equal(np.array(restored["w"]), np.array(state["w"]))
 
     def test_uncommitted_ignored(self, tmp_path):

@@ -185,6 +185,7 @@ def _bucket_parts(bucket: Bucket, by_path, dtype=None):
     return parts, pad_dtype
 
 
+@jax.named_scope("gather")
 def gather(plan: BucketPlan, tree: PyTree, dtype=None) -> Dict[str, jax.Array]:
     """Stack the planned leaves of ``tree`` into per-bucket operands.  Pad
     slices (``padded_size > size``) are zero-filled — mathematically inert
@@ -200,6 +201,7 @@ def gather(plan: BucketPlan, tree: PyTree, dtype=None) -> Dict[str, jax.Array]:
     return out
 
 
+@jax.named_scope("gather")
 def gather_chunks(plan: BucketPlan, tree: PyTree, n_chunks: int,
                   dtype=None) -> Dict[str, jax.Array]:
     """Stack the planned leaves of ``tree`` into ``(n_chunks, padded_L /
@@ -303,6 +305,7 @@ def scatter_chunks(plan: BucketPlan, chunks: Dict[str, jax.Array],
     return map_with_path(visit, base)
 
 
+@jax.named_scope("scatter")
 def scatter(plan: BucketPlan, stacked: Dict[str, jax.Array],
             base: PyTree, cast: bool = False) -> PyTree:
     """Inverse of :func:`gather`: slice each bucket back into the planned

@@ -138,17 +138,18 @@ class BucketedEngine:
         slots_b: Dict[str, Dict[str, jax.Array]] = {n: {} for n in slots}
         for b in plan.buckets:
             sl = self._slots_of(slots, b.key)
-            scale = self.scale(b, step)
-            if self.rule.additive:
-                d, v_new, sl_new = self.rule.precondition(
-                    g_b[b.key], buckets[b.key], sl, step=step,
-                    use_kernel=self.use_kernel)
-                upd = -scale * (d + self.rule.weight_decay * p32_b[b.key])
-            else:
-                w_new, v_new, sl_new = self.rule.apply(
-                    g_b[b.key], buckets[b.key], p32_b[b.key], sl,
-                    scale=scale, step=step, use_kernel=self.use_kernel)
-                upd = w_new - p32_b[b.key]
+            with jax.named_scope(f"bucket_{b.key}"):
+                scale = self.scale(b, step)
+                if self.rule.additive:
+                    d, v_new, sl_new = self.rule.precondition(
+                        g_b[b.key], buckets[b.key], sl, step=step,
+                        use_kernel=self.use_kernel)
+                    upd = -scale * (d + self.rule.weight_decay * p32_b[b.key])
+                else:
+                    w_new, v_new, sl_new = self.rule.apply(
+                        g_b[b.key], buckets[b.key], p32_b[b.key], sl,
+                        scale=scale, step=step, use_kernel=self.use_kernel)
+                    upd = w_new - p32_b[b.key]
             upd_b[b.key], v_b[b.key] = upd, v_new
             for name in sl_new:
                 slots_b[name][b.key] = sl_new[name]
@@ -184,8 +185,9 @@ class BucketedEngine:
             g, v, w_loc, sl, scale=self.scale(bucket, step), step=step,
             use_kernel=self.use_kernel)
         if n_shards > 1:
-            w_new = jax.lax.all_gather(w_new, self.shard_axis, axis=0,
-                                       tiled=True)
+            with jax.named_scope(f"all_gather_{bucket.key}"):
+                w_new = jax.lax.all_gather(w_new, self.shard_axis, axis=0,
+                                           tiled=True)
         return w_new, v_new, sl_new
 
     def apply_buckets(self, plan, g_b, p_b, buckets, slots, step):
@@ -194,9 +196,10 @@ class BucketedEngine:
         w_b, v_b = {}, {}
         slots_b: Dict[str, Dict[str, jax.Array]] = {n: {} for n in slots}
         for b in plan.buckets:
-            w_b[b.key], v_new, sl_new = self.bucket_apply(
-                b, g_b[b.key], buckets[b.key], self._slots_of(slots, b.key),
-                p_b[b.key], step)
+            with jax.named_scope(f"bucket_{b.key}"):
+                w_b[b.key], v_new, sl_new = self.bucket_apply(
+                    b, g_b[b.key], buckets[b.key],
+                    self._slots_of(slots, b.key), p_b[b.key], step)
             v_b[b.key] = v_new
             for name in sl_new:
                 slots_b[name][b.key] = sl_new[name]
@@ -232,8 +235,9 @@ class BucketedEngine:
         w_new, v_new, sl_new = self.rule.apply(
             g, v, w_loc, sl, scale=self.scale(bucket, step), step=step,
             use_kernel=self.use_kernel)
-        w_new = jax.lax.all_gather(w_new, self.shard_axis, axis=0,
-                                   tiled=True)
+        with jax.named_scope(f"all_gather_{bucket.key}"):
+            w_new = jax.lax.all_gather(w_new, self.shard_axis, axis=0,
+                                       tiled=True)
         return w_new, v_new, sl_new
 
     def sharded_n_dev(self, plan, buckets) -> Optional[int]:
@@ -261,10 +265,11 @@ class BucketedEngine:
         w_b, v_b = {}, {}
         slots_b: Dict[str, Dict[str, jax.Array]] = {n: {} for n in slots}
         for b in plan.buckets:
-            w_b[b.key], v_new, sl_new = self.bucket_apply_sharded(
-                b, g_shards[b.key], buckets[b.key],
-                self._slots_of(slots, b.key), w_chunks[b.key], step,
-                clip_scale)
+            with jax.named_scope(f"bucket_{b.key}"):
+                w_b[b.key], v_new, sl_new = self.bucket_apply_sharded(
+                    b, g_shards[b.key], buckets[b.key],
+                    self._slots_of(slots, b.key), w_chunks[b.key], step,
+                    clip_scale)
             v_b[b.key] = v_new
             for name in sl_new:
                 slots_b[name][b.key] = sl_new[name]

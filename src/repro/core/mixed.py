@@ -45,6 +45,7 @@ class ClipStats(NamedTuple):
     clipped: jax.Array  # 1.0 when the step was clipped
 
 
+@jax.named_scope("clip")
 def clip_by_global_norm(grads: PyTree, max_norm: float):
     """Global-norm clip.  ``max_norm <= 0`` disables clipping: the grads
     pass through *bitwise untouched* (no cast round-trip, no scale-by-1
@@ -255,6 +256,7 @@ def _fused_mixed(rule: MatrixUpdateRule, lr_matrix: Schedule,
                                buckets=bucketed.buckets,
                                slots=bucketed.slots)
 
+    @jax.named_scope("adamw")
     def adam_sweep(grads, state, params, step, emit):
         """Shared per-leaf AdamW pass.  ``emit(u, p)`` turns the fp32
         update (``u=None`` on matrix leaves, which the bucket scatter

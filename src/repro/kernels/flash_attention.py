@@ -130,6 +130,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),     # running denom l
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qg, kg, vg)
     return (out.reshape(B, K, G, S, hdv).transpose(0, 3, 1, 2, 4)
             .reshape(B, S, H, hdv))

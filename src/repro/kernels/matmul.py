@@ -62,6 +62,7 @@ def matmul(a, b, *, bm: int = 256, bn: int = 256, bk: int = 256,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="ns_matmul",
     )(a, b)
     return out[:m, :n]
 
@@ -110,5 +111,6 @@ def matmul3(a, b, *, bm: int = 256, bn: int = 256, bk: int = 256,
         out_shape=jax.ShapeDtypeStruct((L, M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="ns_matmul3",
     )(a, b)
     return out[:, :m, :n]

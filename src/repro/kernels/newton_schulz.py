@@ -42,6 +42,7 @@ def ns_step(x, a: float, b: float, c: float, interpret: bool = False):
         out_specs=pl.BlockSpec((bm, m), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
         interpret=interpret,
+        name="ns_poly",
     )(g, gg)
     return a * x + matmul(poly, x, interpret=interpret)
 
@@ -66,5 +67,6 @@ def ns_step3(x, a: float, b: float, c: float, interpret: bool = False):
         out_specs=pl.BlockSpec((1, bm, m), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((L, m, m), jnp.float32),
         interpret=interpret,
+        name="ns_poly3",
     )(g, gg)
     return a * x + matmul3(poly, x, interpret=interpret)

@@ -18,7 +18,7 @@ import signal
 import time
 import warnings
 from pathlib import Path
-from typing import Any, Dict, Optional, Sequence, Union
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -35,22 +35,30 @@ from repro.distributed import elastic
 from repro.distributed.sharding import axis_rules
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
+from repro.launch.spans import Spans
 from repro.models import init_params
 from repro.train import faults
-from repro.train.step import kernel_routes, make_train_step
+from repro.train.step import (kernel_routes, make_train_step,
+                              optimizer_kernel_launches)
 
 
 @dataclasses.dataclass
 class StepReport:
     """What :func:`train` learns about its compiled step, for a caller that
-    passes one in: the compile time, the compiled HLO text (kernel custom
-    calls can be counted in it), the compiler's memory analysis (bytes per
-    device of the step program), and per shape bucket the RMNP kernel
-    launch it traces to (``None``: the bucket takes the XLA path)."""
+    passes one in: the compile time (the ``setup/compile`` span), the
+    compiled HLO text (kernel custom calls can be counted in it, and each
+    instruction's ``op_name`` names its device scope), the compiler's
+    memory analysis (bytes per device of the step program), per shape
+    bucket the RMNP kernel launch it traces to (``None``: the bucket takes
+    the XLA path), the host spans ``(name, parent, step, t0_ns, t1_ns)``
+    and, per loop iteration, ``(step, compiles)`` (``launch/spans.py``)."""
     compile_s: float = 0.0
     hlo_text: str = ""
     memory: Any = None
     routes: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    spans: List[Tuple[str, Optional[str], int, int, int]] = \
+        dataclasses.field(default_factory=list)
+    compiles: List[Tuple[int, int]] = dataclasses.field(default_factory=list)
 
 
 def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
@@ -131,13 +139,16 @@ def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
     ahead of time from the state's shapes, and the state is then created
     directly in the shardings the compiled step expects.  ``report`` (a
     :class:`StepReport`) receives the compile time, the compiled HLO, its
-    memory analysis and the kernel routing.  Every logged history entry carries ``step_s``,
+    memory analysis, the kernel routing, the host spans and the compiles of
+    each loop iteration (``launch/spans.py``; the spans also go to a running
+    profiler's trace, report or not).  Every logged history entry carries ``step_s``,
     the host time from dispatching that step to its outputs being ready
     (a step's own time when every step is logged)."""
     cfg = arch if isinstance(arch, ModelConfig) else get_config(arch)
     if reduced:
         cfg = cfg.reduced()
 
+    spans = Spans(report)
     devices = list(devices) if devices is not None else jax.devices()
     mesh = make_local_mesh(data=len(devices), devices=devices)
     n_dev = mesh.shape["data"]
@@ -206,25 +217,31 @@ def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
         return jax.jit(fn, donate_argnums=(0, 1, 2) if zero2 else (0, 1))
 
     def compile_step(jit_step_, args):
-        t_c = time.perf_counter()
-        with mesh, axis_rules(mesh):
+        with spans.span("setup/compile") as compiling, mesh, axis_rules(mesh):
             compiled_ = jit_step_.lower(*args).compile()
-        compile_s = time.perf_counter() - t_c
-        print(f"[train] step compiled in {compile_s:.1f} s", flush=True)
+        print(f"[train] step compiled in {compiling.seconds:.1f} s",
+              flush=True)
         if report is not None:
-            report.compile_s = compile_s
+            report.compile_s = compiling.seconds
             report.hlo_text = compiled_.as_text()
             report.memory = compiled_.memory_analysis()
         return compiled_
 
-    if log_every and (fused or fused_apply or zero2 or use_kernel):
-        from repro.train.step import optimizer_launches
-        n = optimizer_launches(opt, params)
+    # one trace of the optimizer step gives both the launch count and the
+    # per-bucket kernel routes
+    log_launches = log_every and (fused or fused_apply or zero2 or use_kernel)
+    log_routes = use_kernel and optimizer == "rmnp" and (
+        fused or fused_apply or zero2)
+    if log_launches or log_routes:
+        with spans.span("setup/trace_optimizer"):
+            launches = optimizer_kernel_launches(opt, params)
+    if log_launches:
         detail = (f" ({len(opt_state.buckets)} shape buckets)"
                   if hasattr(opt_state, "buckets") else "")
-        print(f"[train] preconditioner kernel launches/step: {n}{detail}")
-    if use_kernel and optimizer == "rmnp" and (fused or fused_apply or zero2):
-        routes = kernel_routes(opt, params)
+        print(f"[train] preconditioner kernel launches/step: "
+              f"{len(launches)}{detail}")
+    if log_routes:
+        routes = kernel_routes(opt, params, launches)
         if report is not None:
             report.routes = routes
         for key, launch in routes.items():
@@ -239,43 +256,45 @@ def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
     compiled = compile_step(jit_step, tuple(state_abs) + (
         batch_abs, jax.ShapeDtypeStruct((), jnp.int32)))
     state_shardings = compiled.input_shardings[0][:len(state_abs)]
-    state = init_state(state_shardings)
+    with spans.span("setup/init_state"):
+        state = init_state(state_shardings)
     params, opt_state = state[0], state[1]
     comp_state = state[2] if zero2 else None
 
     mgr = CheckpointManager(ckpt_dir) if ckpt_dir else None
     latest = mgr.latest_step() if mgr is not None else None
     if latest is not None:
-        # zero2 checkpoints include the compression error-feedback state:
-        # dropping the accumulated residual on restart would break the
-        # schedule's unbiased-accumulation guarantee at every resume
-        old_layout = mgr.read_layout(latest)
-        old_n = old_layout.get("shard_size") if old_layout else None
-        if zero2 and old_layout is not None and old_n != n_dev:
-            # mesh-size mismatch: anything else differing is fatal (loud,
-            # both layouts named), a pure size change reshards exactly
-            elastic.validate_relayout(old_layout, layout)
-            (params, opt_state, comp_state), data_step = \
-                elastic.restore_resharded(mgr, latest, params, comp_state,
-                                          opt_new=opt,
-                                          opt_old=build_opt(old_n))
-            start_step = latest
-            print(f"[train] resumed from step {latest} "
-                  f"(elastic reshard {old_n}-way -> {n_dev}-way)")
-        else:
-            template = ((params, opt_state, comp_state) if zero2
-                        else (params, opt_state))
-            restored = mgr.restore_latest(template)
-            if zero2:
-                (params, opt_state, comp_state), start_step, data_step = restored
+        with spans.span("setup/restore"):
+            # zero2 checkpoints include the compression error-feedback state:
+            # dropping the accumulated residual on restart would break the
+            # schedule's unbiased-accumulation guarantee at every resume
+            old_layout = mgr.read_layout(latest)
+            old_n = old_layout.get("shard_size") if old_layout else None
+            if zero2 and old_layout is not None and old_n != n_dev:
+                # mesh-size mismatch: anything else differing is fatal (loud,
+                # both layouts named), a pure size change reshards exactly
+                elastic.validate_relayout(old_layout, layout)
+                (params, opt_state, comp_state), data_step = \
+                    elastic.restore_resharded(mgr, latest, params, comp_state,
+                                              opt_new=opt,
+                                              opt_old=build_opt(old_n))
+                start_step = latest
+                print(f"[train] resumed from step {latest} "
+                      f"(elastic reshard {old_n}-way -> {n_dev}-way)")
             else:
-                (params, opt_state), start_step, data_step = restored
-            print(f"[train] resumed from step {start_step}")
-        # restored host arrays go onto the compiled step's shardings
-        state = jax.device_put((params, opt_state, comp_state) if zero2
-                               else (params, opt_state), state_shardings)
-        params, opt_state = state[0], state[1]
-        comp_state = state[2] if zero2 else None
+                template = ((params, opt_state, comp_state) if zero2
+                            else (params, opt_state))
+                restored = mgr.restore_latest(template)
+                if zero2:
+                    (params, opt_state, comp_state), start_step, data_step = restored
+                else:
+                    (params, opt_state), start_step, data_step = restored
+                print(f"[train] resumed from step {start_step}")
+            # restored host arrays go onto the compiled step's shardings
+            state = jax.device_put((params, opt_state, comp_state) if zero2
+                                   else (params, opt_state), state_shardings)
+            params, opt_state = state[0], state[1]
+            comp_state = state[2] if zero2 else None
 
     stream = make_stream(cfg, seq, batch, seed=seed, start_step=data_step)
 
@@ -323,139 +342,150 @@ def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
     history = []
     t0 = time.time()
     end_step = min(steps, stop_at) if stop_at else steps
-    with mesh, axis_rules(mesh):
+    with mesh, axis_rules(mesh), spans:
         step = start_step
         while step < end_step:
-            if anomaly_skip_batch and stream.step in bad_data_steps:
-                bad_data_steps.discard(stream.step)
-                next(stream)  # drop the offending batch on replay
-                print(f"[train] replay: dropped the batch of skipped "
-                      f"data step {stream.step - 1}", flush=True)
-            np_batch = next(stream)
-            jbatch = {k: jnp.asarray(v) for k, v in np_batch.items()}
-            args = (((params, opt_state, comp_state) if zero2
-                     else (params, opt_state)) + (jbatch, jnp.int32(step)))
-            if compiled is None:
-                compiled = compile_step(jit_step, args)
-            if hang_guard is not None:
-                hang_guard.arm()
-                t_step = time.time()
-            t_dispatch = time.perf_counter()
-            if zero2:
-                params, opt_state, comp_state, metrics = compiled(*args)
-            else:
-                params, opt_state, metrics = compiled(*args)
-            if hang_guard is not None:
-                # host snapshot into the manager's double buffer BEFORE
-                # recording: the emergency save must never read live
-                # device buffers — the next step donates them, and a hung
-                # step already owns its donated inputs
-                if mgr is not None:
-                    mgr.snapshot(step + 1,
-                                 (params, opt_state, comp_state) if zero2
-                                 else (params, opt_state),
-                                 data_step=stream.step, layout=layout)
-                hang_guard.record(step, time.time() - t_step)
-            if monitor is not None:
-                gflags = np.asarray(metrics.pop("guard_flags"))
-                was_skipped = bool(float(metrics.pop("skipped")))
-                action = monitor.record(step, float(metrics["loss"]),
-                                        skipped=was_skipped, flags=gflags)
-                if action != "ok":
-                    pending_good.clear()  # anomaly: nothing in flight
-                    #   gets promoted to last-known-good
-                if action == "skip":
-                    leaves = ", ".join(monitor.bad_leaves(gflags)) or \
-                        "<loss non-finite>"
-                    bad_data_steps.add(stream.step - 1)
-                    print(f"[train] guard: step {step} SKIPPED bitwise "
-                          f"(non-finite: {leaves}; "
-                          f"{monitor.consecutive_skips}/"
-                          f"{anomaly_skip_budget} consecutive)", flush=True)
-                elif action == "rewind":
-                    lr_scale *= anomaly_lr_backoff
-                    opt = build_opt(n_dev if zero2 else 1, lr_scale)
-                    good = (mgr.latest_good_step()
-                            if mgr is not None else None)
-                    if good is not None:
-                        mgr.wait()
-                        state, data_step = mgr.restore(good, state_template)
-                        # a restore yields host arrays: put them back on
-                        # the step's own shardings
-                        state = jax.device_put(state, state_shardings)
-                        if zero2:
-                            # every rank's EF residual rides the sharded
-                            # checkpoint (device-axis CompressionState), so
-                            # the replayed tail is bitwise on both wires
-                            params, opt_state, comp_state = state
-                        else:
-                            params, opt_state = state
-                        rewind_to = good
+            with spans.iteration(step):
+                # next(stream) stays in this frame: a wrapped stream may read
+                # the loop's params, opt_state and metrics from its caller
+                with spans.span("data"):
+                    if anomaly_skip_batch and stream.step in bad_data_steps:
+                        bad_data_steps.discard(stream.step)
+                        next(stream)  # drop the offending batch on replay
+                        print(f"[train] replay: dropped the batch of skipped "
+                              f"data step {stream.step - 1}", flush=True)
+                    np_batch = next(stream)
+                    jbatch = {k: jnp.asarray(v) for k, v in np_batch.items()}
+                args = (((params, opt_state, comp_state) if zero2
+                         else (params, opt_state)) + (jbatch, jnp.int32(step)))
+                if compiled is None:
+                    compiled = compile_step(jit_step, args)
+                if hang_guard is not None:
+                    hang_guard.arm()
+                    t_step = time.time()
+                t_dispatch = time.perf_counter()
+                with spans.span("dispatch"):
+                    if zero2:
+                        params, opt_state, comp_state, metrics = compiled(*args)
                     else:
-                        # no good checkpoint yet: restart from init
-                        state = init_state(state_shardings)
-                        params, opt_state = state[0], state[1]
-                        comp_state = state[2] if zero2 else None
-                        rewind_to, data_step = 0, 0
-                    if fault_spec is not None:
-                        print("[train] rewind: disarming the injected "
-                              "fault (transient-fault model)", flush=True)
-                        fault_spec = None
-                    jit_step, compiled = build_step(opt, fault_spec), None
-                    stream = make_stream(cfg, seq, batch, seed=seed,
-                                         start_step=data_step)
-                    print(f"[train] anomaly ladder: rewind #"
-                          f"{monitor.rewinds} to step {rewind_to} "
-                          f"(lr x{lr_scale:g}, data step {data_step}; "
-                          f"{monitor.post_mortem()})", flush=True)
-                    step = rewind_to
-                    continue
-                elif action == "abort":
-                    raise RuntimeError(
-                        f"[train] numerical-anomaly escalation ladder "
-                        f"exhausted at step {step}: "
-                        f"{monitor.post_mortem()}")
-            if log_every and (step % log_every == 0 or step == steps - 1):
-                jax.block_until_ready((params, metrics))
-                step_s = time.perf_counter() - t_dispatch
-                m = {k: float(v) for k, v in metrics.items()}
-                m["step"] = step
-                m["step_s"] = step_s
-                m["wall_s"] = round(time.time() - t0, 2)
-                if dominance_every and step % dominance_every == 0 and \
-                        optimizer != "adamw":
-                    from repro.core.mixed import momentum_for_diagnostics
-                    dom = global_dominance(momentum_for_diagnostics(
-                        opt_state, params, matrix_embed=matrix_embed))
-                    m.update({k: float(v) for k, v in dom.items()})
-                history.append(m)
-                print(f"[train] step={step} loss={m['loss']:.4f} "
-                      f"gnorm={m['grad_norm']:.3f} clip={m['clip_rate']:.0f} "
-                      f"step_s={step_s:.4f}"
-                      + (f" r_avg={m['r_avg']:.2f}" if "r_avg" in m else ""),
-                      flush=True)
-            if mgr is not None and ckpt_every and (step + 1) % ckpt_every == 0:
-                state = ((params, opt_state, comp_state) if zero2
-                         else (params, opt_state))
-                mgr.save(step + 1, state, data_step=stream.step,
-                         layout=layout)
+                        params, opt_state, metrics = compiled(*args)
+                if hang_guard is not None:
+                    # host snapshot into the manager's double buffer BEFORE
+                    # recording: the emergency save must never read live
+                    # device buffers — the next step donates them, and a hung
+                    # step already owns its donated inputs
+                    if mgr is not None:
+                        with spans.span("checkpoint"):
+                            mgr.snapshot(step + 1,
+                                         (params, opt_state, comp_state) if zero2
+                                         else (params, opt_state),
+                                         data_step=stream.step, layout=layout)
+                    hang_guard.record(step, time.time() - t_step)
                 if monitor is not None:
-                    pending_good.append(step + 1)
-            if monitor is not None and pending_good:
-                # promote checkpoints that survived the health window of
-                # anomaly-free steps to last-known-good
-                ripe = [s for s in pending_good
-                        if step + 1 - s >= anomaly_health_window]
-                for s in ripe:
-                    mgr.mark_good(s)
-                    pending_good.remove(s)
-                    print(f"[train] checkpoint step {s} promoted to "
-                          f"last-known-good", flush=True)
-            if kill_at and step + 1 == kill_at:
-                print(f"[train] fault injection: SIGKILL at step {step + 1}",
-                      flush=True)
-                os.kill(os.getpid(), signal.SIGKILL)
-            step += 1
+                    with spans.span("guard"):
+                        gflags = np.asarray(metrics.pop("guard_flags"))
+                        was_skipped = bool(float(metrics.pop("skipped")))
+                        action = monitor.record(step, float(metrics["loss"]),
+                                                skipped=was_skipped, flags=gflags)
+                    if action != "ok":
+                        pending_good.clear()  # anomaly: nothing in flight
+                        #   gets promoted to last-known-good
+                    if action == "skip":
+                        leaves = ", ".join(monitor.bad_leaves(gflags)) or \
+                            "<loss non-finite>"
+                        bad_data_steps.add(stream.step - 1)
+                        print(f"[train] guard: step {step} SKIPPED bitwise "
+                              f"(non-finite: {leaves}; "
+                              f"{monitor.consecutive_skips}/"
+                              f"{anomaly_skip_budget} consecutive)", flush=True)
+                    elif action == "rewind":
+                        lr_scale *= anomaly_lr_backoff
+                        opt = build_opt(n_dev if zero2 else 1, lr_scale)
+                        good = (mgr.latest_good_step()
+                                if mgr is not None else None)
+                        if good is not None:
+                            with spans.span("checkpoint"):
+                                mgr.wait()
+                                state, data_step = mgr.restore(good,
+                                                               state_template)
+                                # a restore yields host arrays: put them back
+                                # on the step's own shardings
+                                state = jax.device_put(state, state_shardings)
+                            if zero2:
+                                # every rank's EF residual rides the sharded
+                                # checkpoint (device-axis CompressionState), so
+                                # the replayed tail is bitwise on both wires
+                                params, opt_state, comp_state = state
+                            else:
+                                params, opt_state = state
+                            rewind_to = good
+                        else:
+                            # no good checkpoint yet: restart from init
+                            state = init_state(state_shardings)
+                            params, opt_state = state[0], state[1]
+                            comp_state = state[2] if zero2 else None
+                            rewind_to, data_step = 0, 0
+                        if fault_spec is not None:
+                            print("[train] rewind: disarming the injected "
+                                  "fault (transient-fault model)", flush=True)
+                            fault_spec = None
+                        jit_step, compiled = build_step(opt, fault_spec), None
+                        stream = make_stream(cfg, seq, batch, seed=seed,
+                                             start_step=data_step)
+                        print(f"[train] anomaly ladder: rewind #"
+                              f"{monitor.rewinds} to step {rewind_to} "
+                              f"(lr x{lr_scale:g}, data step {data_step}; "
+                              f"{monitor.post_mortem()})", flush=True)
+                        step = rewind_to
+                        continue
+                    elif action == "abort":
+                        raise RuntimeError(
+                            f"[train] numerical-anomaly escalation ladder "
+                            f"exhausted at step {step}: "
+                            f"{monitor.post_mortem()}")
+                if log_every and (step % log_every == 0 or step == steps - 1):
+                    with spans.span("block"):
+                        jax.block_until_ready((params, metrics))
+                        step_s = time.perf_counter() - t_dispatch
+                        m = {k: float(v) for k, v in metrics.items()}
+                    m["step"] = step
+                    m["step_s"] = step_s
+                    m["wall_s"] = round(time.time() - t0, 2)
+                    if dominance_every and step % dominance_every == 0 and \
+                            optimizer != "adamw":
+                        from repro.core.mixed import momentum_for_diagnostics
+                        dom = global_dominance(momentum_for_diagnostics(
+                            opt_state, params, matrix_embed=matrix_embed))
+                        m.update({k: float(v) for k, v in dom.items()})
+                    history.append(m)
+                    print(f"[train] step={step} loss={m['loss']:.4f} "
+                          f"gnorm={m['grad_norm']:.3f} clip={m['clip_rate']:.0f} "
+                          f"step_s={step_s:.4f}"
+                          + (f" r_avg={m['r_avg']:.2f}" if "r_avg" in m else ""),
+                          flush=True)
+                if mgr is not None and ckpt_every and (step + 1) % ckpt_every == 0:
+                    state = ((params, opt_state, comp_state) if zero2
+                             else (params, opt_state))
+                    with spans.span("checkpoint"):
+                        mgr.save(step + 1, state, data_step=stream.step,
+                                 layout=layout)
+                    if monitor is not None:
+                        pending_good.append(step + 1)
+                if monitor is not None and pending_good:
+                    # promote checkpoints that survived the health window of
+                    # anomaly-free steps to last-known-good
+                    ripe = [s for s in pending_good
+                            if step + 1 - s >= anomaly_health_window]
+                    for s in ripe:
+                        mgr.mark_good(s)
+                        pending_good.remove(s)
+                        print(f"[train] checkpoint step {s} promoted to "
+                              f"last-known-good", flush=True)
+                if kill_at and step + 1 == kill_at:
+                    print(f"[train] fault injection: SIGKILL at step {step + 1}",
+                          flush=True)
+                    os.kill(os.getpid(), signal.SIGKILL)
+                step += 1
     if hang_guard is not None:
         hang_guard.stop()
     if mgr is not None and end_step == steps:

@@ -269,32 +269,35 @@ def _ce_terms(logits, labels, mask):
 def loss_fn(cfg: ModelConfig, params, batch, remat: str = "full"):
     """Cross-entropy with the LM head applied in sequence chunks so the full
     (B, S, V) fp32 logits tensor is never materialized (the head matmul is
-    recomputed in the backward pass via jax.checkpoint)."""
-    hidden, _, aux = forward(cfg, params, batch, "train", remat=remat,
-                             return_hidden=True)
-    head = (params["embed"]["tokens"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    labels = batch["labels"]
-    mask = (labels >= 0).astype(jnp.float32)
-    labels_c = jnp.maximum(labels, 0)
-    B, S, _ = hidden.shape
+    recomputed in the backward pass via jax.checkpoint).  Runs under the
+    ``forward`` scope, so each compiled instruction's ``op_name`` says
+    forward, or ``transpose(jvp(forward))`` for its gradient."""
+    with jax.named_scope("forward"):
+        hidden, _, aux = forward(cfg, params, batch, "train", remat=remat,
+                                 return_hidden=True)
+        head = (params["embed"]["tokens"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        labels = batch["labels"]
+        mask = (labels >= 0).astype(jnp.float32)
+        labels_c = jnp.maximum(labels, 0)
+        B, S, _ = hidden.shape
 
-    if S % _LOSS_CHUNK == 0 and S > _LOSS_CHUNK:
-        nchunk = S // _LOSS_CHUNK
-        hs = jnp.moveaxis(hidden.reshape(B, nchunk, _LOSS_CHUNK, -1), 1, 0)
-        ls = jnp.moveaxis(labels_c.reshape(B, nchunk, _LOSS_CHUNK), 1, 0)
-        ms = jnp.moveaxis(mask.reshape(B, nchunk, _LOSS_CHUNK), 1, 0)
+        if S % _LOSS_CHUNK == 0 and S > _LOSS_CHUNK:
+            nchunk = S // _LOSS_CHUNK
+            hs = jnp.moveaxis(hidden.reshape(B, nchunk, _LOSS_CHUNK, -1), 1, 0)
+            ls = jnp.moveaxis(labels_c.reshape(B, nchunk, _LOSS_CHUNK), 1, 0)
+            ms = jnp.moveaxis(mask.reshape(B, nchunk, _LOSS_CHUNK), 1, 0)
 
-        @jax.checkpoint
-        def chunk(acc, xs):
-            h, l, m = xs
-            return acc + _ce_terms(h @ head, l, m), None
+            @jax.checkpoint
+            def chunk(acc, xs):
+                h, l, m = xs
+                return acc + _ce_terms(h @ head, l, m), None
 
-        nll_sum, _ = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), (hs, ls, ms))
-    else:
-        nll_sum = _ce_terms(hidden @ head, labels_c, mask)
+            nll_sum, _ = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), (hs, ls, ms))
+        else:
+            nll_sum = _ce_terms(hidden @ head, labels_c, mask)
 
-    denom = jnp.maximum(jnp.sum(mask), 1.0)
-    nll = nll_sum / denom
-    loss = nll + aux
-    return loss, {"loss": loss, "nll": nll, "aux": aux, "ntokens": denom}
+        denom = jnp.maximum(jnp.sum(mask), 1.0)
+        nll = nll_sum / denom
+        loss = nll + aux
+        return loss, {"loss": loss, "nll": nll, "aux": aux, "ntokens": denom}

@@ -181,10 +181,12 @@ def make_dp_train_step(cfg: ModelConfig, opt: Optimizer, mesh: Mesh,
             chunks = gather_chunks(plan, v_tree, n_dev, dtype=jnp.float32)
             resid = {}
             for b in plan.buckets:
-                g_shards[b.key], resid[b.key] = compressed_reduce_scatter_leaf(
-                    chunks[b.key], axis_name, n_dev,
-                    wire_fault=faults.wire_fault_for(fault, b.key, step,
-                                                     axis_name))
+                with jax.named_scope(f"reduce_scatter_{b.key}"):
+                    g_shards[b.key], resid[b.key] = \
+                        compressed_reduce_scatter_leaf(
+                            chunks[b.key], axis_name, n_dev,
+                            wire_fault=faults.wire_fault_for(
+                                fault, b.key, step, axis_name))
             grads, comp_state = compressed_mean(
                 grads, comp_state, axis_name, n_dev, skip=skip)
             comp_state = CompressionState(
@@ -193,8 +195,9 @@ def make_dp_train_step(cfg: ModelConfig, opt: Optimizer, mesh: Mesh,
             from repro.core.bucketing import gather_chunks
             chunks = gather_chunks(plan, grads, n_dev, dtype=jnp.float32)
             for b in plan.buckets:
-                g_shards[b.key] = exact_reduce_scatter(chunks[b.key],
-                                                       axis_name)
+                with jax.named_scope(f"reduce_scatter_{b.key}"):
+                    g_shards[b.key] = exact_reduce_scatter(chunks[b.key],
+                                                           axis_name)
             grads = exact_mean(grads, axis_name, skip=skip)
         return g_shards, grads, comp_state, plan
 
@@ -218,8 +221,9 @@ def make_dp_train_step(cfg: ModelConfig, opt: Optimizer, mesh: Mesh,
                 plan, g_shards, grads, clip_norm, axis_name, n_dev)
             g_shards = {k: s * scale for k, s in g_shards.items()}
             grads = pipeline.scale_rest(grads, rest32, scale)
-            params, opt_state = opt.update_apply_sharded(
-                g_shards, grads, opt_state, params, step)
+            with jax.named_scope("optimizer"):
+                params, opt_state = opt.update_apply_sharded(
+                    g_shards, grads, opt_state, params, step)
         else:
             if compress:
                 grads, comp_state = compressed_mean(
@@ -234,12 +238,14 @@ def make_dp_train_step(cfg: ModelConfig, opt: Optimizer, mesh: Mesh,
                 # per-leaf partials CSE with clip_by_global_norm's
                 ginfo = pipeline.finite_guard(grads)
             grads, clip_stats = clip_by_global_norm(grads, clip_norm)
-            if opt.update_apply is not None:
-                params, opt_state = opt.update_apply(grads, opt_state, params,
-                                                     step)
-            else:
-                updates, opt_state = opt.update(grads, opt_state, params, step)
-                params = apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                if opt.update_apply is not None:
+                    params, opt_state = opt.update_apply(grads, opt_state,
+                                                         params, step)
+                else:
+                    updates, opt_state = opt.update(grads, opt_state, params,
+                                                    step)
+                    params = apply_updates(params, updates)
         metrics = dict(metrics, grad_norm=clip_stats.global_norm,
                        clip_rate=clip_stats.clipped)
         if guard:

@@ -249,6 +249,7 @@ def guard_flag_names(plan, tree, n_dev: int):
             + [p for p, _ in tree_paths(tree) if p not in mat])
 
 
+@jax.named_scope("guard")
 def finite_guard(grads) -> GuardInfo:
     """Per-leaf finite flags for the replicated (non-two-phase) paths: one
     sum of squares per leaf — the same per-leaf partials
@@ -261,6 +262,7 @@ def finite_guard(grads) -> GuardInfo:
     return GuardInfo(ok=jnp.all(flags), flags=flags)
 
 
+@jax.named_scope("guard")
 def mask_updates(ok, new, old):
     """Bitwise step skip: ``jnp.where(ok, new, old)`` on every leaf.
     Select is an elementwise pick — ``ok=True`` yields bitwise ``new``
@@ -271,6 +273,7 @@ def mask_updates(ok, new, old):
     return jax.tree_util.tree_map(lambda n, o: jnp.where(ok, n, o), new, old)
 
 
+@jax.named_scope("clip")
 def two_phase_clip(plan, g_shards, grads, clip_norm: float, axis_name: str,
                    n_dev: int):
     """Two-phase global-norm clip over the ZeRO-2 sharded matrix partition
@@ -383,18 +386,21 @@ def make_pipelined_zero2_step(cfg: ModelConfig, opt: Optimizer, *,
             v_chunks = fold_error_chunks(plan, chunk_means, comp_state, n_dev)
             resid = {}
             for b in plan.buckets:
-                g_shards[b.key], resid[b.key] = compressed_reduce_scatter_leaf(
-                    v_chunks[b.key], axis_name, n_dev,
-                    wire_fault=faults_mod.wire_fault_for(
-                        fault, b.key, step, axis_name))
+                with jax.named_scope(f"reduce_scatter_{b.key}"):
+                    g_shards[b.key], resid[b.key] = \
+                        compressed_reduce_scatter_leaf(
+                            v_chunks[b.key], axis_name, n_dev,
+                            wire_fault=faults_mod.wire_fault_for(
+                                fault, b.key, step, axis_name))
             rest, comp_state = compressed_mean(
                 rest, comp_state, axis_name, n_dev, skip=skip)
             comp_state = CompressionState(
                 error=bucketing.scatter_chunks(plan, resid, comp_state.error))
         else:
             for b in plan.buckets:
-                g_shards[b.key] = exact_reduce_scatter(chunk_means[b.key],
-                                                       axis_name)
+                with jax.named_scope(f"reduce_scatter_{b.key}"):
+                    g_shards[b.key] = exact_reduce_scatter(
+                        chunk_means[b.key], axis_name)
             rest = exact_mean(rest, axis_name, skip=skip)
         metrics = jax.tree_util.tree_map(
             lambda m: jax.lax.pmean(m, axis_name), metrics)
@@ -402,8 +408,9 @@ def make_pipelined_zero2_step(cfg: ModelConfig, opt: Optimizer, *,
         scale, rest32, clip_stats, ginfo = two_phase_clip(
             plan, g_shards, rest, clip_norm, axis_name, n_dev)
         rest = scale_rest(rest, rest32, scale)
-        params, opt_state = opt.update_apply_sharded(
-            g_shards, rest, opt_state, params, step, clip_scale=scale)
+        with jax.named_scope("optimizer"):
+            params, opt_state = opt.update_apply_sharded(
+                g_shards, rest, opt_state, params, step, clip_scale=scale)
         metrics = dict(metrics, grad_norm=clip_stats.global_norm,
                        clip_rate=clip_stats.clipped)
         if guard:

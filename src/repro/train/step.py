@@ -21,47 +21,51 @@ from repro.train import faults
 from repro.train import pipeline as pipeline_mod
 
 
+def _optimizer_call(opt: Optimizer, params, step: int = 0):
+    """``(fn, args)`` of one optimizer step over abstract values:
+    ``opt.update_apply`` when the optimizer carries the single-pass path,
+    else ``opt.update``.  For tracing; nothing is compiled or executed."""
+    def abstract(t):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
+    state = jax.eval_shape(opt.init, params)
+    fn = opt.update_apply if opt.update_apply is not None else opt.update
+    return fn, (abstract(params), state, abstract(params), jnp.int32(step))
+
+
+def optimizer_kernel_launches(opt: Optimizer, params, step: int = 0) -> list:
+    """Every ``pallas_call`` launch (``kernels.introspect.KernelLaunch``)
+    one optimizer step traces to: one trace gives both
+    :func:`optimizer_launches` and :func:`kernel_routes`."""
+    from repro.kernels import introspect
+
+    fn, args = _optimizer_call(opt, params, step)
+    return introspect.collect_kernel_launches(fn, *args)
+
+
 def optimizer_launches(opt: Optimizer, params, step: int = 0) -> int:
     """Kernel (``pallas_call``) launches one optimizer step costs — the
     quantity the shape-bucketed fused engine minimises: per-leaf kernels
     launch once per matrix parameter, the fused path once per shape bucket.
-    Traces ``opt.update_apply`` when the optimizer carries the single-pass
-    path, else ``opt.update``.  Pure tracing (abstract values); nothing is
-    compiled or executed."""
-    from repro.kernels.ops import count_pallas_calls
-
-    def abstract(t):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
-    state = jax.eval_shape(opt.init, params)
-    fn = opt.update_apply if opt.update_apply is not None else opt.update
-    return count_pallas_calls(
-        fn, abstract(params), state, abstract(params), jnp.int32(step))
+    Pure tracing (abstract values); nothing is compiled or executed."""
+    return len(optimizer_kernel_launches(opt, params, step))
 
 
-def kernel_routes(opt: Optimizer, params, step: int = 0) -> dict:
+def kernel_routes(opt: Optimizer, params, launches) -> dict:
     """Per shape bucket of ``opt``'s plan, the RMNP stripe-kernel launch
-    its update traces to (``kernels.introspect.KernelLaunch``), or ``None``
-    where the kernel's VMEM plan sent the bucket to the XLA path.  Pure
-    tracing, like :func:`optimizer_launches`."""
-    from repro.kernels import introspect
+    among ``launches`` (:func:`optimizer_kernel_launches`) its update
+    traces to, or ``None`` where the kernel's VMEM plan sent the bucket to
+    the XLA path."""
     from repro.kernels.rmnp_update import MAX_BLOCK_N
 
-    def abstract(t):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
-    state = jax.eval_shape(opt.init, params)
-    fn = opt.update_apply if opt.update_apply is not None else opt.update
-    launches = [ln for ln in introspect.collect_kernel_launches(
-        fn, abstract(params), state, abstract(params), jnp.int32(step))
-        if ln.name.startswith("rmnp_rownorm")]
+    rmnp = [ln for ln in launches if ln.name.startswith("rmnp_rownorm")]
 
     def of_bucket(ln, b):
         # stripe operands are (L, d_in, d_out padded to the lane block)
         L, d_in, n_p = ln.in_blocks[-1].array_shape
         return (L == b.padded and d_in == b.d_in
                 and b.d_out <= n_p < b.d_out + MAX_BLOCK_N)
-    return {b.key: next((ln for ln in launches if of_bucket(ln, b)), None)
+    return {b.key: next((ln for ln in rmnp if of_bucket(ln, b)), None)
             for b in opt.bucket_plan(params).buckets}
 
 
@@ -73,13 +77,8 @@ def optimizer_fp32_buffers(opt: Optimizer, params, shape,
     two-pass engine does."""
     from repro.kernels.ops import count_buffer_eqns
 
-    def abstract(t):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
-    state = jax.eval_shape(opt.init, params)
-    fn = opt.update_apply if opt.update_apply is not None else opt.update
-    return count_buffer_eqns(fn, shape, jnp.float32, abstract(params), state,
-                             abstract(params), jnp.int32(step))
+    fn, args = _optimizer_call(opt, params, step)
+    return count_buffer_eqns(fn, shape, jnp.float32, *args)
 
 
 def make_train_step(cfg: ModelConfig, opt: Optimizer, *, clip_norm: float = 1.0,
@@ -134,13 +133,15 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, *, clip_norm: float = 1.0,
 
         ginfo = pipeline_mod.finite_guard(grads) if guard else None
         grads, clip_stats = clip_by_global_norm(grads, clip_norm)
-        if opt.update_apply is not None:
-            # single-pass fused apply: the kernel emits the new weights
-            # directly — no updates tree, no apply_updates pass
-            params, opt_state = opt.update_apply(grads, opt_state, params, step)
-        else:
-            updates, opt_state = opt.update(grads, opt_state, params, step)
-            params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            if opt.update_apply is not None:
+                # single-pass fused apply: the kernel emits the new weights
+                # directly — no updates tree, no apply_updates pass
+                params, opt_state = opt.update_apply(grads, opt_state, params,
+                                                     step)
+            else:
+                updates, opt_state = opt.update(grads, opt_state, params, step)
+                params = apply_updates(params, updates)
         metrics = dict(metrics, grad_norm=clip_stats.global_norm,
                        clip_rate=clip_stats.clipped)
         if guard:

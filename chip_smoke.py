@@ -110,6 +110,13 @@ def _compiled_bytes(report) -> str:
             f"aliased={m.alias_size_in_bytes}")
 
 
+def bucket_routes(report) -> dict:
+    """``report.routes`` without the attention call sites: per shape
+    bucket, its RMNP kernel launch (``None``: the XLA path)."""
+    return {k: ln for k, ln in report.routes.items()
+            if not k.startswith("attention ")}
+
+
 def phase_train(dev, *, seed: int):
     """Train on one device; returns (params, opt_state, report)."""
     from repro.configs import get_config
@@ -143,12 +150,16 @@ def phase_train(dev, *, seed: int):
     log(f"loss step0={losses[0]} last5_mean={tail}")
 
     vocab = get_config(ARCH).vocab
-    kernel = {k: ln for k, ln in report.routes.items() if ln is not None}
-    xla = [k for k, ln in report.routes.items() if ln is None]
-    for key, ln in report.routes.items():
+    buckets = bucket_routes(report)
+    kernel = {k: ln for k, ln in buckets.items() if ln is not None}
+    xla = [k for k, ln in buckets.items() if ln is None]
+    for key, ln in buckets.items():
         log(f"bucket {key}: " + ("xla" if ln is None else
                                  f"kernel {ln.name} grid {ln.grid}"))
-    check(bool(report.routes), "no bucket routing reported")
+    for key, route in report.routes.items():
+        if key not in buckets:
+            log(f"{key}: {route}")
+    check(bool(buckets), "no bucket routing reported")
     # only the embedding (fan-in = the vocabulary) may take XLA
     bad = [k for k in xla if int(k.split("x")[0]) < vocab]
     check(not bad, f"block buckets routed to XLA: {bad}")
@@ -268,7 +279,8 @@ def run_one_chip(args) -> list:
     # block); the bucket key is "d_inxd_out"
     shapes = [tuple(ln.in_blocks[-1].array_shape[:2])
               + (int(key.split("x")[1]),)
-              for key, ln in sorted(report.routes.items()) if ln is not None]
+              for key, ln in sorted(bucket_routes(report).items())
+              if ln is not None]
     phase_kernel_check(shapes, seed=args.seed)
     return devs
 

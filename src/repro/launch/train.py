@@ -37,6 +37,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.spans import Spans
 from repro.models import init_params
+from repro.models.layers import recording_attention_routes
 from repro.train import faults
 from repro.train.step import (kernel_routes, make_train_step,
                               optimizer_kernel_launches)
@@ -50,8 +51,11 @@ class StepReport:
     instruction's ``op_name`` names its device scope), the compiler's
     memory analysis (bytes per device of the step program), per shape
     bucket the RMNP kernel launch it traces to (``None``: the bucket takes
-    the XLA path), the host spans ``(name, parent, step, t0_ns, t1_ns)``
-    and, per loop iteration, ``(step, compiles)`` (``launch/spans.py``)."""
+    the XLA path) and per attention call site ``"attention (B,S,H,hd)"``
+    its route (``"flash"``, or ``"dense: <why>"`` / ``"chunked: <why>"``,
+    ``models/layers.py:attention_route``), the host spans ``(name,
+    parent, step, t0_ns, t1_ns)`` and, per loop iteration, ``(step,
+    compiles)`` (``launch/spans.py``)."""
     compile_s: float = 0.0
     hlo_text: str = ""
     memory: Any = None
@@ -217,7 +221,8 @@ def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
         return jax.jit(fn, donate_argnums=(0, 1, 2) if zero2 else (0, 1))
 
     def compile_step(jit_step_, args):
-        with spans.span("setup/compile") as compiling, mesh, axis_rules(mesh):
+        with spans.span("setup/compile") as compiling, mesh, \
+                axis_rules(mesh), recording_attention_routes() as attention:
             compiled_ = jit_step_.lower(*args).compile()
         print(f"[train] step compiled in {compiling.seconds:.1f} s",
               flush=True)
@@ -225,7 +230,8 @@ def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
             report.compile_s = compiling.seconds
             report.hlo_text = compiled_.as_text()
             report.memory = compiled_.memory_analysis()
-        return compiled_
+            report.routes.update(attention)
+        return compiled_, attention
 
     # one trace of the optimizer step gives both the launch count and the
     # per-bucket kernel routes
@@ -253,8 +259,10 @@ def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
     batch_abs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
                  for k, v in next(make_stream(cfg, seq, batch,
                                               seed=seed)).items()}
-    compiled = compile_step(jit_step, tuple(state_abs) + (
+    compiled, attention = compile_step(jit_step, tuple(state_abs) + (
         batch_abs, jax.ShapeDtypeStruct((), jnp.int32)))
+    for key, route in attention.items():
+        print(f"[train] {key}: {route}", flush=True)
     state_shardings = compiled.input_shardings[0][:len(state_abs)]
     with spans.span("setup/init_state"):
         state = init_state(state_shardings)
@@ -359,7 +367,7 @@ def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
                 args = (((params, opt_state, comp_state) if zero2
                          else (params, opt_state)) + (jbatch, jnp.int32(step)))
                 if compiled is None:
-                    compiled = compile_step(jit_step, args)
+                    compiled, _ = compile_step(jit_step, args)
                 if hang_guard is not None:
                     hang_guard.arm()
                     t_step = time.time()

@@ -1,20 +1,24 @@
 """Shared transformer layer primitives: RMSNorm, RoPE, GQA + MLA attention
-(dense / flash-chunked / decode paths), SwiGLU FFN.
+(dense / flash kernel / chunked / decode paths), SwiGLU FFN.
 
 Shape conventions: activations (B, S, D); per-head tensors (B, S, H, hd);
 all matmul weights stored (..., d_in, d_out).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
-from typing import Dict, Optional, Tuple
+import threading
+from typing import Dict, Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MLAConfig, ModelConfig
-from repro.distributed.sharding import logical
+from repro.distributed.sharding import current_mesh, logical
+from repro.kernels.flash_attention import (block_sizes, flash_attention,
+                                           flash_route)
 
 # ---------------------------------------------------------------------------
 # Param specs
@@ -132,7 +136,7 @@ apply_rope.defvjp(_rope_fwd, _rope_bwd)
 # Attention math
 # ---------------------------------------------------------------------------
 
-_FLASH_THRESHOLD = 8192  # use chunked (flash-style) attention above this S
+_FLASH_THRESHOLD = 8192  # off the flash kernels: chunked attention from this S
 _Q_CHUNK = 2048
 _KV_CHUNK = 2048
 
@@ -235,21 +239,65 @@ def _chunked_attention(q, k, v, causal: bool, qc: int, kc: int):
     return logical(out.astype(q.dtype), ("batch", None, "heads", None))
 
 
+_routes = threading.local()
+
+
+@contextlib.contextmanager
+def recording_attention_routes() -> Iterator[Dict[str, str]]:
+    """Collect the route of every ``impl="auto"`` attention call traced
+    inside the block: ``"attention (B,S,H,hd)"`` -> ``"flash"``, or
+    ``"dense: <why not flash>"`` / ``"chunked: <why not flash>"``."""
+    log: Dict[str, str] = {}
+    outer = getattr(_routes, "log", None)
+    _routes.log = log
+    try:
+        yield log
+    finally:
+        _routes.log = outer
+
+
+def attention_route(q, k, v, causal: bool,
+                    q_offset: int = 0) -> Tuple[str, str]:
+    """``impl="auto"``'s choice for these shapes: ``("flash", "")``, or
+    the XLA path (chunked self-attention from ``_FLASH_THRESHOLD``, dense
+    below) with the reason the flash kernels' rule
+    (``kernels/flash_attention.py:flash_route``) gave."""
+    S, Skv = q.shape[1], k.shape[1]
+    mesh = current_mesh()
+    why = flash_route(jax.default_backend(), S, Skv, q_offset, q.shape[-1],
+                      v.shape[-1], causal, mesh.size if mesh else 1)
+    if why is True:
+        return "flash", ""
+    if S >= _FLASH_THRESHOLD and S == Skv:
+        return "chunked", why
+    return "dense", why
+
+
 def attention(q, k, v, causal=True, q_offset=0, impl: str = "auto",
               chunk_q: int = _Q_CHUNK, chunk_k: int = _KV_CHUNK):
-    """impl: auto | dense | chunked | pallas.  "auto" = chunked above the
-    S threshold, dense below; "pallas" = flash-attention kernel (TPU; runs
-    in interpret mode elsewhere — tests only)."""
+    """impl: auto | dense | chunked | pallas.  "auto" = the Pallas flash
+    kernels where :func:`attention_route` sends the shape (TPU), else
+    chunked above the S threshold and dense below; "pallas" = the flash
+    kernels at ``chunk_q``/``chunk_k`` blocks (TPU; runs in interpret mode
+    elsewhere -- tests only)."""
     S = q.shape[1]
     if impl == "pallas":
-        from repro.kernels.flash_attention import flash_attention
         interp = jax.default_backend() != "tpu"
         return flash_attention(q, k, v, causal, min(chunk_q, S),
                                min(chunk_k, S), interp)
-    if impl == "chunked" or (impl == "auto" and S >= _FLASH_THRESHOLD
-                             and S == k.shape[1]):
-        if S == k.shape[1]:  # self-attention only
-            return _chunked_attention(q, k, v, causal, chunk_q, chunk_k)
+    if impl == "auto":
+        route, why = attention_route(q, k, v, causal, q_offset)
+        log = getattr(_routes, "log", None)
+        if log is not None:
+            B, _, H, hd = q.shape
+            log[f"attention ({B},{S},{H},{hd})"] = (
+                route + (f": {why}" if why else ""))
+        if route == "flash":
+            return flash_attention(q, k, v, causal,
+                                   *block_sizes(S, q.shape[-1]), False)
+        impl = route
+    if impl == "chunked" and S == k.shape[1]:  # self-attention only
+        return _chunked_attention(q, k, v, causal, chunk_q, chunk_k)
     return _dense_attention(q, k, v, causal, q_offset)
 
 

@@ -152,6 +152,8 @@ class TestFlashAttentionKernel:
                                    np.asarray(ref), atol=3e-2, rtol=3e-2)
 
     def test_gradients_flow_via_recompute_vjp(self):
+        """The Pallas backward recomputes the probabilities from the saved
+        logsumexp; its gradients are the dense path's."""
         from repro.kernels.flash_attention import flash_attention
         from repro.models.layers import _dense_attention
         q, k, v = self._rand(1, 128, 4, 2, 32, seed=3)
@@ -168,6 +170,80 @@ class TestFlashAttentionKernel:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-4, rtol=1e-4)
 
+    @pytest.mark.parametrize("B,S,H,K,hd,hdv,bq,bk", [
+        (1, 256, 4, 4, 64, 64, 64, 64),      # MHA, square blocks
+        (2, 256, 4, 2, 64, 64, 128, 64),     # GQA 2:1, wide q blocks
+        (1, 256, 8, 2, 96, 96, 64, 128),     # GQA 4:1, head dim 96
+        (1, 512, 2, 2, 96, 96, 256, 128),    # head dim 96, rectangular
+        (1, 256, 4, 1, 96, 64, 128, 128),    # MQA, v head dim below q's
+    ])
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_backward_matches_dense(self, B, S, H, K, hd, hdv, bq, bk,
+                                    causal):
+        from repro.kernels.flash_attention import flash_attention
+        from repro.models.layers import _dense_attention
+        ks = jax.random.split(jax.random.PRNGKey(S + H + hd), 4)
+        q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32)
+        k = jax.random.normal(ks[1], (B, S, K, hd), jnp.float32)
+        v = jax.random.normal(ks[2], (B, S, K, hdv), jnp.float32)
+        g = jax.random.normal(ks[3], (B, S, H, hdv), jnp.float32)
+
+        def grads(attn, *xs):
+            return jax.grad(lambda q, k, v: jnp.sum(attn(q, k, v) * g),
+                            argnums=(0, 1, 2))(*xs)
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal, bq, bk, True)
+
+        def dense(q, k, v):
+            return _dense_attention(q, k, v, causal)
+
+        for a, b in zip(grads(flash, q, k, v), grads(dense, q, k, v),
+                        strict=True):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-4, rtol=1e-4)
+        # bf16 operands: the same products in the input dtype, loosely
+        lo = [t.astype(jnp.bfloat16) for t in (q, k, v)]
+        for a, b in zip(grads(flash, *lo), grads(dense, q, k, v),
+                        strict=True):
+            assert a.dtype == jnp.bfloat16
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b), atol=6e-2, rtol=6e-2)
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("hd,bq,bk", [(64, 64, 128), (96, 128, 64)])
+    def test_logsumexp_residual(self, causal, hd, bq, bk):
+        from repro.kernels.flash_attention import _flash_fwd
+        B, S, H, K = 2, 256, 4, 2
+        q, k, v = self._rand(B, S, H, K, hd, seed=hd)
+        _, lse = _flash_fwd(q, k, v, causal, bq, bk, True)
+        assert lse.shape == (B * H, 1, S) and lse.dtype == jnp.float32
+        scores = jnp.einsum("bqkgh,bskh->bkgqs",
+                            q.reshape(B, S, K, H // K, hd), k) / hd ** 0.5
+        if causal:
+            scores = jnp.where(jnp.tril(jnp.ones((S, S), bool)), scores,
+                               -1e30)
+        ref = jax.nn.logsumexp(scores, axis=-1).reshape(B * H, S)
+        np.testing.assert_allclose(np.asarray(lse[:, 0]), np.asarray(ref),
+                                   atol=1e-5, rtol=1e-5)
+
+    def test_vjp_holds_nothing_of_size_s_by_s(self):
+        """Outside the kernels, neither the forward's residuals nor the
+        backward build a (S, S) array."""
+        from repro.kernels.flash_attention import flash_attention
+        S = 256
+        q, k, v = self._rand(1, S, 2, 2, 64, seed=5)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(flash_attention(q, k, v, True, 128, 128,
+                                                    True)),
+            argnums=(0, 1, 2)))(q, k, v)
+        shapes = [tuple(var.aval.shape) for eqn in jaxpr.eqns
+                  for var in eqn.outvars]
+        assert shapes and not [s for s in shapes if s[-2:] == (S, S)]
+        assert sum(e.primitive.name == "pallas_call"
+                   for e in jaxpr.eqns) == 2
+
     def test_chunked_oracle_matches_dense(self):
         from repro.kernels.ref import chunked_attention_ref
         from repro.models.layers import _dense_attention
@@ -177,3 +253,78 @@ class TestFlashAttentionKernel:
         ref = _dense_attention(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
+
+
+class TestFlashRoute:
+    """``flash_route``: the one rule that sends ``impl="auto"`` attention
+    to the Pallas kernels.  It reads shapes and the backend only."""
+
+    @pytest.mark.parametrize("backend,S,Skv,q_offset,hd,hdv,devices,want", [
+        ("tpu", 1024, 1024, 0, 64, 64, 1, True),
+        ("tpu", 2048, 2048, 0, 96, 96, 1, True),
+        ("tpu", 4096, 4096, 0, 128, 128, 1, True),
+        ("tpu", 8192, 8192, 0, 64, 64, 1, True),
+        ("tpu", 2048, 2048, 0, 256, 256, 1, True),   # a multiple of 128
+        ("cpu", 1024, 1024, 0, 64, 64, 1, "backend cpu"),
+        ("tpu", 1024, 2048, 0, 64, 64, 1, "not self-attention"),
+        ("tpu", 1024, 1024, 16, 64, 64, 1, "not self-attention"),
+        ("tpu", 1024, 1024, 0, 64, 64, 4, "GSPMD over 4 devices"),
+        ("tpu", 256, 256, 0, 96, 96, 1, True),
+        ("tpu", 128, 128, 0, 64, 64, 1, "S 128 < 256"),
+        ("tpu", 2000, 2000, 0, 64, 64, 1, "not a multiple of 256"),
+        ("tpu", 1152, 1152, 0, 64, 64, 1, "not a multiple of 256"),
+        ("tpu", 1024, 1024, 0, 192, 128, 1, "head dim 192"),  # MLA q/k
+        ("tpu", 1024, 1024, 0, 128, 160, 1, "head dim 160"),
+        ("tpu", 2 ** 20, 2 ** 20, 0, 128, 128, 1, "MiB VMEM"),
+    ])
+    def test_route(self, backend, S, Skv, q_offset, hd, hdv, devices, want):
+        from repro.kernels.flash_attention import flash_route
+        got = flash_route(backend, S, Skv, q_offset, hd, hdv, True, devices)
+        if want is True:
+            assert got is True
+        else:
+            assert isinstance(got, str) and want in got, got
+
+    @pytest.mark.parametrize("B,S,H,hd", [(8, 1024, 20, 64),
+                                          (2, 2048, 32, 96)],
+                             ids=["gpt2-large", "phi3-mini-8l"])
+    def test_benchmark_cells_route_to_flash_on_tpu(self, B, S, H, hd):
+        from repro.kernels.flash_attention import block_sizes, flash_route
+        assert flash_route("tpu", S, S, 0, hd, hd, True) is True
+        bq, bk = block_sizes(S, hd)
+        assert (bq, bk) == (512, 512) and S % bq == 0
+
+    @pytest.mark.parametrize("S,Skv,q_offset,want", [
+        (16, 16, 0, "dense"), (1024, 1024, 0, "dense"),
+        (2048, 2048, 0, "dense"), (8192, 8192, 0, "chunked"),
+        (16384, 16384, 0, "chunked"), (8192, 16384, 0, "dense"),
+        (1, 4096, 100, "dense"),
+    ])
+    def test_cpu_keeps_the_xla_choice(self, S, Skv, q_offset, want):
+        """Off the TPU, ``auto`` picks what it picked before the flash
+        route: chunked self-attention from 8192, dense below."""
+        from repro.models.layers import attention_route
+        q = jax.ShapeDtypeStruct((1, S, 4, 64), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((1, Skv, 4, 64), jnp.bfloat16)
+        route, why = attention_route(q, k, k, True, q_offset)
+        assert (route, why) == (want, "backend cpu")
+
+    def test_auto_on_cpu_is_dense_bitwise(self):
+        from repro.models.layers import _dense_attention, attention
+        ks = jax.random.split(jax.random.PRNGKey(2), 3)
+        q, k, v = (jax.random.normal(kk, (2, 64, 4, 32), jnp.bfloat16)
+                   for kk in ks)
+        np.testing.assert_array_equal(
+            np.asarray(attention(q, k, v), np.float32),
+            np.asarray(_dense_attention(q, k, v, True), np.float32))
+
+    def test_routes_recorded_per_call_site(self):
+        from repro.models.layers import attention, recording_attention_routes
+        q = jnp.zeros((2, 32, 4, 16), jnp.float32)
+        with recording_attention_routes() as log:
+            attention(q, q, q)
+            attention(q, q, q)                       # same site, one key
+            attention(q, q, q, impl="dense")         # not auto: no route
+        assert log == {"attention (2,32,4,16)": "dense: backend cpu"}
+        attention(q, q, q)                           # outside: nothing
+        assert len(log) == 1

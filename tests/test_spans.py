@@ -105,3 +105,17 @@ def test_a_span_closes_and_records_when_its_body_raises(name):
         raise KeyError(name)
     assert [(n, p, s) for n, p, s, *_ in rep.spans] == [(name, None, -1)]
     assert spans.open == []
+
+
+def test_attention_routes_recorded_and_logged_once_at_setup(capsys):
+    """Each attention call site's route sits in ``StepReport.routes`` beside
+    the kernel routes, and is logged once, at setup."""
+    rep = StepReport()
+    train("gpt2-60m", "rmnp", report=rep, **SMALL)
+    attention = {k: r for k, r in rep.routes.items()
+                 if k.startswith("attention ")}
+    assert list(attention.values()) == ["dense: backend cpu"]
+    (key,) = attention
+    assert key.startswith("attention (2,16,")
+    out = capsys.readouterr().out
+    assert out.count(f"[train] {key}: dense: backend cpu") == 1

@@ -71,6 +71,28 @@ def copied_params(text: str) -> Dict[int, List[str]]:
     return out
 
 
+def state_copies(text: str) -> int:
+    """Number of ENTRY ``copy`` ops that move a donated parameter (one in
+    the ``input_output_alias`` table) of at least ``BIG_LEAF_BYTES`` aside
+    unchanged: the copy's type, layout and memory space included, is the
+    parameter's own.  Such a copy exists because a consumer cannot write its
+    output over the donated input in place; a copy to another layout or
+    memory space (a transpose, a prefetch) is work of its own and is not
+    counted."""
+    comps, entry = H.parse_module(text)
+    comp = comps.get(entry or "")
+    if comp is None:
+        return 0
+    names = entry_param_ops(text)
+    donated = {a.param_number for a in H.module_io_aliases(text)}
+    ops = {op.name: op for op in comp.ops}
+    return sum(
+        1 for num, copies in copied_params(text).items() if num in donated
+        for name in copies
+        if ops[name].type_str == comp.symtab[names[num]]
+        and H.shape_bytes(ops[name].type_str) >= BIG_LEAF_BYTES)
+
+
 @register_pass
 class DonationPass(AnalysisPass):
     name = "donation"

@@ -36,10 +36,10 @@ def rmnp_bucket_update(g, v, *, beta: float, eps: float = 1e-8):
     ``pallas_call`` over a whole stacked bucket.
 
     g: (L, d_in, d_out) fp32 gradients; v: matching momentum in its storage
-    dtype (fp32 or bf16).  Returns (v_new in v.dtype, d fp32).  Momentum
-    buffers are donated where it actually helps — at the train-step jit
-    boundary (``donate_argnums`` on the outer step), where the old bucket's
-    allocation is reused for the new one."""
+    dtype (fp32 or bf16).  Returns (v_new in v.dtype, d fp32).  The kernel
+    writes v_new over v, so where the train step donates the momentum
+    (``donate_argnums`` on the outer step) the old bucket's allocation is
+    updated in place."""
     if _rm.rownorm_plan(g, v) is None:
         from repro.kernels.ref import rmnp_momentum_rownorm_ref
         return rmnp_momentum_rownorm_ref(g, v, beta=beta, eps=eps)

@@ -24,6 +24,13 @@ so the fp32 ``d`` bucket is never materialized in HBM and the separate
 ``apply_updates`` tree pass disappears — the optimizer becomes a single
 memory pass over (g, v, w).
 
+Both kernels write their state in place: each state operand is aliased to
+its output (``v -> v_new``; the apply kernel also ``w -> w_new``).  Where
+the caller donates the momentum and weights (the train step's
+``donate_argnums``), the kernel reads and writes the donated buffers
+directly; where it does not, XLA copies the operand first and the caller's
+arrays are left unchanged.
+
 Every launch is planned by :func:`plan_stripes`: its VMEM accounting sets
 the lane block (never below 128 lanes), the launch's scoped-VMEM limit,
 and whether a shape takes the kernel at all — a stripe that cannot fit
@@ -33,7 +40,7 @@ at 128 lanes (an embedding-sized fan-in) takes the XLA path instead
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Sequence
+from typing import Dict, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -121,13 +128,19 @@ def _kernel3d(g_ref, v_ref, v_out_ref, d_ref, *, beta: float, eps: float):
 
 def _stripe_call(kernel, name: str, operands, out_dtypes,
                  plan: Optional[StripePlan], *, interpret: bool,
-                 scalars=None):
+                 aliases: Dict[int, int], scalars=None):
     """Shared scaffolding for the column-stripe kernels: flatten leading
     dims (layer / expert stacks, bucket slices) into the outer grid axis,
     zero-pad d_out to the block, run one program per (l, stripe), slice the
     pad back off.  ``scalars`` (optional (k,) fp32) is prepended as a
     whole-array SMEM operand.  Padded columns are self-contained (their
-    norm is local garbage) and never escape the slice."""
+    norm is local garbage) and never escape the slice.
+
+    ``aliases`` maps an index into ``operands`` to the output written over
+    it (same shape and dtype).  The ``(L, d_in, n)`` reshape is a bitcast,
+    so an unpadded operand is still the caller's buffer; where d_out is
+    padded, the aliased operand is ``jnp.pad``'s fresh buffer instead, and
+    the kernel writes into that."""
     lead = operands[0].shape[:-2]
     d_in, n = operands[0].shape[-2:]
     if plan is None:
@@ -150,6 +163,7 @@ def _stripe_call(kernel, name: str, operands, out_dtypes,
     if scalars is not None:
         in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + in_specs
         ops3 = [scalars.astype(jnp.float32)] + ops3
+    shift = 0 if scalars is None else 1
     outs = pl.pallas_call(
         kernel,
         grid=grid,
@@ -157,6 +171,7 @@ def _stripe_call(kernel, name: str, operands, out_dtypes,
         out_specs=[spec] * len(out_dtypes),
         out_shape=[jax.ShapeDtypeStruct((L, d_in, n_p), dt)
                    for dt in out_dtypes],
+        input_output_aliases={i + shift: o for i, o in aliases.items()},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=plan.vmem_limit),
         interpret=interpret,
@@ -174,13 +189,14 @@ def _rownorm_2d(g, v, *, beta: float, eps: float = 1e-8,
     return _stripe_call(
         functools.partial(_kernel3d, beta=beta, eps=eps), "rmnp_rownorm",
         [g, v], [v.dtype, jnp.float32], rownorm_plan(g, v),
-        interpret=interpret)
+        interpret=interpret, aliases={1: 0})
 
 
 # momentum donation happens at the *train-step* jit boundary
 # (donate_argnums on the outer step fn): a donate annotation on this nested
-# jit would be dropped inside an outer jit, and the eager path pads d_out so
-# the buffers could not alias anyway
+# jit would be dropped inside an outer jit.  Inside a donating step the
+# kernel's v -> v_new alias makes the update in place; called eagerly, or
+# from a jit that does not donate, XLA copies v first
 rmnp_momentum_rownorm_2d = functools.partial(
     jax.jit, static_argnames=("beta", "eps", "interpret"))(_rownorm_2d)
 
@@ -211,7 +227,8 @@ def _rownorm_apply_2d(g, v, w, scalars, *, beta: float, eps: float = 1e-8,
     return _stripe_call(
         functools.partial(_kernel3d_apply, beta=beta, eps=eps),
         "rmnp_rownorm_apply", [g, v, w], [v.dtype, w.dtype],
-        rownorm_apply_plan(g, v, w), interpret=interpret, scalars=scalars)
+        rownorm_apply_plan(g, v, w), interpret=interpret,
+        aliases={1: 0, 2: 1}, scalars=scalars)
 
 
 rmnp_rownorm_apply_2d = functools.partial(

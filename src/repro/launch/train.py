@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis.donation import state_copies
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config
 from repro.configs.base import ModelConfig
@@ -60,6 +61,7 @@ class StepReport:
     hlo_text: str = ""
     memory: Any = None
     routes: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    state_copies: int = 0
     spans: List[Tuple[str, Optional[str], int, int, int]] = \
         dataclasses.field(default_factory=list)
     compiles: List[Tuple[int, int]] = dataclasses.field(default_factory=list)
@@ -226,11 +228,16 @@ def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
             compiled_ = jit_step_.lower(*args).compile()
         print(f"[train] step compiled in {compiling.seconds:.1f} s",
               flush=True)
+        hlo_text = compiled_.as_text()
+        copies = state_copies(hlo_text)
+        print(f"[train] donated state buffers copied per step: {copies}",
+              flush=True)
         if report is not None:
             report.compile_s = compiling.seconds
-            report.hlo_text = compiled_.as_text()
+            report.hlo_text = hlo_text
             report.memory = compiled_.memory_analysis()
             report.routes.update(attention)
+            report.state_copies = copies
         return compiled_, attention
 
     # one trace of the optimizer step gives both the launch count and the

@@ -167,3 +167,37 @@ def test_flash_kernels_land_in_forward_and_backward_scopes(topo,
     # recompute where memory allows (this toy model); the cells recompute
     assert fwd[0] is False and bwd == [True]
     assert all("jvp(forward)" in p for _, p in paths)
+
+
+def test_train_step_updates_donated_state_in_place(topo, monkeypatch):
+    """A fused-apply kernel train step at gpt2-large's bucket widths, two
+    layers deep: every RMNP kernel writes its momentum and weights over its
+    operands, so no donated momentum bucket and no single-leaf weight
+    bucket is copied aside before the kernel.  ``train`` raises placing
+    its state, after the step has compiled."""
+    import re
+
+    from repro.analysis.donation import copied_params, entry_param_ops
+    from repro.configs.base import ModelConfig
+    from repro.launch.train import StepReport
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = ModelConfig(name="gpt2-large-2l", family="dense", num_layers=2,
+                      d_model=1280, n_heads=20, n_kv_heads=20, d_ff=5120,
+                      vocab=50304, tie_embeddings=True)
+    report = StepReport()
+    with pytest.raises(Exception):
+        train(cfg, steps=1, batch=1, seq=128, reduced=False, fused=True,
+              fused_apply=True, use_kernel=True, log_every=0,
+              devices=[topo.devices[0]], report=report)
+    calls = re.findall(r"%rmnp_rownorm_apply[\w.]* = .* custom-call\(.*",
+                       report.hlo_text)
+    # the three block buckets; the embedding takes the XLA path
+    assert len(calls) == 3
+    # operands: scalars, g, v, w -> outputs v_new, w_new
+    assert all("output_to_operand_aliasing={{0}: (2, {}), {1}: (3, {})}"
+               in c for c in calls)
+    names = entry_param_ops(report.hlo_text)
+    copied = [names[n] for n in copied_params(report.hlo_text)]
+    assert not [n for n in copied
+                if re.search(r"opt_state_buckets__|w_in__|w_out__", n)]
+    assert report.state_copies == 0

@@ -55,6 +55,52 @@ class TestRmnpKernel:
             np.linalg.norm(np.array(d), axis=0), 1.0, atol=1e-4)
 
 
+class TestRmnpKernelAliasing:
+    """The stripe kernels write v_new (and w_new) over their v (and w)
+    operands.  The values are those of the jitted reference, bit for bit;
+    a caller that does not donate keeps its arrays, one that donates gets
+    the same values back."""
+
+    @staticmethod
+    def _kernel_and_ref(kernel):
+        from repro.kernels import rmnp_update as rm
+        from repro.kernels.ref import rmnp_rownorm_apply_ref
+        if kernel == "apply":
+            return (lambda g, v, w, sc: rm.rmnp_rownorm_apply_2d(
+                        g, v, w, sc, beta=0.95, interpret=True),
+                    lambda g, v, w, sc: rmnp_rownorm_apply_ref(
+                        g, v, w, sc[0], sc[1], beta=0.95))
+        return (lambda g, v, w, sc: rm.rmnp_momentum_rownorm_2d(
+                    g, v, beta=0.95, interpret=True),
+                lambda g, v, w, sc: rmnp_momentum_rownorm_ref(
+                    g, v, beta=0.95))
+
+    @pytest.mark.parametrize("kernel", ["apply", "precondition"])
+    @pytest.mark.parametrize("d_out", [256, 200], ids=["unpadded", "padded"])
+    @pytest.mark.parametrize("mdtype", [jnp.float32, jnp.bfloat16],
+                             ids=["fp32", "bf16"])
+    def test_aliased_state(self, kernel, d_out, mdtype):
+        fn, ref = self._kernel_and_ref(kernel)
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(d_out), 3)
+        g = jax.random.normal(k1, (2, 64, d_out))
+        v = jax.random.normal(k2, (2, 64, d_out)).astype(mdtype)
+        w = jax.random.normal(k3, (2, 64, d_out)).astype(jnp.bfloat16)
+        sc = jnp.array([0.01, 0.1], jnp.float32)
+        before = [np.array(x) for x in (g, v, w)]
+        want = [np.array(x) for x in jax.jit(ref)(g, v, w, sc)]
+        got = [np.array(x) for x in jax.jit(fn)(g, v, w, sc)]
+        for a, b in zip(got, want, strict=True):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(a, b)
+        for a, b in zip((g, v, w), before, strict=True):
+            np.testing.assert_array_equal(np.array(a), b)
+        donate = (1, 2) if kernel == "apply" else (1,)
+        donated = jax.jit(fn, donate_argnums=donate)(
+            g, jnp.array(v, copy=True), jnp.array(w, copy=True), sc)
+        for a, b in zip(donated, want, strict=True):
+            np.testing.assert_array_equal(np.array(a), b)
+
+
 class TestMatmulKernel:
     @pytest.mark.parametrize("m,k,n", [(8, 8, 8), (128, 256, 64),
                                        (100, 200, 72), (257, 129, 33),

@@ -113,7 +113,7 @@ def bench_size(name: str, layers: int, d: int, ns_steps: int, iters: int,
 
 
 def bench_fused(name: str, layers: int, d: int, iters: int) -> Dict:
-    """Shape-bucketed fused engine vs the per-leaf path: wall-clock per
+    """Shape-bucketed engine vs the per-leaf RMNP reference: wall-clock per
     optimizer step plus kernel launches per step.
 
     Launches are counted by tracing the Pallas (``use_kernel=True``) update
@@ -121,24 +121,31 @@ def bench_fused(name: str, layers: int, d: int, iters: int) -> Dict:
     and free even on CPU.  Wall-clock is measured on the Pallas path on TPU
     and on the XLA path on CPU (interpret-mode Pallas times the Python
     interpreter, not the math)."""
-    from repro.core.rmnp import rmnp
+    from repro.core import make_optimizer
+    from repro.core.rules import RmnpRule, per_leaf_reference
     from repro.train.step import optimizer_launches
 
     params, grads = _bucketed_tree(layers, d, jax.random.PRNGKey(0))
     on_tpu = jax.default_backend() == "tpu"
-    per_leaf = rmnp(constant(1e-3), use_kernel=on_tpu)
-    fused = rmnp(constant(1e-3), use_kernel=on_tpu, fused=True)
-    launches_leaf = optimizer_launches(rmnp(constant(1e-3), use_kernel=True), params)
-    launches_fused = optimizer_launches(
-        rmnp(constant(1e-3), use_kernel=True, fused=True), params)
+
+    def per_leaf(use_kernel):
+        return per_leaf_reference(RmnpRule(), constant(1e-3),
+                                  use_kernel=use_kernel)
+
+    def fused(use_kernel):
+        return make_optimizer("rmnp", lr_matrix=1e-3, fused=True,
+                              use_kernel=use_kernel)
+
+    launches_leaf = optimizer_launches(per_leaf(True), params)
+    launches_fused = optimizer_launches(fused(True), params)
 
     def step_of(opt):
         state = opt.init(params)
-        fn = jax.jit(lambda g, s, p: opt.update(g, s, p, 0))
+        fn = jax.jit(lambda g, s, p: opt.update_apply(g, s, p, 0))
         return time_fn(fn, grads, state, params, iters=iters)
 
-    t_leaf = step_of(per_leaf)
-    t_fused = step_of(fused)
+    t_leaf = step_of(per_leaf(on_tpu))
+    t_fused = step_of(fused(on_tpu))
     n_buckets = len({(s.shape[-2], s.shape[-1]) for s in params.values()})
     return {
         "size": name, "layers": layers, "d_model": d,
@@ -165,56 +172,6 @@ def _bucketed_tree(layers: int, d: int, key):
                     jax.random.fold_in(key, i * 1009 + si * 31 + c),
                     shape, jnp.float32)
     return params, grads
-
-
-def bench_fused_apply(name: str, layers: int, d: int, iters: int) -> Dict:
-    """Single-pass fused apply vs the two-pass baseline, timing the FULL
-    update (precondition + weight apply): the two-pass path materializes an
-    fp32 ``d`` bucket per shape then re-reads it in ``apply_updates``; the
-    single-pass path folds the weight update into the kernel and emits the
-    new params directly.
-
-    Wall-clock is measured on the XLA path on CPU / the Pallas path on TPU
-    (interpret-mode Pallas times the Python interpreter, not the math); the
-    memory claim — no full-bucket fp32 intermediate beyond the updated
-    weights — is verified by tracing the Pallas update and counting fp32
-    buffers at the largest bucket shape."""
-    from repro.core import apply_updates
-    from repro.core.rmnp import rmnp
-    from repro.train.step import optimizer_fp32_buffers
-
-    params, grads = _bucketed_tree(layers, d, jax.random.PRNGKey(0))
-    on_tpu = jax.default_backend() == "tpu"
-    two = rmnp(constant(1e-3), use_kernel=on_tpu, fused=True)
-    one = rmnp(constant(1e-3), use_kernel=on_tpu, fused_apply=True)
-
-    def two_pass(g, s, p, step):
-        u, s2 = two.update(g, s, p, step)
-        return apply_updates(p, u), s2
-
-    t_two = time_fn(jax.jit(two_pass), grads, two.init(params), params,
-                    jnp.int32(0), iters=iters)
-    t_one = time_fn(jax.jit(one.update_apply), grads, one.init(params),
-                    params, jnp.int32(0), iters=iters)
-
-    # traced memory claim, exact and free even on CPU: count buffers at the
-    # largest bucket shape, (layers, d, 4d)
-    bucket_shape = (layers, d, 4 * d)
-    buf_two = optimizer_fp32_buffers(
-        rmnp(constant(1e-3), use_kernel=True, fused=True), params, bucket_shape)
-    buf_one = optimizer_fp32_buffers(
-        rmnp(constant(1e-3), use_kernel=True, fused_apply=True), params,
-        bucket_shape)
-    return {
-        "bench": "fused_apply", "size": name, "layers": layers, "d_model": d,
-        "n_matrix_leaves": len(params),
-        "two_pass_step_s": t_two,
-        "single_pass_step_s": t_one,
-        "single_pass_speedup": t_two / t_one if t_one else float("inf"),
-        "fp32_bucket_buffers_two_pass": buf_two,
-        "fp32_bucket_buffers_single_pass": buf_one,
-        "timed_backend": "pallas" if on_tpu else "xla",
-    }
 
 
 def bench_zero2(name: str, layers: int, d: int, n_dev: int) -> Dict:
@@ -277,12 +234,8 @@ def main(argv=None):
     ap.add_argument("--no-derive", dest="derive", action="store_false",
                     help="time every matrix directly (TPU harness)")
     ap.add_argument("--fused", action="store_true",
-                    help="also benchmark the shape-bucketed fused engine "
+                    help="also benchmark the shape-bucketed engine "
                          "(wall-clock + launches per optimizer step)")
-    ap.add_argument("--fused-apply", action="store_true",
-                    help="benchmark the single-pass fused apply (weight "
-                         "update folded into the kernel) vs the two-pass "
-                         "baseline; emits BENCH_fused_step.json")
     ap.add_argument("--fused-layers", type=int, default=4,
                     help="layer count for the fused section (0 = the size's "
                          "real depth; capped by default to bound memory)")
@@ -325,24 +278,6 @@ def main(argv=None):
         print("\n== fused update engine: launches + wall-clock per step ==")
         print_table(["size", "leaves", "buckets", "launch/leaf", "launch/fused",
                      "leaf ms", "fused ms", "speedup"], frows)
-
-    if args.fused_apply:
-        arows, arecs = [], []
-        for name in sizes:
-            layers, d = GPT2_SIZES[name]
-            fl = args.fused_layers or layers
-            ar = bench_fused_apply(name, min(fl, layers), d, args.iters)
-            recs.append(ar)
-            arecs.append(ar)
-            arows.append([name, f"{1e3 * ar['two_pass_step_s']:.2f}",
-                          f"{1e3 * ar['single_pass_step_s']:.2f}",
-                          f"{ar['single_pass_speedup']:.2f}x",
-                          ar["fp32_bucket_buffers_two_pass"],
-                          ar["fp32_bucket_buffers_single_pass"]])
-        print("\n== single-pass fused apply: full update wall-clock ==")
-        print_table(["size", "two-pass ms", "1-pass ms", "speedup",
-                     "fp32 bufs 2p", "fp32 bufs 1p"], arows)
-        write_artifact("BENCH_fused_step", arecs)
 
     if args.zero2:
         zrows, zrecs = [], []

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.core import cosine_with_warmup, global_dominance, mixed_optimizer
+from repro.core import cosine_with_warmup, global_dominance, make_optimizer
 from repro.data.pipeline import make_stream
 from repro.models import init_params
 from repro.train.step import make_train_step
@@ -19,9 +19,9 @@ from repro.train.step import make_train_step
 STEPS = 50
 
 cfg = get_config("gpt2-small").reduced()
-opt = mixed_optimizer("rmnp",
-                      lr_matrix=cosine_with_warmup(2e-2, STEPS),
-                      lr_adamw=cosine_with_warmup(3e-3, STEPS))
+opt = make_optimizer("rmnp",
+                     lr_matrix=cosine_with_warmup(2e-2, STEPS),
+                     lr_adamw=cosine_with_warmup(3e-3, STEPS))
 
 params = init_params(cfg, jax.random.PRNGKey(0))
 opt_state = opt.init(params)

@@ -13,7 +13,7 @@ static analysis instead of ad-hoc per-PR checks:
 * :mod:`repro.analysis.framework` — pass framework: severity-ranked
   :class:`Finding`, the pass registry, and the per-combo runner.
 * :mod:`repro.analysis.lowering` — lowers (never executes) every
-  registry optimizer x engine x wire x accum combination on an abstract
+  registry optimizer x wire x accum combination on an abstract
   4-device mesh via ``jax.eval_shape`` / AOT ``.lower()``.
 * the passes — :mod:`memory`, :mod:`sharding`, :mod:`donation`,
   :mod:`overlap`, :mod:`kernel_lint`, :mod:`conventions`.

@@ -34,15 +34,12 @@ def _parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="python -m repro.analysis.check",
         description="Static (lower-only) invariant checks over the "
-                    "optimizer x engine x wire matrix.")
+                    "optimizer x wire matrix.")
     p.add_argument("--all", action="store_true",
                    help="check the full combo matrix (default when no "
                         "filter is given)")
     p.add_argument("--optimizer", action="append", default=None,
                    help="restrict to an optimizer (repeatable)")
-    p.add_argument("--engine", action="append", default=None,
-                   choices=["bucketed", "single-pass"],
-                   help="restrict to an engine (repeatable)")
     p.add_argument("--wire", action="append", default=None,
                    choices=["fp32", "int8-ef"],
                    help="restrict to a wire format (repeatable)")
@@ -65,7 +62,7 @@ def main(argv: List[str] | None = None) -> int:
     from repro.analysis import lowering
 
     combos = lowering.build_combos(
-        optimizers=args.optimizer, engines=args.engine,
+        optimizers=args.optimizer,
         wires=args.wire, accums=args.accum)
     catalog = pass_catalog()
     catalog_names = [entry["name"] for entry in catalog]

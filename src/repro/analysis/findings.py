@@ -1,7 +1,7 @@
 """Severity-ranked findings and the stable ``ANALYSIS_report.json`` schema.
 
 A finding is one violated (or degraded) invariant, attributed to a pass
-and, when applicable, to the optimizer x engine x wire x accum combo whose
+and, when applicable, to the optimizer x wire x accum combo whose
 lowered program exhibited it.  The report schema is stable across PRs so
 CI artifacts diff cleanly:
 
@@ -41,7 +41,7 @@ class Finding:
     severity: Severity
     code: str             # stable machine code, e.g. "full-bucket-fp32"
     message: str          # human explanation, names the offending object
-    combo: str = ""       # combo id ("rmnp/single-pass/fp32/accum1") or ""
+    combo: str = ""       # combo id ("rmnp/fp32/accum1") or ""
     location: str = ""    # op / file / bucket the finding points at
 
     def as_dict(self) -> Dict[str, str]:

@@ -1,6 +1,7 @@
 """The pass framework: combos, per-combo artifacts, and the registry.
 
-A *combo* is one point of the optimizer x engine x wire x accum matrix.
+A *combo* is one point of the optimizer x wire x accum matrix, lowered
+through the bucketed engine's ZeRO-2 path.
 The lowering harness (:mod:`repro.analysis.lowering`) turns a combo into
 :class:`Artifacts` — the traced jaxpr and AOT-compiled HLO of the real
 ``make_dp_train_step`` program, plus the static metadata the passes need
@@ -20,29 +21,20 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 from repro.analysis import hlo as hlo_mod
 from repro.analysis.findings import Finding, Severity
 
-ENGINES = ("bucketed", "single-pass")
 WIRES = ("fp32", "int8-ef")
 
 
 @dataclasses.dataclass(frozen=True)
 class Combo:
-    """One optimizer x engine x wire x accum point.
-
-    ``engine="bucketed"`` is the two-pass bucketed engine (replicated
-    state — the full fp32 direction bucket is its *definition*, so the
-    memory pass does not apply); ``engine="single-pass"`` is the fused
-    ZeRO-2 path (``update_apply_sharded`` under ``shard_map``), where
-    every memory/sharding/overlap invariant must hold."""
+    """One optimizer x wire x accum point, lowered through the bucketed
+    engine's ZeRO-2 path (``update_apply_sharded`` under ``shard_map``),
+    where every memory/sharding/overlap invariant must hold."""
     optimizer: str
-    engine: str            # "bucketed" | "single-pass"
     wire: str              # "fp32" | "int8-ef"
     accum: int = 1
     guard: bool = False    # in-graph non-finite guard + bitwise step skip
 
     def __post_init__(self):
-        if self.engine not in ENGINES:
-            raise ValueError(f"engine must be one of {ENGINES}, "
-                             f"got {self.engine!r}")
         if self.wire not in WIRES:
             raise ValueError(f"wire must be one of {WIRES}, "
                              f"got {self.wire!r}")
@@ -50,16 +42,12 @@ class Combo:
             raise ValueError(f"accum must be >= 1, got {self.accum}")
 
     @property
-    def zero2(self) -> bool:
-        return self.engine == "single-pass"
-
-    @property
     def compress(self) -> bool:
         return self.wire == "int8-ef"
 
     @property
     def id(self) -> str:
-        base = f"{self.optimizer}/{self.engine}/{self.wire}/accum{self.accum}"
+        base = f"{self.optimizer}/{self.wire}/accum{self.accum}"
         return base + "/guard" if self.guard else base
 
 
@@ -132,22 +120,14 @@ class Artifacts:
 
 class AnalysisPass:
     """Base checker.  Subclasses set ``name``/``description``/``scope``
-    and implement ``run``; ``applies`` gates combos the invariant is not
-    defined for (returning False records an INFO skip, not silence)."""
+    and implement ``run``."""
 
     name = "base"
     description = ""
     scope = "combo"            # "combo" | "repo"
 
-    def applies(self, combo: Combo) -> bool:
-        return True
-
     def run(self, artifacts: Optional[Artifacts]) -> List[Finding]:
         raise NotImplementedError
-
-    def skip_finding(self, combo: Combo, why: str) -> Finding:
-        return Finding(pass_name=self.name, severity=Severity.INFO,
-                       code="not-applicable", message=why, combo=combo.id)
 
 
 _REGISTRY: Dict[str, Callable[[], AnalysisPass]] = {}
@@ -191,11 +171,6 @@ def run_passes(artifacts_list: Sequence[Artifacts],
             findings.extend(p.run(None))
             continue
         for art in artifacts_list:
-            if not p.applies(art.combo):
-                findings.append(p.skip_finding(
-                    art.combo, f"{name}: invariant not defined for "
-                    f"{art.combo.engine} engine"))
-                continue
             for f in p.run(art):
                 key = (f.code, f.combo, f.location)
                 if f.code.startswith("hlo-parse-"):

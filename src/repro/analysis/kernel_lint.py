@@ -52,8 +52,7 @@ def _temps_for(name: str) -> Optional[int]:
     """Body temporaries the stripe accounting charges a kernel, by name."""
     from repro.kernels import rmnp_update as rm
 
-    return {"rmnp_rownorm": rm.PRECOND_TEMPS,
-            "rmnp_rownorm_apply": rm.APPLY_TEMPS}.get(name)
+    return {"rmnp_rownorm_apply": rm.APPLY_TEMPS}.get(name)
 
 
 def _trace_targets():
@@ -70,10 +69,6 @@ def _trace_targets():
     targets = []
     for (ll, d_in, d_out) in LINT_SHAPES:
         g = jnp.zeros((ll, d_in, d_out), jnp.float32)
-        targets.append((
-            f"rmnp_bucket_update[{ll}x{d_in}x{d_out}]",
-            lambda g=g: kops.rmnp_bucket_update(g, g, beta=0.95),
-            rm.rownorm_plan(g, g) is None))
         targets.append((
             f"rmnp_bucket_update_apply[{ll}x{d_in}x{d_out}]",
             lambda g=g: kops.rmnp_bucket_update_apply(

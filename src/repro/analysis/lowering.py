@@ -1,4 +1,4 @@
-"""Lower (never execute) every optimizer x engine x wire x accum combo.
+"""Lower (never execute) every optimizer x wire x accum combo.
 
 Each combo builds the REAL training step — ``make_dp_train_step`` on the
 reduced gpt2-60m config over an abstract 4-device ``data`` mesh — and
@@ -12,11 +12,10 @@ produces :class:`Artifacts` from two compiler views of it:
 Nothing is ever run: params, optimizer state and batch are
 ``jax.eval_shape`` / ``ShapeDtypeStruct`` abstractions end to end.
 
-Engine semantics: ``bucketed`` is the replicated-state shape-bucketed
-engine (two-pass update + apply_updates); ``single-pass`` is the fused
-ZeRO-2 path (``shard_axis="data", shard_size=4``, reduce-scattered
-gradient shards, pipelined schedule forced with ``overlap=True`` so the
-serialized fallback never masks a pipelining regression).  Wire
+Every combo lowers the bucketed engine's ZeRO-2 path
+(``shard_axis="data", shard_size=4``, reduce-scattered gradient shards,
+pipelined schedule forced with ``overlap=True`` so the serialized
+fallback never masks a pipelining regression).  Wire
 ``int8-ef`` turns on the int8 error-feedback gradient compression.
 
 Requires >= 4 CPU devices (``XLA_FLAGS=--xla_force_host_platform_\
@@ -27,7 +26,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple
 
-from repro.analysis.framework import Artifacts, Combo, DonatedLeaf, ENGINES, WIRES
+from repro.analysis.framework import Artifacts, Combo, DonatedLeaf, WIRES
 
 N_DEV = 4
 _LR = 1e-2
@@ -38,33 +37,29 @@ _FIXTURE: Dict[str, object] = {}
 
 
 def build_combos(optimizers: Optional[List[str]] = None,
-                 engines: Optional[List[str]] = None,
                  wires: Optional[List[str]] = None,
                  accums: Optional[List[int]] = None) -> List[Combo]:
-    """The full matrix: every registry optimizer x engine x wire at
+    """The full matrix: every registry optimizer x wire at
     ``accum=1``, plus the rmnp ZeRO-2 accumulation points (the pipelined
     schedule interacts with the accumulation scan, so both wires get an
     ``accum=4`` combo).  Filters narrow the matrix for the CLI."""
     from repro.core import optimizer_names
 
     names = list(optimizers) if optimizers else list(optimizer_names())
-    combos = [Combo(n, e, w, 1)
-              for n in names for e in ENGINES for w in WIRES]
+    combos = [Combo(n, w, 1) for n in names for w in WIRES]
     if not optimizers or "rmnp" in names:
-        combos.append(Combo("rmnp", "single-pass", "fp32", 4))
-        combos.append(Combo("rmnp", "single-pass", "int8-ef", 4))
+        combos.append(Combo("rmnp", "fp32", 4))
+        combos.append(Combo("rmnp", "int8-ef", 4))
     # guarded lowerings: the non-finite guard's post-update selects must
     # not cost the pipelined step its zero serialization edges, its
     # donation aliasing or its memory profile — rmnp + normuon on both
     # wires (the fault-injection proof matrix) plus the accum interaction
     for n in ("rmnp", "normuon"):
         if not optimizers or n in names:
-            combos += [Combo(n, "single-pass", w, 1, guard=True)
+            combos += [Combo(n, w, 1, guard=True)
                        for w in WIRES]
     if not optimizers or "rmnp" in names:
-        combos.append(Combo("rmnp", "single-pass", "fp32", 4, guard=True))
-    if engines:
-        combos = [c for c in combos if c.engine in engines]
+        combos.append(Combo("rmnp", "fp32", 4, guard=True))
     if wires:
         combos = [c for c in combos if c.wire in wires]
     if accums:
@@ -105,12 +100,8 @@ def make_combo_optimizer(combo: Combo):
     """The registry optimizer a combo lowers with."""
     from repro.core import make_optimizer
 
-    config = {"lr_matrix": _LR}
-    if combo.engine == "single-pass":
-        config.update(shard_axis="data", shard_size=N_DEV)
-    else:
-        config.update(fused=True)
-    return make_optimizer(combo.optimizer, config)
+    return make_optimizer(combo.optimizer, lr_matrix=_LR, shard_axis="data",
+                          shard_size=N_DEV)
 
 
 def _donated_leaves(params, opt_state) -> Tuple[DonatedLeaf, ...]:
@@ -149,11 +140,10 @@ def lower_combo(combo: Combo, *, break_mode: Optional[str] = None) -> Artifacts:
     params, comp, batch = fx["params"], fx["comp"], fx["batch"]
     opt_state = jax.eval_shape(opt.init, params)
 
-    kwargs = dict(compress=combo.compress, accum=combo.accum,
-                  guard=combo.guard)
-    if combo.zero2:
-        kwargs.update(zero2=True, opt_state=opt_state, overlap=True)
-    base_step = make_dp_train_step(fx["cfg"], opt, fx["mesh"], **kwargs)
+    base_step = make_dp_train_step(
+        fx["cfg"], opt, fx["mesh"], compress=combo.compress,
+        accum=combo.accum, guard=combo.guard, zero2=True,
+        opt_state=opt_state, overlap=True)
 
     if break_mode == "gather-momentum":
         from jax.sharding import PartitionSpec as P
@@ -196,4 +186,4 @@ def lower_combo(combo: Combo, *, break_mode: Optional[str] = None) -> Artifacts:
     return Artifacts(
         combo=combo, jaxpr=jaxpr, hlo_text=hlo, buckets=tuple(meta),
         donated=_donated_leaves(params, opt_state), n_dev=N_DEV,
-        overlap=combo.zero2)
+        overlap=True)

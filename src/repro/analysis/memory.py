@@ -7,7 +7,7 @@ exist.  This pass generalizes the ad-hoc ``count_buffer_eqns`` test
 (``kernels/ops.py``) to every registered rule's full state surface:
 
 * the full ``(padded, d_in, d_out)`` fp32 bucket (a gradient gather, a
-  two-pass ``d`` buffer, or a replicated momentum buffer leaking in);
+  ``d`` buffer, or a replicated momentum buffer leaking in);
 * every slot stripe at its FULL ``(padded, 1, d_out)`` shape — sharded
   rules (NorMuon's ``nu``, Nora's ``r``) must only ever hold the
   ``padded/N`` shard.
@@ -23,7 +23,7 @@ from typing import List, Tuple
 
 from repro.analysis.findings import Finding, Severity
 from repro.analysis.framework import (
-    AnalysisPass, Artifacts, Combo, register_pass,
+    AnalysisPass, Artifacts, register_pass,
 )
 
 EXCLUDE_PRIMS = frozenset({"all_gather", "reshape", "shard_map"})
@@ -64,11 +64,6 @@ class MemoryPass(AnalysisPass):
     description = ("no full-bucket fp32 gradient/momentum/slot "
                    "intermediate in the ZeRO-2 step jaxpr")
     scope = "combo"
-
-    def applies(self, combo: Combo) -> bool:
-        # the bucketed two-pass engine materializes the full fp32 ``d``
-        # bucket by design; the invariant is defined for ZeRO-2 only
-        return combo.zero2
 
     def run(self, artifacts: Artifacts) -> List[Finding]:
         out: List[Finding] = []

@@ -18,7 +18,7 @@ from typing import Dict, List, Optional
 from repro.analysis import hlo as H
 from repro.analysis.findings import Finding, Severity
 from repro.analysis.framework import (
-    AnalysisPass, Artifacts, Combo, register_pass,
+    AnalysisPass, Artifacts, register_pass,
 )
 
 
@@ -103,11 +103,6 @@ class OverlapPass(AnalysisPass):
     description = ("no update-output -> gradient-collective serialization "
                    "edge in the compiled ZeRO-2 step")
     scope = "combo"
-
-    def applies(self, combo: Combo) -> bool:
-        # only the ZeRO-2 path has per-bucket collective/update chains to
-        # serialize; the bucketed two-pass engine is replicated-state
-        return combo.zero2
 
     def run(self, artifacts: Artifacts) -> List[Finding]:
         out = artifacts.parse_findings(self.name)

@@ -17,7 +17,7 @@ from typing import Dict, List, Optional, Tuple
 from repro.analysis import hlo as H
 from repro.analysis.findings import Finding, Severity
 from repro.analysis.framework import (
-    AnalysisPass, Artifacts, Combo, register_pass,
+    AnalysisPass, Artifacts, register_pass,
 )
 
 
@@ -68,9 +68,6 @@ class ShardingPass(AnalysisPass):
     description = ("no all-gather replicates ZeRO-sharded momentum or "
                    "slot stripes (one weight gather per bucket)")
     scope = "combo"
-
-    def applies(self, combo: Combo) -> bool:
-        return combo.zero2
 
     def run(self, artifacts: Artifacts) -> List[Finding]:
         out = artifacts.parse_findings(self.name)

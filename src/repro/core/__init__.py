@@ -2,11 +2,7 @@
 Muown / Nora / AdamW family, mixed update strategy, the generic bucketed
 engine, schedules and preconditioner diagnostics."""
 from repro.core.adamw import adamw  # noqa: F401
-from repro.core.bucketing import (  # noqa: F401
-    BucketPlan,
-    build_plan,
-    fused_rownorm_update,
-)
+from repro.core.bucketing import BucketPlan, build_plan  # noqa: F401
 from repro.core.dominance import dominance_ratios, global_dominance  # noqa: F401
 from repro.core.engine import BucketedState  # noqa: F401
 from repro.core.mixed import (  # noqa: F401
@@ -18,9 +14,9 @@ from repro.core.mixed import (  # noqa: F401
     mixed_optimizer,
     momentum_for_diagnostics,
 )
-from repro.core.muon import muon, newton_schulz  # noqa: F401
+from repro.core.muon import newton_schulz  # noqa: F401
 from repro.core.registry import make_optimizer, optimizer_names  # noqa: F401
-from repro.core.rmnp import rmnp, rms_lr_scale, row_normalize  # noqa: F401
+from repro.core.rmnp import rms_lr_scale, row_normalize  # noqa: F401
 from repro.core.rules import (  # noqa: F401
     MatrixUpdateRule,
     make_rule,

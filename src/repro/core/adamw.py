@@ -6,7 +6,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.types import Optimizer, PyTree, Schedule
+from repro.core.types import Optimizer, PyTree, Schedule, update_then_apply
 
 
 class AdamWState(NamedTuple):
@@ -41,4 +41,5 @@ def adamw(lr: Schedule, b1: float = 0.9, b2: float = 0.95,
                 lambda x: x[i], out, is_leaf=lambda x: isinstance(x, tuple))
         return pick(0), AdamWState(mu=pick(1), nu=pick(2))
 
-    return Optimizer(init=init, update=update)
+    return Optimizer(init=init, update=update,
+                     update_apply=update_then_apply(update))

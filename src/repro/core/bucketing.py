@@ -10,7 +10,8 @@ matrix shapes (qkv, attn-out, mlp-in, mlp-out, ...), so we:
      flattening leading scan/expert axes (a ``(layers, d, 4d)`` stack
      contributes ``layers`` slices to the ``d x 4d`` bucket),
   2. stack each bucket into a single ``(L, d_in, d_out)`` operand, and
-  3. run the 3-D RMNP kernel once per *bucket* instead of once per *leaf*.
+  3. run the rule's single-pass apply (the RMNP kernel) once per *bucket*
+     instead of once per *leaf*.
 
 The leaf->bucket plan is pure static metadata (paths, shapes, offsets):
 it is computed once at optimizer ``init`` and reused by ``update``; the
@@ -117,10 +118,9 @@ def plan_signature(params: PyTree,
 
 def build_plan(params: PyTree,
                predicate: Optional[Callable[[str, jax.Array], bool]] = None,
-               strict: bool = False, pad_multiple: int = 1) -> BucketPlan:
+               pad_multiple: int = 1) -> BucketPlan:
     """Group leaves selected by ``predicate`` (default: ``ndim >= 2``) into
-    ``(d_in, d_out)`` buckets.  ``strict=True`` raises on any rejected leaf
-    (used by the pure-matrix ``rmnp`` optimizer, which has no AdamW side).
+    ``(d_in, d_out)`` buckets; the other leaves stay out of the plan.
 
     ``pad_multiple`` (the ZeRO shard-axis size) rounds every bucket's
     stacked ``L`` up to a multiple, so uneven buckets shard instead of
@@ -135,10 +135,6 @@ def build_plan(params: PyTree,
         is_mat = (predicate(path, leaf) if predicate is not None
                   else getattr(leaf, "ndim", 0) >= 2)
         if not is_mat:
-            if strict:
-                raise ValueError(
-                    f"fused RMNP requires matrix leaves; {path!r} has shape "
-                    f"{getattr(leaf, 'shape', None)}")
             continue
         d_in, d_out = leaf.shape[-2], leaf.shape[-1]
         groups.setdefault((d_in, d_out), []).append((path, tuple(leaf.shape)))
@@ -313,9 +309,8 @@ def scatter(plan: BucketPlan, stacked: Dict[str, jax.Array],
     slices beyond ``size`` are never read — padded buckets scatter for free.
     ``cast=True`` restores each base leaf's dtype — needed when the bucket
     was gathered without an explicit dtype and a mixed-dtype bucket promoted
-    on concatenation (the fused-apply path scatters *params*, whose dtypes
-    must stay stable across steps; the two-pass path scatters fp32 updates
-    and must NOT cast)."""
+    on concatenation (the engine scatters *params*, whose dtypes must stay
+    stable across steps)."""
     from repro.core.types import map_with_path
 
     slices = {}
@@ -380,35 +375,6 @@ def repad_buckets(plan: BucketPlan,
     return out
 
 
-def fused_rownorm_update(plan: BucketPlan,
-                         grad_buckets: Dict[str, jax.Array],
-                         mom_buckets: Dict[str, jax.Array],
-                         *, beta: float, eps: float,
-                         use_kernel: bool = False):
-    """One fused momentum-EMA + row-normalize pass per bucket.
-
-    Returns ``(d_buckets fp32, new_mom_buckets)`` with momentum kept in its
-    storage dtype (fp32 or bf16).  ``use_kernel`` selects the Pallas kernel
-    (one ``pallas_call`` per bucket); otherwise a single XLA pass per bucket.
-    """
-    from repro.core.rmnp import row_normalize
-
-    d_out, v_out = {}, {}
-    for b in plan.buckets:
-        g = grad_buckets[b.key]
-        v = mom_buckets[b.key]
-        if use_kernel:
-            from repro.kernels import ops as kops
-            v_new, d = kops.rmnp_bucket_update(g, v, beta=beta, eps=eps)
-        else:
-            v_new32 = beta * v.astype(jnp.float32) + (1.0 - beta) * g.astype(jnp.float32)
-            d = row_normalize(v_new32, eps)
-            v_new = v_new32.astype(v.dtype)
-        d_out[b.key] = d
-        v_out[b.key] = v_new
-    return d_out, v_out
-
-
 def shard_count(bucket: Bucket, l_loc: int) -> int:
     """Number of ZeRO shards implied by a local momentum buffer of ``l_loc``
     slices: 1 (the full padded buffer) or ``padded_size / l_loc``.  Any
@@ -426,93 +392,3 @@ def shard_count(bucket: Bucket, l_loc: int) -> int:
             f"a different mesh or built with a different shard_size?")
     return psize // l_loc
 
-
-def _apply_one(g, v, w, scale, weight_decay, beta, eps, use_kernel):
-    if use_kernel:
-        from repro.kernels import ops as kops
-        return kops.rmnp_bucket_update_apply(
-            g, v, w, scale, weight_decay, beta=beta, eps=eps)
-    from repro.kernels.ref import rmnp_rownorm_apply_ref
-    return rmnp_rownorm_apply_ref(
-        g, v, w, scale, weight_decay, beta=beta, eps=eps)
-
-
-def bucket_update_apply(bucket: Bucket, g: jax.Array, v: jax.Array,
-                        w: jax.Array, *, scale, weight_decay: float,
-                        beta: float, eps: float, use_kernel: bool = False,
-                        shard_axis: Optional[str] = None):
-    """Single-pass fused update of one stacked bucket, ZeRO-1 aware.
-
-    ``g`` / ``w`` are the full ``(padded L, d_in, d_out)`` gradient / weight
-    operands (both exist per step anyway); ``v`` is the stacked momentum —
-    either the full padded buffer, or this rank's ``(padded L / N, ...)``
-    shard when the optimizer state is ZeRO-sharded along ``L`` over
-    ``shard_axis`` (the per-bucket decision made by
-    :func:`repro.distributed.sharding.bucket_specs`; with a plan padded to
-    the axis size every bucket shards, uneven ``L`` included).  On a shard
-    the kernel runs over the local slices only and the updated weight
-    slices are all-gathered back to the full bucket; momentum stays sharded.
-    A momentum buffer whose slice count divides nothing raises (stale state
-    / wrong mesh) instead of slicing garbage.
-
-    Returns ``(w_new full, v_new in v's layout)``; no fp32 ``d`` buffer is
-    materialized on either path.
-    """
-    l_loc = v.shape[0]
-    n_shards = shard_count(bucket, l_loc)
-    if g.shape[0] != bucket.padded or w.shape[0] != bucket.padded:
-        raise ValueError(
-            f"bucket {bucket.key!r}: gradient/weight operands have "
-            f"{g.shape[0]}/{w.shape[0]} slices, expected the padded bucket "
-            f"size {bucket.padded}")
-    if n_shards > 1:
-        if shard_axis is None:
-            raise ValueError(
-                f"bucket {bucket.key!r}: momentum holds {l_loc} of "
-                f"{bucket.padded} slices but no shard_axis was given")
-        idx = jax.lax.axis_index(shard_axis)
-        g = jax.lax.dynamic_slice_in_dim(g, idx * l_loc, l_loc, axis=0)
-        w_loc = jax.lax.dynamic_slice_in_dim(w, idx * l_loc, l_loc, axis=0)
-    else:
-        w_loc = w
-    v_new, w_new = _apply_one(g, v, w_loc, scale, weight_decay, beta, eps,
-                              use_kernel)
-    if n_shards > 1:
-        w_new = jax.lax.all_gather(w_new, shard_axis, axis=0, tiled=True)
-    return w_new, v_new
-
-
-def bucket_update_apply_sharded(bucket: Bucket, g_shard: jax.Array,
-                                v: jax.Array, w_chunks: jax.Array, *,
-                                scale, weight_decay: float, beta: float,
-                                eps: float, use_kernel: bool = False,
-                                shard_axis: str):
-    """ZeRO-2 single-pass fused update of one stacked bucket: gradient
-    arrives *already reduced and sharded* (this rank's ``(padded L / N,
-    d_in, d_out)`` mean-gradient shard from
-    :func:`repro.distributed.compression.exact_reduce_scatter` /
-    ``compressed_reduce_scatter_leaf``), momentum ``v`` is the matching
-    shard, and ``w_chunks`` is the ``(N, padded L / N, d_in, d_out)``
-    chunked weight operand from :func:`gather_chunks`.  The kernel runs
-    shard-in/shard-out and only the updated weight slices are all-gathered
-    — the full mean-gradient bucket never exists on any rank.
-
-    Returns ``(w_new full padded bucket, v_new shard)``."""
-    l_loc = v.shape[0]
-    n_shards = shard_count(bucket, l_loc)
-    if g_shard.shape[0] != l_loc:
-        raise ValueError(
-            f"bucket {bucket.key!r}: gradient shard has {g_shard.shape[0]} "
-            f"slices but the momentum shard has {l_loc}")
-    if w_chunks.shape[:2] != (n_shards, l_loc):
-        raise ValueError(
-            f"bucket {bucket.key!r}: weight chunks have shape "
-            f"{w_chunks.shape[:2]}, expected ({n_shards}, {l_loc}) — "
-            f"gather_chunks n_chunks must equal the shard count")
-    idx = jax.lax.axis_index(shard_axis)
-    w_loc = jax.lax.dynamic_index_in_dim(w_chunks, idx, axis=0,
-                                         keepdims=False)
-    v_new, w_new = _apply_one(g_shard, v, w_loc, scale, weight_decay, beta,
-                              eps, use_kernel)
-    w_new = jax.lax.all_gather(w_new, shard_axis, axis=0, tiled=True)
-    return w_new, v_new

@@ -1,14 +1,13 @@
 """Generic shape-bucketed optimizer engine, parameterized by a
 :class:`repro.core.rules.MatrixUpdateRule`.
 
-This module owns everything the RMNP and mixed fused optimizers used to
-duplicate: the cached leaf->bucket plan, stacked momentum (+ per-rule slot
-stripes) initialization, the two-pass bucket update, the ZeRO-1-aware fused
-apply, and the ZeRO-2 per-bucket sharded apply with the clip scale folded
-into each chain.  ``core/rmnp.py``, ``core/muon.py`` and ``core/mixed.py``
-are thin compositions over it, so a new update rule inherits ZeRO-1/2
-sharding, padded uneven buckets, int8 error-feedback and pipelined overlap
-with zero new distributed code.
+This module owns the rule-agnostic machinery of the matrix partition: the
+cached leaf->bucket plan, stacked momentum (+ per-rule slot stripes)
+initialization, the ZeRO-1-aware single-pass apply, and the ZeRO-2
+per-bucket sharded apply with the clip scale folded into each chain.
+``core/mixed.py`` composes it with the per-leaf AdamW sweep, so a new
+update rule inherits ZeRO-1/2 sharding, padded uneven buckets, int8
+error-feedback and pipelined overlap with zero new distributed code.
 
 State layout (:class:`BucketedState`): ``buckets`` maps bucket key -> the
 stacked ``(padded L, d_in, d_out)`` momentum; ``slots`` maps slot name ->
@@ -27,7 +26,7 @@ import jax.numpy as jnp
 
 from repro.core import bucketing
 from repro.core.rules import MatrixUpdateRule
-from repro.core.types import Optimizer, Schedule
+from repro.core.types import Schedule
 
 
 class BucketedState(NamedTuple):
@@ -62,15 +61,14 @@ class BucketStateMeta(NamedTuple):
 class BucketedEngine:
     """The rule-agnostic machinery of a bucketed matrix optimizer.
 
-    Callers compose an :class:`Optimizer` from these methods (see
-    :func:`matrix_optimizer` for the pure-matrix form and
-    ``core/mixed.py`` for the mixed form with its AdamW sweep).
+    ``core/mixed.py`` composes the :class:`Optimizer` from these methods
+    and its AdamW sweep.
     """
 
     def __init__(self, rule: MatrixUpdateRule, lr: Schedule, *,
                  use_kernel: bool = False, momentum_dtype: str = "float32",
                  shard_axis: Optional[str] = None, shard_size: int = 1,
-                 predicate=None, strict: bool = False):
+                 predicate=None):
         mdtype = jnp.dtype(momentum_dtype)
         if mdtype not in (jnp.float32, jnp.bfloat16):
             raise ValueError(f"momentum_dtype must be float32 or bfloat16, "
@@ -82,7 +80,6 @@ class BucketedEngine:
         self.shard_axis = shard_axis
         self.shard_size = shard_size
         self.predicate = predicate
-        self.strict = strict
         # static metadata, computed once and reused by every trace (bounded
         # LRU keyed on leaf paths/shapes — one optimizer can serve several
         # models without leaking plan metadata)
@@ -93,7 +90,6 @@ class BucketedEngine:
         return self.plans.get(
             bucketing.plan_signature(params, self.predicate),
             lambda: bucketing.build_plan(params, predicate=self.predicate,
-                                         strict=self.strict,
                                          pad_multiple=self.shard_size))
 
     def init_state(self, plan: bucketing.BucketPlan) -> BucketedState:
@@ -126,34 +122,6 @@ class BucketedEngine:
 
     def _slots_of(self, slots, key) -> Dict[str, jax.Array]:
         return {name: per_bucket[key] for name, per_bucket in slots.items()}
-
-    # -- two-pass (update + apply_updates) ------------------------------
-    def update_buckets(self, plan, g_b, p32_b, buckets, slots, step):
-        """Per-bucket fp32 updates for the two-pass path: ``(upd_b, v_b,
-        slots_b)``.  Additive rules go through ``precondition`` with the
-        canonical op order; non-additive rules apply onto the fp32 params
-        and return the difference (documented as allclose-only vs the
-        fused path)."""
-        upd_b, v_b = {}, {}
-        slots_b: Dict[str, Dict[str, jax.Array]] = {n: {} for n in slots}
-        for b in plan.buckets:
-            sl = self._slots_of(slots, b.key)
-            with jax.named_scope(f"bucket_{b.key}"):
-                scale = self.scale(b, step)
-                if self.rule.additive:
-                    d, v_new, sl_new = self.rule.precondition(
-                        g_b[b.key], buckets[b.key], sl, step=step,
-                        use_kernel=self.use_kernel)
-                    upd = -scale * (d + self.rule.weight_decay * p32_b[b.key])
-                else:
-                    w_new, v_new, sl_new = self.rule.apply(
-                        g_b[b.key], buckets[b.key], p32_b[b.key], sl,
-                        scale=scale, step=step, use_kernel=self.use_kernel)
-                    upd = w_new - p32_b[b.key]
-            upd_b[b.key], v_b[b.key] = upd, v_new
-            for name in sl_new:
-                slots_b[name][b.key] = sl_new[name]
-        return upd_b, v_b, slots_b
 
     # -- single-pass fused apply (replicated / ZeRO-1) ------------------
     def bucket_apply(self, bucket, g, v, sl, w, step):
@@ -275,76 +243,3 @@ class BucketedEngine:
                 slots_b[name][b.key] = sl_new[name]
         return w_b, v_b, slots_b
 
-
-def matrix_optimizer(rule: MatrixUpdateRule, lr: Schedule, *,
-                     use_kernel: bool = False,
-                     momentum_dtype: str = "float32",
-                     fused_apply: bool = False,
-                     shard_axis: Optional[str] = None,
-                     shard_size: int = 1) -> Optimizer:
-    """Bucketed optimizer over a pure-matrix tree for any registered rule —
-    the engine behind ``rmnp(fused=True)`` and ``muon(fused=True)``.  The
-    flag semantics (``fused_apply`` unlocking ``update_apply``,
-    ``shard_axis``/``shard_size`` unlocking the ZeRO-2 entry points) match
-    the historical RMNP constructor exactly."""
-    eng = BucketedEngine(rule, lr, use_kernel=use_kernel,
-                         momentum_dtype=momentum_dtype,
-                         shard_axis=shard_axis, shard_size=shard_size,
-                         strict=True)
-
-    def init(params):
-        return eng.init_state(eng.plan(params))
-
-    def update(grads, state, params, step):
-        plan = eng.plan(params)
-        g_b = bucketing.gather(plan, grads, dtype=jnp.float32)
-        p_b = bucketing.gather(plan, params, dtype=jnp.float32)
-        upd_b, v_b, s_b = eng.update_buckets(plan, g_b, p_b, state.buckets,
-                                             state.slots, step)
-        updates = bucketing.scatter(plan, upd_b, params)
-        return updates, BucketedState(buckets=v_b, slots=s_b)
-
-    def update_apply(grads, state, params, step):
-        """Single-pass fused apply: params are gathered per bucket in their
-        native dtype, updated in one rule pass, and scattered back — no
-        fp32 ``d`` bucket and no separate ``apply_updates`` pass."""
-        plan = eng.plan(params)
-        g_b = bucketing.gather(plan, grads, dtype=jnp.float32)
-        p_b = bucketing.gather(plan, params)
-        w_b, v_b, s_b = eng.apply_buckets(plan, g_b, p_b, state.buckets,
-                                          state.slots, step)
-        new_params = bucketing.scatter(plan, w_b, params, cast=True)
-        return new_params, BucketedState(buckets=v_b, slots=s_b)
-
-    def update_apply_bucket(bucket, g_shard, v_shard, w_chunks, step,
-                            clip_scale=None, *, slots=None):
-        """Public per-bucket ZeRO-2 entry point; ``slots`` maps slot name
-        -> this rank's stripe shard (None/{} for slotless rules).  Returns
-        ``(w_new full padded bucket, v_new shard, slots_new shard)``."""
-        return eng.bucket_apply_sharded(bucket, g_shard, v_shard,
-                                        slots or {}, w_chunks, step,
-                                        clip_scale)
-
-    def update_apply_sharded(g_shards, grads, state, params, step,
-                             clip_scale=None):
-        """ZeRO-2 single-pass apply (call inside ``shard_map``): a loop of
-        independent per-bucket chains; ``grads`` is unused (pure-matrix
-        optimizer); ``clip_scale`` folds the global-norm clip into each
-        chain instead of pre-scaling the shards."""
-        del grads
-        plan = eng.plan(params)
-        out = eng.sharded_apply(plan, g_shards, state.buckets, state.slots,
-                                params, step, clip_scale)
-        if out is None:
-            return params, state
-        w_b, v_b, s_b = out
-        new_params = bucketing.scatter(plan, w_b, params, cast=True)
-        return new_params, BucketedState(buckets=v_b, slots=s_b)
-
-    zero2 = fused_apply and shard_axis is not None
-    return Optimizer(init=init, update=update,
-                     update_apply=update_apply if fused_apply else None,
-                     update_apply_sharded=update_apply_sharded if zero2 else None,
-                     update_apply_bucket=update_apply_bucket if zero2 else None,
-                     bucket_plan=eng.plan, shard_size=shard_size,
-                     state_meta=eng.state_meta)

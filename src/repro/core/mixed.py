@@ -7,9 +7,10 @@ tracking (paper Appendix E.7).
 Implemented as a single per-leaf-dispatch optimizer so the whole state is one
 pytree (momentum for matrix leaves, Adam (mu, nu) for the rest) — this keeps
 pjit sharding of optimizer state trivially aligned with parameter sharding.
-The fused path composes the generic bucketed engine (core/engine.py) with
-the per-leaf AdamW sweep, so every rule in the family inherits ZeRO-1/2
-sharding, padded uneven buckets and the pipelined dp step unchanged.
+The bucketed path (``fused=True``) composes the generic bucketed engine
+(core/engine.py) with the per-leaf AdamW sweep, so every rule in the
+family inherits ZeRO-1/2 sharding, padded uneven buckets and the
+pipelined dp step unchanged.
 """
 from __future__ import annotations
 
@@ -22,7 +23,8 @@ from repro.core import bucketing
 from repro.core.muon import newton_schulz
 from repro.core.rmnp import rms_lr_scale, row_normalize
 from repro.core.rules import MatrixUpdateRule, make_rule, rule_names
-from repro.core.types import Optimizer, PyTree, Schedule, map_with_path
+from repro.core.types import (Optimizer, PyTree, Schedule, map_with_path,
+                              update_then_apply)
 
 # parameter path fragments always handled by AdamW regardless of rank
 _NON_MATRIX_TOKENS = ("norm", "bias", "scale", "a_log", "dt_", "conv")
@@ -98,7 +100,6 @@ def mixed_optimizer(
     use_kernel: bool = False,
     fused: bool = False,
     momentum_dtype: str = "float32",
-    fused_apply: bool = False,
     shard_axis: Optional[str] = None,
     shard_size: int = 1,
 ) -> Optimizer:
@@ -108,29 +109,26 @@ def mixed_optimizer(
     paper's AdamW baseline).
 
     ``fused=True`` routes the matrix partition through the shape-bucketed
-    engine (core/engine.py): one preconditioner pass per distinct
-    ``(d_in, d_out)`` bucket — the RMNP family runs its fused Pallas stripes
-    when ``use_kernel`` is set, the NS family batches Newton-Schulz over the
-    bucket's stacked ``L`` axis (one 3-launch sequence per bucket instead of
-    one per leaf).  Rules beyond rmnp/muon carry extra per-bucket state
-    stripes or a non-additive apply, which exist only in the bucketed
-    layout, so they imply ``fused=True``.  ``momentum_dtype``
-    ('float32' | 'bfloat16') sets the fused matrix-momentum storage dtype
-    (math is always fp32).
+    engine (core/engine.py): one single-pass rule apply per distinct
+    ``(d_in, d_out)`` bucket — RMNP runs its Pallas apply kernel when
+    ``use_kernel`` is set (no fp32 ``d`` bucket, no updates tree), the NS
+    family batches Newton-Schulz over the bucket's stacked ``L`` axis.
+    Rules beyond rmnp/muon carry extra per-bucket state stripes or a
+    non-additive apply, which exist only in the bucketed layout, so they
+    imply ``fused=True``.  ``momentum_dtype`` ('float32' | 'bfloat16') sets
+    the bucketed matrix-momentum storage dtype (math is always fp32).
+    Without ``fused`` the matrix leaves take the per-leaf path, plain
+    ``jax.numpy`` — the reference the engine is tested against — which
+    runs no kernel, so ``use_kernel`` there raises.
 
-    ``fused_apply=True`` (implies ``fused``) exposes
-    ``Optimizer.update_apply``: matrix buckets fold the weight update into
-    the preconditioner kernel (single memory pass, no fp32 ``d`` bucket) and
-    AdamW leaves compute their new params in place, so the step needs no
-    separate ``apply_updates`` pass.  ``shard_axis`` names the mesh axis the
-    stacked matrix momentum may be ZeRO-sharded over (consulted only when
-    a bucket arrives as an ``L/N`` shard inside ``shard_map``); setting it
-    implies ``fused_apply``, since sharded state only works through
-    ``update_apply``.  ``shard_size`` (the size of ``shard_axis``) pads
-    bucket ``L`` to a multiple so uneven buckets shard too, and unlocks
-    ``Optimizer.update_apply_sharded`` — the ZeRO-2 entry point taking
-    reduce-scattered per-bucket mean-gradient shards (AdamW leaves still
-    read their mean grads from the per-leaf tree)."""
+    ``shard_axis`` (implies ``fused``) names the mesh axis the stacked
+    matrix momentum may be ZeRO-sharded over (consulted only when a bucket
+    arrives as an ``L/N`` shard inside ``shard_map``).  ``shard_size`` (the
+    size of ``shard_axis``) pads bucket ``L`` to a multiple so uneven
+    buckets shard too, and unlocks ``Optimizer.update_apply_sharded`` — the
+    ZeRO-2 entry point taking reduce-scattered per-bucket mean-gradient
+    shards (AdamW leaves still read their mean grads from the per-leaf
+    tree)."""
     if matrix_kind not in rule_names() + ("adamw",):
         raise ValueError(
             f"unknown matrix optimizer {matrix_kind!r}; expected one of "
@@ -141,11 +139,14 @@ def mixed_optimizer(
         raise ValueError("shard_size > 1 needs shard_axis (the mesh axis "
                          "the padded buckets shard over)")
     if shard_axis is not None:
-        fused_apply = True  # sharded state needs the single-pass path
-    if fused_apply:
-        fused = True  # single-pass apply rides the shape-bucketed engine
+        fused = True  # sharded state lives in the bucketed layout
     if matrix_kind not in ("rmnp", "muon", "adamw"):
         fused = True  # slot stripes / non-additive apply are bucketed-only
+    if use_kernel and not fused:
+        raise ValueError(
+            "use_kernel needs the bucketed engine: pass fused=True "
+            "(launch/train: --engine single-pass); the per-leaf path is "
+            "the plain jax.numpy reference and runs no kernel")
     b1, b2 = adam_betas
 
     def _is_mat(path, leaf):
@@ -161,8 +162,7 @@ def mixed_optimizer(
             rule, lr_matrix, lr_adamw, is_mat=_is_mat,
             weight_decay=weight_decay, b1=b1, b2=b2, adam_eps=adam_eps,
             use_kernel=use_kernel, momentum_dtype=momentum_dtype,
-            fused_apply=fused_apply, shard_axis=shard_axis,
-            shard_size=shard_size)
+            shard_axis=shard_axis, shard_size=shard_size)
 
     def init(params):
         momentum = jax.tree_util.tree_map(
@@ -185,15 +185,11 @@ def mixed_optimizer(
             g32 = g.astype(jnp.float32)
             p32 = p.astype(jnp.float32)
             if _is_mat(path, p):
-                if use_kernel and matrix_kind == "rmnp":
-                    from repro.kernels import ops as kops
-                    v_new, d = kops.rmnp_momentum_rownorm(g32, v, beta=beta, eps=rn_eps)
+                v_new = beta * v + (1.0 - beta) * g32
+                if matrix_kind == "rmnp":
+                    d = row_normalize(v_new, rn_eps)
                 else:
-                    v_new = beta * v + (1.0 - beta) * g32
-                    if matrix_kind == "rmnp":
-                        d = row_normalize(v_new, rn_eps)
-                    else:
-                        d = newton_schulz(v_new, steps=ns_steps, use_kernel=use_kernel)
+                    d = newton_schulz(v_new, steps=ns_steps)
                 scale = eta_m * rms_lr_scale(p.shape)
                 return -scale * (d + weight_decay * p32), v_new, nu
             # AdamW leaf
@@ -209,7 +205,8 @@ def mixed_optimizer(
                 lambda x: x[i], out, is_leaf=lambda x: isinstance(x, tuple))
         return pick(0), MixedState(momentum=pick(1), nu=pick(2))
 
-    return Optimizer(init=init, update=update)
+    return Optimizer(init=init, update=update,
+                     update_apply=update_then_apply(update))
 
 
 def momentum_for_diagnostics(opt_state, params, matrix_embed: bool = True) -> PyTree:
@@ -229,7 +226,7 @@ def _fused_mixed(rule: MatrixUpdateRule, lr_matrix: Schedule,
                  lr_adamw: Schedule, *, is_mat,
                  weight_decay: float, b1: float, b2: float,
                  adam_eps: float, use_kernel: bool,
-                 momentum_dtype: str, fused_apply: bool = False,
+                 momentum_dtype: str,
                  shard_axis: Optional[str] = None,
                  shard_size: int = 1) -> Optimizer:
     """Mixed optimizer with the matrix partition running through the
@@ -257,12 +254,10 @@ def _fused_mixed(rule: MatrixUpdateRule, lr_matrix: Schedule,
                                slots=bucketed.slots)
 
     @jax.named_scope("adamw")
-    def adam_sweep(grads, state, params, step, emit):
-        """Shared per-leaf AdamW pass.  ``emit(u, p)`` turns the fp32
-        update (``u=None`` on matrix leaves, which the bucket scatter
-        overwrites) into the output leaf — the *only* place the two-pass
-        and single-pass paths differ, so their AdamW math cannot drift
-        apart.  Returns (emitted tree, momentum, nu)."""
+    def adam_sweep(grads, state, params, step):
+        """Per-leaf AdamW pass, new params computed in place; matrix leaves
+        pass through (the bucket scatter overwrites them).  Returns (new
+        params, momentum, nu)."""
         eta_a = lr_adamw(step)
         t = jnp.asarray(step, jnp.float32) + 1.0
         bc1 = 1.0 - b1 ** t
@@ -270,13 +265,13 @@ def _fused_mixed(rule: MatrixUpdateRule, lr_matrix: Schedule,
 
         def upd_adam(path, g, mu, nu, p):
             if is_mat(path, p):
-                return emit(None, p), mu, nu
+                return p, mu, nu
             g32 = g.astype(jnp.float32)
             mu_new = b1 * mu + (1 - b1) * g32
             nu_new = b2 * nu + (1 - b2) * jnp.square(g32)
             d = (mu_new / bc1) / (jnp.sqrt(nu_new / bc2) + adam_eps)
             u = -eta_a * (d + weight_decay * p.astype(jnp.float32))
-            return emit(u, p), mu_new, nu_new
+            return p + u.astype(p.dtype), mu_new, nu_new
 
         paths_tree = map_with_path(lambda path, _: path, params)
         out = jax.tree_util.tree_map(upd_adam, paths_tree, grads,
@@ -286,32 +281,13 @@ def _fused_mixed(rule: MatrixUpdateRule, lr_matrix: Schedule,
                 lambda x: x[i], out, is_leaf=lambda x: isinstance(x, tuple))
         return pick(0), pick(1), pick(2)
 
-    def update(grads, state, params, step):
-        plan = eng.plan(params)
-        updates, momentum, nu = adam_sweep(
-            grads, state, params, step,
-            emit=lambda u, p: jnp.zeros(p.shape, jnp.float32) if u is None else u)
-
-        # matrix partition: one rule pass per shape bucket
-        g_b = bucketing.gather(plan, grads, dtype=jnp.float32)
-        p_b = bucketing.gather(plan, params, dtype=jnp.float32)
-        upd_b, v_b, s_b = eng.update_buckets(plan, g_b, p_b, state.buckets,
-                                             state.slots, step)
-        updates = bucketing.scatter(plan, upd_b, updates)
-        return updates, FusedMixedState(momentum=momentum, nu=nu,
-                                        buckets=v_b, slots=s_b)
-
     def update_apply(grads, state, params, step):
-        """Single-pass fused apply: -> (new_params, state).  AdamW leaves
-        compute their new params in place (same op order as apply_updates,
-        so fp32 results are bit-identical to the two-pass path); matrix
-        buckets run the fused-apply kernel — gather (g, v, w), one pass,
-        scatter the updated weights — with no fp32 ``d`` bucket and no
-        updates tree."""
+        """-> (new_params, state).  AdamW leaves compute their new params in
+        place (same op order as apply_updates); matrix buckets run the
+        rule's single-pass apply — gather (g, v, w), one pass, scatter the
+        updated weights — with no fp32 ``d`` bucket and no updates tree."""
         plan = eng.plan(params)
-        new_params, momentum, nu = adam_sweep(
-            grads, state, params, step,
-            emit=lambda u, p: p if u is None else p + u.astype(p.dtype))
+        new_params, momentum, nu = adam_sweep(grads, state, params, step)
 
         # matrix partition: one single-pass rule apply per bucket
         g_b = bucketing.gather(plan, grads, dtype=jnp.float32)
@@ -348,9 +324,7 @@ def _fused_mixed(rule: MatrixUpdateRule, lr_matrix: Schedule,
         the updated weight slices are all-gathered — no full gradient
         bucket per rank."""
         plan = eng.plan(params)
-        new_params, momentum, nu = adam_sweep(
-            grads, state, params, step,
-            emit=lambda u, p: p if u is None else p + u.astype(p.dtype))
+        new_params, momentum, nu = adam_sweep(grads, state, params, step)
 
         out = eng.sharded_apply(plan, g_shards, state.buckets, state.slots,
                                 params, step, clip_scale)
@@ -362,9 +336,8 @@ def _fused_mixed(rule: MatrixUpdateRule, lr_matrix: Schedule,
         return new_params, FusedMixedState(momentum=momentum, nu=nu,
                                            buckets=v_b, slots=s_b)
 
-    zero2 = fused_apply and shard_axis is not None
-    return Optimizer(init=init, update=update,
-                     update_apply=update_apply if fused_apply else None,
+    zero2 = shard_axis is not None
+    return Optimizer(init=init, update_apply=update_apply,
                      update_apply_sharded=update_apply_sharded if zero2 else None,
                      update_apply_bucket=update_apply_bucket if zero2 else None,
                      bucket_plan=eng.plan, shard_size=shard_size,

@@ -2,16 +2,13 @@
 
 Reference coefficients from Jordan et al. [11]; 5 iterations by default.
 The NS iteration costs O(mn * min(m, n)) per step — the quantity RMNP removes.
+The optimizer is ``make_optimizer("muon", ...)``; its rule is
+``rules.MuonRule``.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
-
 import jax
 import jax.numpy as jnp
-
-from repro.core.rmnp import rms_lr_scale
-from repro.core.types import Optimizer, PyTree, Schedule
 
 _NS_COEFFS = (3.4445, -4.7750, 2.0315)
 
@@ -42,61 +39,3 @@ def newton_schulz(v: jax.Array, steps: int = 5, eps: float = 1e-7,
     if transpose:
         x = jnp.swapaxes(x, -1, -2)
     return x.astype(orig_dtype)
-
-
-class MuonState(NamedTuple):
-    momentum: PyTree
-
-
-def muon(lr: Schedule, beta: float = 0.95, weight_decay: float = 0.1,
-         ns_steps: int = 5, use_kernel: bool = False, fused: bool = False,
-         momentum_dtype: str = "float32", fused_apply: bool = False,
-         shard_axis: Optional[str] = None, shard_size: int = 1) -> Optimizer:
-    """Muon for matrix parameters.  The flag cascade mirrors ``rmnp()``:
-    ``fused=True`` shape-buckets the leaves so Newton-Schulz batches over
-    each bucket's stacked ``L`` axis (one 3-launch NS sequence per bucket
-    per iteration instead of one per leaf); ``fused_apply`` (implied by
-    ``shard_axis``) unlocks ``update_apply``; ``shard_axis``/``shard_size``
-    unlock the ZeRO-1/2 entry points — all inherited from the generic
-    bucketed engine (core/engine.py), with state in the same
-    ``BucketedState`` layout as every other family member."""
-    if shard_size < 1:
-        raise ValueError(f"shard_size must be >= 1, got {shard_size}")
-    if shard_size > 1 and shard_axis is None:
-        raise ValueError("shard_size > 1 needs shard_axis (the mesh axis "
-                         "the padded buckets shard over)")
-    if shard_axis is not None:
-        fused_apply = True  # sharded state needs the single-pass path
-    if fused_apply:
-        fused = True  # single-pass apply rides the shape-bucketed engine
-    if fused:
-        from repro.core.engine import matrix_optimizer
-        from repro.core.rules import MuonRule
-        return matrix_optimizer(
-            MuonRule(beta=beta, weight_decay=weight_decay,
-                     ns_steps=ns_steps), lr,
-            use_kernel=use_kernel, momentum_dtype=momentum_dtype,
-            fused_apply=fused_apply, shard_axis=shard_axis,
-            shard_size=shard_size)
-
-    def init(params):
-        return MuonState(momentum=jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), params))
-
-    def update(grads, state, params, step):
-        eta = lr(step)
-
-        def upd(g, v, p):
-            v_new = beta * v + (1.0 - beta) * g.astype(jnp.float32)
-            d = newton_schulz(v_new, steps=ns_steps, use_kernel=use_kernel)
-            scale = eta * rms_lr_scale(p.shape)
-            return (-scale * (d + weight_decay * p.astype(jnp.float32))), v_new
-
-        out = jax.tree_util.tree_map(upd, grads, state.momentum, params)
-        updates = jax.tree_util.tree_map(lambda x: x[0], out,
-                                         is_leaf=lambda x: isinstance(x, tuple))
-        momentum = jax.tree_util.tree_map(lambda x: x[1], out,
-                                          is_leaf=lambda x: isinstance(x, tuple))
-        return updates, MuonState(momentum=momentum)
-
-    return Optimizer(init=init, update=update)

@@ -1,8 +1,7 @@
 """Constructor registry: one entry point for every optimizer family member.
 
-``make_optimizer(name, config)`` replaces the ad-hoc
-``mixed_optimizer(kind, lr_m, lr_a, ...)`` call sites scattered through the
-launchers and benchmarks: the name is any registered matrix update rule
+``make_optimizer(name, config)`` builds every optimizer the launchers,
+benchmarks and examples run: the name is any registered matrix update rule
 (core/rules.py — rmnp, muon, normuon, muown, nora) or ``adamw``, and the
 config is a plain dict of ``mixed_optimizer`` keyword arguments plus the
 two learning rates (floats are wrapped in a constant schedule; callables
@@ -35,8 +34,8 @@ def make_optimizer(name: str, config: Optional[Dict[str, Any]] = None,
     ``config`` (optionally updated by keyword ``overrides``) holds
     ``lr_matrix`` (required; float or schedule), ``lr_adamw`` (defaults to
     ``lr_matrix``), and any further ``mixed_optimizer`` keyword argument
-    (``fused``, ``fused_apply``, ``shard_axis``, ``shard_size``,
-    ``use_kernel``, ``momentum_dtype``, ``beta``, ``weight_decay``, ...).
+    (``fused``, ``shard_axis``, ``shard_size``, ``use_kernel``,
+    ``momentum_dtype``, ``beta``, ``weight_decay``, ...).
     Unknown names raise the rule registry's ValueError listing what is
     registered."""
     if name not in optimizer_names():

@@ -13,15 +13,15 @@ are treated as independent matrices.
 
 Per-iteration cost is O(mn) — a single elementwise pass + a row reduction —
 versus Muon's O(mn * min(m, n)) Newton-Schulz matmuls.
+
+The optimizer itself is ``make_optimizer("rmnp", ...)`` (core/registry.py):
+the rule lives in core/rules.py and runs on the bucketed engine
+(core/engine.py).  This module keeps the two primitives it is built from.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
-
 import jax
 import jax.numpy as jnp
-
-from repro.core.types import Optimizer, PyTree, Schedule
 
 
 def row_normalize(v: jax.Array, eps: float = 1e-8, in_axis: int = -2) -> jax.Array:
@@ -34,107 +34,3 @@ def rms_lr_scale(shape) -> float:
     """Muon/RMNP RMS scaling: lr * max(1, sqrt(d_out / d_in)) (Eq. 17/18)."""
     d_in, d_out = shape[-2], shape[-1]
     return max(1.0, (d_out / d_in) ** 0.5)
-
-
-class RmnpState(NamedTuple):
-    momentum: PyTree
-
-
-def __getattr__(name):
-    # Back-compat: the stacked-bucket state moved to the generic engine as
-    # the family-wide BucketedState (lazy to keep import order acyclic).
-    if name == "RmnpFusedState":
-        from repro.core.engine import BucketedState
-        return BucketedState
-    raise AttributeError(name)
-
-
-def rmnp(lr: Schedule, beta: float = 0.95, weight_decay: float = 0.1,
-         eps: float = 1e-8, use_kernel: bool = False, fused: bool = False,
-         momentum_dtype: str = "float32", fused_apply: bool = False,
-         shard_axis: Optional[str] = None, shard_size: int = 1) -> Optimizer:
-    """RMNP for matrix parameters.
-
-    ``use_kernel`` selects the Pallas path; ``fused=True`` additionally
-    shape-buckets the leaves (core/bucketing.py) so the preconditioner runs
-    once per distinct ``(d_in, d_out)`` shape instead of once per leaf.
-    ``momentum_dtype`` ('float32' | 'bfloat16') sets the fused momentum
-    storage dtype (bf16 halves optimizer-state bytes, fp32 math throughout).
-
-    ``fused_apply=True`` (implies ``fused``) additionally exposes
-    ``Optimizer.update_apply``: the weight update is folded into the
-    per-bucket kernel, so the step is a single memory pass over (g, v, w)
-    with no fp32 ``d`` bucket and no separate ``apply_updates`` pass.
-    ``shard_axis`` names the mesh axis the stacked momentum may be
-    ZeRO-sharded over (only consulted inside ``shard_map`` when a bucket
-    arrives as an ``L/N`` shard; full buckets take the replicated path).
-    Setting it implies ``fused_apply`` — sharded state only works through
-    ``update_apply``, so silently ignoring it would replicate the state.
-
-    ``shard_size`` (the size of ``shard_axis``) pads every bucket's stacked
-    ``L`` up to a multiple, so buckets whose ``L`` is uneven — including
-    ``L < N`` — shard instead of replicating (pad slices are zero-filled,
-    mathematically inert, and dropped on scatter).  It also unlocks
-    ``Optimizer.update_apply_sharded``, the ZeRO-2 entry point consuming
-    reduce-scattered per-bucket gradient shards directly.
-    """
-    if shard_size < 1:
-        raise ValueError(f"shard_size must be >= 1, got {shard_size}")
-    if shard_size > 1 and shard_axis is None:
-        raise ValueError("shard_size > 1 needs shard_axis (the mesh axis "
-                         "the padded buckets shard over)")
-    if shard_axis is not None:
-        fused_apply = True  # sharded state needs the single-pass path
-    if fused_apply:
-        fused = True  # single-pass apply rides the shape-bucketed engine
-    if fused:
-        return _rmnp_fused(lr, beta=beta, weight_decay=weight_decay, eps=eps,
-                           use_kernel=use_kernel, momentum_dtype=momentum_dtype,
-                           fused_apply=fused_apply, shard_axis=shard_axis,
-                           shard_size=shard_size)
-
-    def init(params):
-        return RmnpState(momentum=jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), params))
-
-    def update(grads, state, params, step):
-        eta = lr(step)
-
-        def upd(g, v, p):
-            if use_kernel:
-                from repro.kernels import ops as kops
-                v_new, d = kops.rmnp_momentum_rownorm(
-                    g.astype(jnp.float32), v, beta=beta, eps=eps)
-            else:
-                v_new = beta * v + (1.0 - beta) * g.astype(jnp.float32)
-                d = row_normalize(v_new, eps)
-            scale = eta * rms_lr_scale(p.shape)
-            return (-scale * (d + weight_decay * p.astype(jnp.float32))), v_new
-
-        out = jax.tree_util.tree_map(upd, grads, state.momentum, params)
-        updates = jax.tree_util.tree_map(lambda x: x[0], out,
-                                         is_leaf=lambda x: isinstance(x, tuple))
-        momentum = jax.tree_util.tree_map(lambda x: x[1], out,
-                                          is_leaf=lambda x: isinstance(x, tuple))
-        return updates, RmnpState(momentum=momentum)
-
-    return Optimizer(init=init, update=update)
-
-
-def _rmnp_fused(lr: Schedule, *, beta: float, weight_decay: float, eps: float,
-                use_kernel: bool, momentum_dtype: str,
-                fused_apply: bool = False,
-                shard_axis: Optional[str] = None,
-                shard_size: int = 1) -> Optimizer:
-    """The shape-bucketed RMNP optimizer is the generic bucketed engine
-    (core/engine.py) instantiated with the RMNP rule — the historical
-    behavior (plan caching, fused Pallas apply, ZeRO-1/2 entry points) now
-    lives there, shared with the whole update-rule family."""
-    from repro.core.engine import matrix_optimizer
-    from repro.core.rules import RmnpRule
-
-    return matrix_optimizer(
-        RmnpRule(beta=beta, weight_decay=weight_decay, eps=eps), lr,
-        use_kernel=use_kernel, momentum_dtype=momentum_dtype,
-        fused_apply=fused_apply, shard_axis=shard_axis,
-        shard_size=shard_size)

@@ -9,17 +9,13 @@ optimizers is only the per-bucket math, captured here as a
 * ``slot_shapes`` — extra per-bucket state beyond the stacked momentum
   (e.g. NorMuon's neuron-wise second moment), stored as ``(L, 1, d_out)``
   stripes that shard along ``L`` exactly like the momentum;
-* ``precondition`` — the two-pass direction ``d`` (update is then the
-  canonical ``-scale * (d + wd * w)``), used by additive rules;
-* ``apply`` — the fused single-pass form ``(g, v, w) -> (w_new, v_new)``.
-  The default derives it from ``precondition`` with the exact op order of
-  the RMNP fused-apply kernel (``w32 + (-scale) * (d + wd * w32)``), so
-  ``update`` + ``apply_updates`` agrees with ``update_apply`` for every
-  additive rule — bitwise within one compilation context, and to FMA-
-  contraction level (a few ulps) across separately jitted programs, where
-  XLA may fuse the preconditioner chain into its consumers differently;
-  non-additive rules (Muown's multiplicative norm control) override it
-  and set ``additive = False``.
+* ``precondition`` — the rule's direction ``d``;
+* ``apply`` — the single-pass form ``(g, v, w) -> (w_new, v_new)`` the
+  engine runs.  The default derives it from ``precondition`` with the
+  exact op order of the RMNP apply kernel (``w32 + (-scale) * (d + wd *
+  w32)``); RMNP's apply is the kernel itself (or its jnp reference), so
+  RMNP has no separate ``precondition``, and Muown's multiplicative norm
+  control overrides apply with its own update.
 
 Every rule operates on stacked ``(L, d_in, d_out)`` operands where each
 ``L`` slice is an independent matrix — row reductions run along axis -2
@@ -86,11 +82,6 @@ class MatrixUpdateRule:
     eps: float = 1e-8
 
     name = "base"
-    # True when update() + apply_updates() is bitwise-equal (fp32 params) to
-    # update_apply(): the update is additive in w with the canonical op
-    # order.  Muown's multiplicative norm control sets this False — its
-    # two-pass form is w_new - w32, which re-associates the final add.
-    additive = True
 
     def slot_shapes(self, rows: int, d_in: int,
                     d_out: int) -> Dict[str, Tuple[Tuple[int, ...], jnp.dtype]]:
@@ -111,9 +102,9 @@ class MatrixUpdateRule:
     def apply(self, g: jax.Array, v: jax.Array, w: jax.Array,
               slots: Dict[str, jax.Array], *, scale, step,
               use_kernel: bool = False):
-        """Fused per-bucket apply: ``(w_new in w.dtype, v_new, slots_new)``.
-        ``scale`` already folds lr * rms_lr_scale.  Default: the canonical
-        additive form, op-order-identical to the two-pass path."""
+        """Single-pass per-bucket apply: ``(w_new in w.dtype, v_new,
+        slots_new)``.  ``scale`` already folds lr * rms_lr_scale.  Default:
+        the canonical additive form ``w - scale * (d + wd * w)``."""
         d, v_new, slots_new = self.precondition(g, v, slots, step=step,
                                                 use_kernel=use_kernel)
         w32 = w.astype(jnp.float32)
@@ -124,27 +115,23 @@ class MatrixUpdateRule:
 @_register
 @dataclasses.dataclass(frozen=True)
 class RmnpRule(MatrixUpdateRule):
-    """The paper's rule: momentum EMA + row (fan-in) l2 normalize.  Routes
-    through the fused Pallas stripes (kernels/rmnp_update.py) when
-    ``use_kernel`` is set, including the single-pass fused apply."""
+    """The paper's rule: momentum EMA + row (fan-in) l2 normalize.  Its
+    apply is the single-pass Pallas stripe kernel (kernels/rmnp_update.py)
+    when ``use_kernel`` is set, else the kernel's jnp reference."""
     name = "rmnp"
-
-    def precondition(self, g, v, slots, *, step, use_kernel=False):
-        del step
-        if use_kernel:
-            from repro.kernels import ops as kops
-            v_new, d = kops.rmnp_bucket_update(g, v, beta=self.beta,
-                                               eps=self.eps)
-            return d, v_new, {}
-        from repro.core.rmnp import row_normalize
-        v32 = _ema32(g, v, self.beta)
-        return row_normalize(v32, self.eps), v32.astype(v.dtype), {}
 
     def apply(self, g, v, w, slots, *, scale, step, use_kernel=False):
         del step
-        from repro.core.bucketing import _apply_one
-        v_new, w_new = _apply_one(g, v, w, scale, self.weight_decay,
-                                  self.beta, self.eps, use_kernel)
+        if use_kernel:
+            from repro.kernels import ops as kops
+            v_new, w_new = kops.rmnp_bucket_update_apply(
+                g, v, w, scale, self.weight_decay, beta=self.beta,
+                eps=self.eps)
+        else:
+            from repro.kernels.ref import rmnp_rownorm_apply_ref
+            v_new, w_new = rmnp_rownorm_apply_ref(
+                g, v, w, scale, self.weight_decay, beta=self.beta,
+                eps=self.eps)
         return w_new, v_new, {}
 
 
@@ -205,9 +192,8 @@ class MuownRule(MuonRule):
     control — after the orthogonalized step, each output neuron's fan-in
     vector is rescaled back to its pre-step norm decayed by
     ``1 - scale * wd``, replacing additive weight decay.  Stateless beyond
-    momentum, but *not* additive in w."""
+    momentum."""
     name = "muown"
-    additive = False
 
     def apply(self, g, v, w, slots, *, scale, step, use_kernel=False):
         d, v_new, _ = self.precondition(g, v, slots, step=step,

@@ -3,8 +3,11 @@
 All optimizers are pure-pytree transformations compatible with jit / pjit:
 
     state = opt.init(params)
+    params, state = opt.update_apply(grads, state, params, step)
+
+Per-leaf optimizers also expose the updates tree they apply:
+
     updates, state = opt.update(grads, state, params, step)
-    params = apply_updates(params, updates)
 
 ``updates`` already contain the (negative) learning-rate scaling, i.e. the
 new parameters are ``params + updates``.
@@ -23,12 +26,13 @@ Schedule = Callable[[jax.Array], jax.Array]  # step -> lr
 @dataclasses.dataclass(frozen=True)
 class Optimizer:
     init: Callable[[PyTree], PyTree]
-    update: Callable[..., Any]  # (grads, state, params, step) -> (updates, state)
-    # single-pass fused apply: (grads, state, params, step) -> (new_params,
-    # state) — the weight update is folded into the preconditioner kernel, so
-    # no updates tree (and no apply_updates pass) ever exists.  Train steps
-    # use it when present; None means two-pass update + apply_updates.
-    update_apply: Optional[Callable[..., Any]] = None
+    # (grads, state, params, step) -> (new_params, state): the one step every
+    # train step calls.  On the bucketed engine the weight update is folded
+    # into the per-bucket rule pass, so no updates tree ever exists.
+    update_apply: Callable[..., Any]
+    # (grads, state, params, step) -> (updates, state): the per-leaf
+    # optimizers' updates tree (None on the bucketed engine)
+    update: Optional[Callable[..., Any]] = None
     # ZeRO-2 fused apply: (g_shards, grads, state, params, step, *,
     # clip_scale=None) -> (new_params, state).  ``g_shards`` maps bucket key
     # -> this rank's (padded L / N, d_in, d_out) fp32 *mean-gradient shard*
@@ -36,7 +40,7 @@ class Optimizer:
     # are ignored, non-matrix leaves must already be mean-reduced (and
     # clip-scaled — ``clip_scale`` applies only to the matrix shards, folded
     # into each bucket's chain so no pre-scaled shard buffers serialize the
-    # buckets).  Exposed by the fused-apply optimizers when built with
+    # buckets).  Exposed by the bucketed engine when built with
     # shard_axis + shard_size.
     update_apply_sharded: Optional[Callable[..., Any]] = None
     # per-bucket ZeRO-2 entry point: (bucket, g_shard, v_shard, w_chunks,
@@ -60,7 +64,7 @@ class Optimizer:
     # the shard_size the optimizer was built with (pad multiple of every
     # bucket's stacked L == the intended ZeRO shard-axis size).  The dp step
     # validates it against the mesh axis up front — a mismatch otherwise
-    # surfaces as a shape error deep inside bucket_update_apply.
+    # surfaces as a shape error deep inside the bucket apply.
     shard_size: int = 1
     # params -> tuple of repro.core.engine.BucketStateMeta: static
     # per-bucket state-layout metadata (momentum + slot-stripe full shapes
@@ -79,6 +83,15 @@ def apply_updates(params: PyTree, updates: PyTree) -> PyTree:
     return jax.tree_util.tree_map(
         lambda p, u: (p + u.astype(p.dtype)) if u is not None else p,
         params, updates, is_leaf=lambda x: x is None)
+
+
+def update_then_apply(update: Callable[..., Any]) -> Callable[..., Any]:
+    """``update_apply`` of a per-leaf optimizer: its ``update``, then
+    :func:`apply_updates`."""
+    def update_apply(grads, state, params, step):
+        updates, state = update(grads, state, params, step)
+        return apply_updates(params, updates), state
+    return update_apply
 
 
 def path_str(keypath) -> str:

@@ -3,7 +3,7 @@
 Off the TPU the kernels run in interpret mode for correctness testing; on
 TPU they compile to Mosaic.  ``_interpret()`` picks automatically.
 Leading batch dims (layer stacks, expert stacks) are vmapped.  The RMNP
-entry points route a shape to the kernel or to the jnp reference by the
+entry point routes a shape to the kernel or to the jnp reference by the
 kernel's own VMEM plan (``rmnp_update.plan_stripes``): embedding-sized
 fan-ins whose stripe cannot fit VMEM take the XLA path.
 """
@@ -21,42 +21,19 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def rmnp_momentum_rownorm(g, v, *, beta: float, eps: float = 1e-8):
-    """Fused momentum EMA + row (fan-in) l2 normalization.
-    g, v: (..., d_in, d_out) fp32.  Returns (v_new, d)."""
-    if _rm.rownorm_plan(g, v) is None:
-        from repro.kernels.ref import rmnp_momentum_rownorm_ref
-        return rmnp_momentum_rownorm_ref(g, v, beta=beta, eps=eps)
-    return _rm.rmnp_momentum_rownorm_2d(g, v, beta=beta, eps=eps,
-                                        interpret=_interpret())
-
-
-def rmnp_bucket_update(g, v, *, beta: float, eps: float = 1e-8):
-    """Batched entry point for the shape-bucketed fused engine: one
-    ``pallas_call`` over a whole stacked bucket.
-
-    g: (L, d_in, d_out) fp32 gradients; v: matching momentum in its storage
-    dtype (fp32 or bf16).  Returns (v_new in v.dtype, d fp32).  The kernel
-    writes v_new over v, so where the train step donates the momentum
-    (``donate_argnums`` on the outer step) the old bucket's allocation is
-    updated in place."""
-    if _rm.rownorm_plan(g, v) is None:
-        from repro.kernels.ref import rmnp_momentum_rownorm_ref
-        return rmnp_momentum_rownorm_ref(g, v, beta=beta, eps=eps)
-    return _rm.rmnp_momentum_rownorm_2d(g, v, beta=beta, eps=eps,
-                                        interpret=_interpret())
-
-
 def rmnp_bucket_update_apply(g, v, w, scale, wd, *, beta: float,
                              eps: float = 1e-8):
-    """Single-pass fused apply over a stacked bucket: momentum EMA + row
-    normalize + weight update in one ``pallas_call`` — the fp32 ``d`` buffer
-    of the two-pass path is never materialized.
+    """Single-pass apply over a stacked bucket: momentum EMA + row
+    normalize + weight update in one ``pallas_call`` — no fp32 ``d`` buffer
+    is ever materialized.
 
     g: (L, d_in, d_out) fp32 gradients; v: matching momentum in its storage
     dtype; w: matching weights (math fp32, output in w.dtype); scale/wd are
     traced fp32 scalars (scale folds lr * rms_lr_scale).  Returns
-    (v_new, w_new)."""
+    (v_new, w_new).  The kernel writes v_new over v and w_new over w, so
+    where the train step donates the momentum and weights
+    (``donate_argnums`` on the outer step) their allocations are updated
+    in place."""
     if _rm.rownorm_apply_plan(g, v, w) is None:
         from repro.kernels.ref import rmnp_rownorm_apply_ref
         return rmnp_rownorm_apply_ref(g, v, w, scale, wd, beta=beta, eps=eps)
@@ -101,10 +78,9 @@ def count_buffer_eqns(fn, shape, dtype, *args, exclude_prims=(),
                       **kwargs) -> int:
     """Number of jaxpr equations in ``fn`` (recursive) producing an output of
     exactly ``(shape, dtype)`` — the tracer behind the single-pass engine's
-    'no full-partition fp32 intermediate' claim: per bucket, the two-pass
-    update materializes the fp32 preconditioned ``d`` buffer *and* the scaled
-    update at the full bucket shape, while fused-apply emits only the updated
-    weights.  Traces but never runs ``fn``.
+    'no full-partition fp32 intermediate' claim: per bucket the apply emits
+    only the updated weights, never an fp32 ``d`` buffer at the full bucket
+    shape.  Traces but never runs ``fn``.
 
     ``exclude_prims`` names primitives whose outputs are not counted — the
     ZeRO-2 tests use it to discount the *intended* full-bucket buffer (the
@@ -129,7 +105,8 @@ def ns_step(x, a: float, b: float, c: float):
     """One Newton-Schulz iteration on (..., m, n) fp32.  Leading dims are
     batched through the stacked-bucket kernel: a whole ``(L, m, n)`` shape
     bucket costs one 3-launch sequence (Gram, polynomial, apply) instead of
-    one per matrix — the bucketed-Muon analogue of ``rmnp_bucket_update``."""
+    one per matrix — the bucketed-Muon analogue of
+    ``rmnp_bucket_update_apply``."""
     if x.ndim == 2:
         return _ns.ns_step(x, a=a, b=b, c=c, interpret=_interpret())
     lead = x.shape[:-2]

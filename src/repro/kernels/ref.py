@@ -4,27 +4,14 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 
-def rmnp_momentum_rownorm_ref(g, v, *, beta: float, eps: float = 1e-8):
-    """Fused RMNP preconditioning: momentum EMA + per-output-neuron l2 norm.
-
-    g: (..., d_in, d_out) fp32; v may be fp32 or bf16 momentum storage.
-    Math in fp32 (matching the kernel); returns (v_new in v.dtype, d fp32)
-    with d = v_new / ||col||.
-    """
-    v_new = beta * v.astype(jnp.float32) + (1.0 - beta) * g.astype(jnp.float32)
-    norm = jnp.sqrt(jnp.sum(jnp.square(v_new), axis=-2, keepdims=True))
-    return v_new.astype(v.dtype), v_new / (norm + eps)
-
-
 def rmnp_rownorm_apply_ref(g, v, w, scale, wd, *, beta: float,
                            eps: float = 1e-8):
     """Single-pass fused apply: momentum EMA + row normalize + weight update.
 
     g: (..., d_in, d_out) fp32; v: fp32 or bf16 momentum storage; w: weights
     (math in fp32, returned in w.dtype); scale already folds lr *
-    rms_lr_scale.  Op order matches the Pallas kernel and the two-pass
-    reference exactly (update = -scale*(d + wd*w), then w + update), so fp32
-    results are bit-identical to both.
+    rms_lr_scale.  Op order matches the Pallas kernel exactly (update =
+    -scale*(d + wd*w), then w + update).
     """
     w32 = w.astype(jnp.float32)
     v_new = beta * v.astype(jnp.float32) + (1.0 - beta) * g.astype(jnp.float32)

@@ -1,35 +1,29 @@
-"""Fused RMNP preconditioning kernel (the paper's O(mn) hot loop).
+"""Single-pass RMNP apply kernel (the paper's O(mn) hot loop).
 
-One pass over the momentum/gradient pair per column stripe:
+One pass over the gradient/momentum/weight triple per column stripe:
     v_new = beta * v + (1 - beta) * g
     d     = v_new / (||v_new||_col + eps)
+    w_new = w - scale * (d + wd * w)
 
-Grid is 1-D over d_out column stripes; each program holds a full
+Grid is over (bucket slice, d_out column stripe); each program holds a full
 (d_in, block_n) stripe in VMEM — the column reduction is local, so no
 cross-program accumulation is needed.  This is the TPU-native shape of the
 paper's row-normalization: the reduction runs down the sublane axis while
 the 128-wide lane axis streams output neurons.
 
 The batched (leading-axis) form is the engine behind the shape-bucketed
-fused optimizer path (core/bucketing.py): a whole (L, d_in, d_out) bucket
-of stacked parameter slices is one ``pallas_call``.  Momentum may be stored
-in bf16 (``v`` dtype is preserved on output); math is always fp32.
+optimizer (core/engine.py): a whole (L, d_in, d_out) bucket of stacked
+parameter slices is one ``pallas_call``.  Momentum may be stored in bf16
+(``v`` dtype is preserved on output); math is always fp32.  Scalar
+(lr-scale, weight-decay) arrive in SMEM, and the fp32 ``d`` bucket is
+never materialized in HBM: the optimizer is a single memory pass over
+(g, v, w).
 
-The *fused-apply* variant additionally takes the stacked weights plus
-scalar (lr-scale, weight-decay) and emits the updated weights directly:
-
-    w_new = w - scale * (v_new / (||v_new||_col + eps) + wd * w)
-
-so the fp32 ``d`` bucket is never materialized in HBM and the separate
-``apply_updates`` tree pass disappears — the optimizer becomes a single
-memory pass over (g, v, w).
-
-Both kernels write their state in place: each state operand is aliased to
-its output (``v -> v_new``; the apply kernel also ``w -> w_new``).  Where
-the caller donates the momentum and weights (the train step's
-``donate_argnums``), the kernel reads and writes the donated buffers
-directly; where it does not, XLA copies the operand first and the caller's
-arrays are left unchanged.
+The kernel writes its state in place: ``v`` is aliased to ``v_new`` and
+``w`` to ``w_new``.  Where the caller donates the momentum and weights
+(the train step's ``donate_argnums``), the kernel reads and writes the
+donated buffers directly; where it does not, XLA copies the operand first
+and the caller's arrays are left unchanged.
 
 Every launch is planned by :func:`plan_stripes`: its VMEM accounting sets
 the lane block (never below 128 lanes), the launch's scoped-VMEM limit,
@@ -53,10 +47,9 @@ GROW_BUDGET = 16 * 2**20      # grow the block only while it needs at most this
 VMEM_LIMIT_CAP = 96 * 2**20   # most scoped VMEM a launch may ask for (v5e
 #                               has 128 MiB per core)
 
-# fp32 (d_in, block_n) temporaries the kernel bodies hold beyond their
-# pipelined blocks (the upcast loads and v_new; the apply body also d and
-# w_new) — measured against Mosaic's own VMEM demand in v5e compiles
-PRECOND_TEMPS = 1
+# fp32 (d_in, block_n) temporaries the kernel body holds beyond its
+# pipelined blocks (the upcast loads, v_new, d and w_new) — measured
+# against Mosaic's own VMEM demand in v5e compiles
 APPLY_TEMPS = 2
 
 
@@ -102,34 +95,18 @@ def _itemsizes(*dtypes) -> tuple:
     return tuple(jnp.dtype(d).itemsize for d in dtypes)
 
 
-def rownorm_plan(g, v) -> Optional[StripePlan]:
-    """Plan of the precondition-only kernel: g, v in; v_new, d out."""
-    return plan_stripes(g.shape[-2], g.shape[-1],
-                        _itemsizes(g.dtype, v.dtype, v.dtype, jnp.float32),
-                        PRECOND_TEMPS)
-
-
 def rownorm_apply_plan(g, v, w) -> Optional[StripePlan]:
-    """Plan of the fused-apply kernel: g, v, w in; v_new, w_new out."""
+    """Plan of the apply kernel: g, v, w in; v_new, w_new out."""
     return plan_stripes(g.shape[-2], g.shape[-1],
                         _itemsizes(g.dtype, v.dtype, w.dtype, v.dtype,
                                    w.dtype),
                         APPLY_TEMPS)
 
 
-def _kernel3d(g_ref, v_ref, v_out_ref, d_ref, *, beta: float, eps: float):
-    g = g_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    v_new = beta * v + (1.0 - beta) * g
-    norm = jnp.sqrt(jnp.sum(v_new * v_new, axis=0, keepdims=True))
-    v_out_ref[0] = v_new.astype(v_out_ref.dtype)
-    d_ref[0] = v_new / (norm + eps)
-
-
 def _stripe_call(kernel, name: str, operands, out_dtypes,
                  plan: Optional[StripePlan], *, interpret: bool,
                  aliases: Dict[int, int], scalars=None):
-    """Shared scaffolding for the column-stripe kernels: flatten leading
+    """Scaffolding of a column-stripe kernel: flatten leading
     dims (layer / expert stacks, bucket slices) into the outer grid axis,
     zero-pad d_out to the block, run one program per (l, stripe), slice the
     pad back off.  ``scalars`` (optional (k,) fp32) is prepended as a
@@ -182,25 +159,6 @@ def _stripe_call(kernel, name: str, operands, out_dtypes,
     return tuple(o.reshape(*lead, d_in, n) for o in outs)
 
 
-def _rownorm_2d(g, v, *, beta: float, eps: float = 1e-8,
-                interpret: bool = False):
-    """g: (..., d_in, d_out) fp32; v: same shape, fp32 or bf16 momentum
-    storage -> (v_new in v.dtype, d fp32)."""
-    return _stripe_call(
-        functools.partial(_kernel3d, beta=beta, eps=eps), "rmnp_rownorm",
-        [g, v], [v.dtype, jnp.float32], rownorm_plan(g, v),
-        interpret=interpret, aliases={1: 0})
-
-
-# momentum donation happens at the *train-step* jit boundary
-# (donate_argnums on the outer step fn): a donate annotation on this nested
-# jit would be dropped inside an outer jit.  Inside a donating step the
-# kernel's v -> v_new alias makes the update in place; called eagerly, or
-# from a jit that does not donate, XLA copies v first
-rmnp_momentum_rownorm_2d = functools.partial(
-    jax.jit, static_argnames=("beta", "eps", "interpret"))(_rownorm_2d)
-
-
 def _kernel3d_apply(scal_ref, g_ref, v_ref, w_ref, v_out_ref, w_out_ref,
                     *, beta: float, eps: float):
     scale = scal_ref[0]
@@ -212,8 +170,8 @@ def _kernel3d_apply(scal_ref, g_ref, v_ref, w_ref, v_out_ref, w_out_ref,
     norm = jnp.sqrt(jnp.sum(v_new * v_new, axis=0, keepdims=True))
     d = v_new / (norm + eps)
     v_out_ref[0] = v_new.astype(v_out_ref.dtype)
-    # same op order as the two-pass reference (update = -scale*(d + wd*w),
-    # then w + update) so fp32 results are bit-identical to it
+    # same op order as kernels/ref.rmnp_rownorm_apply_ref (update =
+    # -scale*(d + wd*w), then w + update)
     w_out_ref[0] = (w + (-scale) * (d + wd * w)).astype(w_out_ref.dtype)
 
 
@@ -231,5 +189,10 @@ def _rownorm_apply_2d(g, v, w, scalars, *, beta: float, eps: float = 1e-8,
         aliases={1: 0, 2: 1}, scalars=scalars)
 
 
+# momentum and weight donation happens at the *train-step* jit boundary
+# (donate_argnums on the outer step fn): a donate annotation on this nested
+# jit would be dropped inside an outer jit.  Inside a donating step the
+# kernel's aliases make the update in place; called eagerly, or from a jit
+# that does not donate, XLA copies v and w first
 rmnp_rownorm_apply_2d = functools.partial(
     jax.jit, static_argnames=("beta", "eps", "interpret"))(_rownorm_apply_2d)

@@ -16,7 +16,6 @@ import json
 import os
 import signal
 import time
-import warnings
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -103,12 +102,12 @@ def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
     this run's device count reshards the bucketed state automatically
     (``distributed/elastic.py``) instead of failing on the padded shapes.
 
-    ``fused`` routes matrix parameters through the shape-bucketed engine
-    (one preconditioner pass per distinct matrix shape instead of one per
-    leaf); ``momentum_dtype='bfloat16'`` halves its momentum storage;
-    ``fused_apply`` folds the weight update into the per-bucket kernel
-    (single memory pass, no separate apply_updates sweep); ``zero2``
-    (implies ``fused_apply``) switches to the explicit data-parallel step
+    ``fused`` (or ``fused_apply``, its older name) routes matrix parameters
+    through the shape-bucketed engine: one single-pass rule apply per
+    distinct matrix shape, the weight update folded into the per-bucket
+    kernel with ``use_kernel`` (which needs the engine);
+    ``momentum_dtype='bfloat16'`` halves its momentum storage; ``zero2``
+    (implies ``fused``) switches to the explicit data-parallel step
     with the matrix momentum *and* gradient buckets sharded over the data
     axis — reduce-scatter straight into the bucket shard, padded uneven
     buckets included (``compress`` picks the int8 error-feedback schedule
@@ -169,9 +168,8 @@ def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
             lr_adamw=cosine_with_warmup(lr_adamw * lr_scale, steps),
             matrix_embed=matrix_embed,
             use_kernel=use_kernel,
-            fused=fused,
+            fused=fused or fused_apply,
             momentum_dtype=momentum_dtype,
-            fused_apply=fused_apply or zero2,
             shard_axis="data" if zero2 else None,
             shard_size=shard_size,
         ))
@@ -241,19 +239,14 @@ def train(arch: Union[str, ModelConfig], optimizer: str = "rmnp",
         return compiled_, attention
 
     # one trace of the optimizer step gives both the launch count and the
-    # per-bucket kernel routes
-    log_launches = log_every and (fused or fused_apply or zero2 or use_kernel)
-    log_routes = use_kernel and optimizer == "rmnp" and (
-        fused or fused_apply or zero2)
-    if log_launches or log_routes:
+    # per-bucket RMNP kernel routes
+    if use_kernel and optimizer == "rmnp":
         with spans.span("setup/trace_optimizer"):
             launches = optimizer_kernel_launches(opt, params)
-    if log_launches:
-        detail = (f" ({len(opt_state.buckets)} shape buckets)"
-                  if hasattr(opt_state, "buckets") else "")
-        print(f"[train] preconditioner kernel launches/step: "
-              f"{len(launches)}{detail}")
-    if log_routes:
+        if log_every:
+            print(f"[train] preconditioner kernel launches/step: "
+                  f"{len(launches)} ({len(opt_state.buckets)} shape "
+                  f"buckets)")
         routes = kernel_routes(opt, params, launches)
         if report is not None:
             report.routes = routes
@@ -540,24 +533,20 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--dominance-every", type=int, default=0)
     ap.add_argument("--use-kernel", action="store_true")
-    ap.add_argument("--engine", default=None,
-                    choices=["per-leaf", "bucketed", "single-pass"],
-                    help="matrix-partition engine: 'per-leaf' (one "
-                         "preconditioner pass per parameter), 'bucketed' "
-                         "(shape-bucketed: one pass per distinct matrix "
-                         "shape), 'single-pass' (bucketed with the weight "
-                         "apply folded into the per-bucket pass — no fp32 "
-                         "d buffer, no separate apply_updates sweep)")
+    ap.add_argument("--engine", default="per-leaf",
+                    choices=["per-leaf", "single-pass"],
+                    help="matrix-partition engine: 'per-leaf' (the plain "
+                         "jax.numpy reference, one pass per parameter), "
+                         "'single-pass' (shape-bucketed: one rule apply per "
+                         "distinct matrix shape with the weight update "
+                         "folded in — no fp32 d buffer, no updates tree; "
+                         "--use-kernel needs it)")
     ap.add_argument("--momentum-dtype", default="float32",
                     choices=["float32", "bfloat16"],
                     help="bucketed matrix-momentum storage dtype")
-    ap.add_argument("--fused", action="store_true",
-                    help="DEPRECATED alias for --engine bucketed")
-    ap.add_argument("--fused-apply", action="store_true",
-                    help="DEPRECATED alias for --engine single-pass")
     ap.add_argument("--zero2", action="store_true",
                     help="explicit data-parallel step with ZeRO-2 sharding "
-                         "(implies --fused-apply): matrix momentum AND "
+                         "(implies --engine single-pass): matrix momentum AND "
                          "gradient buckets shard over the data axis — "
                          "gradients reduce-scatter straight into the bucket "
                          "shard, uneven buckets padded; only updated param "
@@ -579,8 +568,6 @@ def main():
                          "serialized all-reduce-then-all-update baseline; "
                          "'auto' (default) pipelines except the measured "
                          "accum=1 fp32-wire regression case")
-    ap.add_argument("--no-overlap", action="store_true",
-                    help="DEPRECATED alias for --overlap off")
     ap.add_argument("--no-matrix-embed", action="store_true",
                     help="AdamW on LM-head/embeddings (paper App D.4 ablation)")
     ap.add_argument("--stop-at", type=int, default=0,
@@ -636,29 +623,15 @@ def main():
                          "guard-skipped steps (suspected data poisoning)")
     args = ap.parse_args()
     enable_compile_cache()
-    engine = args.engine
-    if args.fused or args.fused_apply:
-        alias = "--fused-apply" if args.fused_apply else "--fused"
-        mapped = "single-pass" if args.fused_apply else "bucketed"
-        warnings.warn(f"{alias} is deprecated; use --engine {mapped}",
-                      DeprecationWarning, stacklevel=2)
-        if engine is None:
-            engine = mapped
-    engine = engine or "per-leaf"
     overlap = {"auto": None, "on": True, "off": False}[args.overlap]
-    if args.no_overlap:
-        warnings.warn("--no-overlap is deprecated; use --overlap off",
-                      DeprecationWarning, stacklevel=2)
-        overlap = False
     train(args.arch, args.optimizer, args.steps, args.batch, args.seq,
           args.lr_matrix, args.lr_adamw, reduced=not args.full,
           seed=args.seed, ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
           log_every=args.log_every, dominance_every=args.dominance_every,
           matrix_embed=not args.no_matrix_embed,
           use_kernel=args.use_kernel,
-          fused=engine in ("bucketed", "single-pass"),
+          fused=args.engine == "single-pass",
           momentum_dtype=args.momentum_dtype,
-          fused_apply=engine == "single-pass",
           zero2=args.zero2, compress=not args.no_compress,
           accum=args.accum, overlap=overlap,
           log_file=args.log_file, stop_at=args.stop_at,

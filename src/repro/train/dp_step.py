@@ -13,10 +13,10 @@ Optimizer state has three modes:
 * ``shard_state=False`` (ZeRO-0): state replicated, any optimizer works.
 * ``shard_state=True`` (ZeRO-1): the stacked per-bucket matrix momentum
   (core/bucketing.py) is sharded along its leading ``L`` axis — each rank
-  holds ``L/N`` slices, runs the single-pass fused-apply kernel on its
-  shard, and all-gathers only the updated param slices.  Per-rank stacked
-  momentum bytes drop by the data-axis size.  Requires a fused-apply
-  optimizer built with ``shard_axis=axis_name``; with ``shard_size=N`` the
+  holds ``L/N`` slices, runs the single-pass apply kernel on its shard,
+  and all-gathers only the updated param slices.  Per-rank stacked
+  momentum bytes drop by the data-axis size.  Requires an optimizer built
+  with ``shard_axis=axis_name``; with ``shard_size=N`` the
   buckets are padded so *every* bucket shards (uneven ``L`` included),
   without it uneven buckets fall back to replication individually
   (distributed/sharding.py ``bucket_specs``).
@@ -59,7 +59,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core import apply_updates, clip_by_global_norm
+from repro.core import clip_by_global_norm
 from repro.core.types import Optimizer, PyTree
 from repro.distributed.compression import (
     CompressionState, compressed_mean, compressed_reduce_scatter_leaf,
@@ -97,8 +97,8 @@ def make_dp_train_step(cfg: ModelConfig, opt: Optimizer, mesh: Mesh,
     replicated; optimizer state replicated (default) or ZeRO-sharded along
     the stacked-bucket ``L`` axis (``shard_state=True``, which needs
     ``opt_state`` — real or ``jax.eval_shape`` abstract — to derive the
-    per-bucket specs, and an optimizer built with ``fused_apply=True,
-    shard_axis=axis_name``).  ``zero2=True`` (implies ``shard_state``)
+    per-bucket specs, and an optimizer built with
+    ``shard_axis=axis_name``).  ``zero2=True`` (implies ``shard_state``)
     reduce-scatters the matrix gradient buckets straight into the shard;
     it needs the optimizer built with ``shard_size == the axis size``
     (padded buckets + ``update_apply_sharded``).  ``accum`` splits the
@@ -123,12 +123,12 @@ def make_dp_train_step(cfg: ModelConfig, opt: Optimizer, mesh: Mesh,
         raise ValueError(f"accum must be >= 1, got {accum}")
     state_spec = P()
     if shard_state:
-        if opt.update_apply is None:
+        if opt.bucket_plan is None:
             raise ValueError(
-                "shard_state=True requires a fused-apply optimizer "
-                "(fused_apply=True, shard_axis=axis_name): the sharded step "
-                "runs the update kernel on local momentum slices and "
-                "all-gathers the updated param slices")
+                "shard_state=True requires the bucketed engine (an optimizer "
+                "built with shard_axis=axis_name): the sharded step runs the "
+                "update kernel on local momentum slices and all-gathers the "
+                "updated param slices")
         if opt_state is None:
             raise ValueError(
                 "shard_state=True needs opt_state (the real state or its "
@@ -138,7 +138,7 @@ def make_dp_train_step(cfg: ModelConfig, opt: Optimizer, mesh: Mesh,
         if opt.update_apply_sharded is None or opt.bucket_plan is None:
             raise ValueError(
                 "zero2=True requires an optimizer exposing "
-                "update_apply_sharded (rmnp/mixed_optimizer built with "
+                "update_apply_sharded (make_optimizer built with "
                 "shard_axis=axis_name and shard_size=the axis size): the "
                 "ZeRO-2 step reduce-scatters gradient buckets straight "
                 "into the momentum shard")
@@ -239,13 +239,8 @@ def make_dp_train_step(cfg: ModelConfig, opt: Optimizer, mesh: Mesh,
                 ginfo = pipeline.finite_guard(grads)
             grads, clip_stats = clip_by_global_norm(grads, clip_norm)
             with jax.named_scope("optimizer"):
-                if opt.update_apply is not None:
-                    params, opt_state = opt.update_apply(grads, opt_state,
-                                                         params, step)
-                else:
-                    updates, opt_state = opt.update(grads, opt_state, params,
-                                                    step)
-                    params = apply_updates(params, updates)
+                params, opt_state = opt.update_apply(grads, opt_state,
+                                                     params, step)
         metrics = dict(metrics, grad_norm=clip_stats.global_norm,
                        clip_rate=clip_stats.clipped)
         if guard:

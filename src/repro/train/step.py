@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core import apply_updates, clip_by_global_norm
+from repro.core import clip_by_global_norm
 from repro.core.types import Optimizer
 from repro.models.model import forward, loss_fn
 from repro.train import faults
@@ -22,15 +22,14 @@ from repro.train import pipeline as pipeline_mod
 
 
 def _optimizer_call(opt: Optimizer, params, step: int = 0):
-    """``(fn, args)`` of one optimizer step over abstract values:
-    ``opt.update_apply`` when the optimizer carries the single-pass path,
-    else ``opt.update``.  For tracing; nothing is compiled or executed."""
+    """``(opt.update_apply, args)`` of one optimizer step over abstract
+    values.  For tracing; nothing is compiled or executed."""
     def abstract(t):
         return jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
     state = jax.eval_shape(opt.init, params)
-    fn = opt.update_apply if opt.update_apply is not None else opt.update
-    return fn, (abstract(params), state, abstract(params), jnp.int32(step))
+    return opt.update_apply, (abstract(params), state, abstract(params),
+                              jnp.int32(step))
 
 
 def optimizer_kernel_launches(opt: Optimizer, params, step: int = 0) -> list:
@@ -45,20 +44,20 @@ def optimizer_kernel_launches(opt: Optimizer, params, step: int = 0) -> list:
 
 def optimizer_launches(opt: Optimizer, params, step: int = 0) -> int:
     """Kernel (``pallas_call``) launches one optimizer step costs — the
-    quantity the shape-bucketed fused engine minimises: per-leaf kernels
-    launch once per matrix parameter, the fused path once per shape bucket.
+    quantity the shape-bucketed engine minimises: one launch per shape
+    bucket, not per matrix parameter.
     Pure tracing (abstract values); nothing is compiled or executed."""
     return len(optimizer_kernel_launches(opt, params, step))
 
 
 def kernel_routes(opt: Optimizer, params, launches) -> dict:
-    """Per shape bucket of ``opt``'s plan, the RMNP stripe-kernel launch
-    among ``launches`` (:func:`optimizer_kernel_launches`) its update
-    traces to, or ``None`` where the kernel's VMEM plan sent the bucket to
-    the XLA path."""
+    """Per shape bucket of ``opt``'s plan, the ``rmnp_rownorm_apply``
+    kernel launch among ``launches`` (:func:`optimizer_kernel_launches`)
+    its update traces to, or ``None`` where the kernel's VMEM plan sent
+    the bucket to the XLA path."""
     from repro.kernels.rmnp_update import MAX_BLOCK_N
 
-    rmnp = [ln for ln in launches if ln.name.startswith("rmnp_rownorm")]
+    rmnp = [ln for ln in launches if ln.name == "rmnp_rownorm_apply"]
 
     def of_bucket(ln, b):
         # stripe operands are (L, d_in, d_out padded to the lane block)
@@ -73,8 +72,7 @@ def optimizer_fp32_buffers(opt: Optimizer, params, shape,
                            step: int = 0) -> int:
     """Number of full-size fp32 buffers of exactly ``shape`` the optimizer
     step materializes (jaxpr equation outputs, recursive) — used to verify
-    the single-pass fused-apply path never writes the fp32 ``d`` bucket the
-    two-pass engine does."""
+    the engine never writes an fp32 ``d`` bucket."""
     from repro.kernels.ops import count_buffer_eqns
 
     fn, args = _optimizer_call(opt, params, step)
@@ -134,14 +132,8 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, *, clip_norm: float = 1.0,
         ginfo = pipeline_mod.finite_guard(grads) if guard else None
         grads, clip_stats = clip_by_global_norm(grads, clip_norm)
         with jax.named_scope("optimizer"):
-            if opt.update_apply is not None:
-                # single-pass fused apply: the kernel emits the new weights
-                # directly — no updates tree, no apply_updates pass
-                params, opt_state = opt.update_apply(grads, opt_state, params,
-                                                     step)
-            else:
-                updates, opt_state = opt.update(grads, opt_state, params, step)
-                params = apply_updates(params, updates)
+            params, opt_state = opt.update_apply(grads, opt_state, params,
+                                                 step)
         metrics = dict(metrics, grad_norm=clip_stats.global_norm,
                        clip_rate=clip_stats.clipped)
         if guard:

@@ -4,9 +4,11 @@ in the pytest process).
 
 Modes (argv[1]):
 
-* ``sweep``  — lower every registry optimizer x engine at fp32/accum1 and
-  assert every pass is finding-free; prints ``ANALYSIS_SWEEP_OK``.
-* ``broken`` — lower deliberately degraded rmnp/single-pass variants and
+* ``sweep``  — lower every registry optimizer through the bucketed
+  engine's ZeRO-2 path at fp32/accum1 and assert every pass is
+  finding-free;
+  prints ``ANALYSIS_SWEEP_OK``.
+* ``broken`` — lower deliberately degraded rmnp variants and
   assert the passes catch them; prints ``ANALYSIS_BREAK_OK``.
 """
 import os
@@ -34,15 +36,13 @@ def sweep():
         print(f"{f.severity.value} {f.pass_name} [{f.code}] "
               f"{f.combo or f.location}: {f.message}")
     assert not bad, f"{len(bad)} gate findings on the clean registry sweep"
-    engines = {(c.optimizer, c.engine) for c in combos}
     from repro.core import optimizer_names
-    assert engines == {(n, e) for n in optimizer_names()
-                       for e in ("bucketed", "single-pass")}
+    assert {c.optimizer for c in combos} == set(optimizer_names())
     print("ANALYSIS_SWEEP_OK")
 
 
 def broken():
-    combo = Combo("rmnp", "single-pass", "fp32", 1)
+    combo = Combo("rmnp", "fp32", 1)
 
     art = lowering.lower_combo(combo, break_mode="gather-momentum")
     fs = run_passes([art], only=["sharding", "memory"])

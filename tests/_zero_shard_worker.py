@@ -90,8 +90,9 @@ import numpy as np  # noqa: E402
 from jax import shard_map  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.core import bucketing, constant, mixed_optimizer  # noqa: E402
-from repro.core.rmnp import rmnp  # noqa: E402
+from repro.core import (  # noqa: E402
+    bucketing, constant, make_optimizer, mixed_optimizer,
+)
 from repro.core.types import tree_paths  # noqa: E402
 from repro.distributed.compression import exact_reduce_scatter  # noqa: E402
 from repro.distributed.sharding import bucket_specs  # noqa: E402
@@ -120,8 +121,9 @@ def synthetic_four_way():
     assert len(jax.devices()) >= 4, f"need 4 CPU devices, got {jax.devices()}"
     mesh = make_data_mesh(4)
     params, grads = make(0), make(1)
-    opt_sh = rmnp(constant(0.1), beta=0.9, shard_axis="data", shard_size=4)
-    opt_rep = rmnp(constant(0.1), beta=0.9, fused_apply=True)
+    opt_sh = make_optimizer("rmnp", lr_matrix=0.1, beta=0.9,
+                            shard_axis="data", shard_size=4)
+    opt_rep = make_optimizer("rmnp", lr_matrix=0.1, beta=0.9, fused=True)
     sizes = {b.key: b.size for b in opt_rep.bucket_plan(params).buckets}
 
     state = opt_sh.init(params)
@@ -175,7 +177,8 @@ def synthetic_traced_buffers():
     gradient-path intermediate.  ZeRO-2 must have none — the mean-gradient
     bucket never exists per rank — while ZeRO-1 gathers it (>= 1)."""
     mesh = make_data_mesh(4)
-    opt_sh = rmnp(constant(0.1), beta=0.9, shard_axis="data", shard_size=4)
+    opt_sh = make_optimizer("rmnp", lr_matrix=0.1, beta=0.9,
+                            shard_axis="data", shard_size=4)
     params = jax.tree_util.tree_map(lambda p: p.astype(jnp.bfloat16), make(0))
     grads = make(1)
     state = jax.eval_shape(opt_sh.init, params)
@@ -223,9 +226,9 @@ def dp_step_two_way():
     batch = {"tokens": toks, "labels": toks}
 
     opt_sh = mixed_optimizer("rmnp", constant(1e-2), constant(1e-2),
-                             fused_apply=True, shard_axis="data")
+                             shard_axis="data")
     opt_rep = mixed_optimizer("rmnp", constant(1e-2), constant(1e-2),
-                              fused_apply=True)
+                              fused=True)
     st_sh, st_rep = opt_sh.init(params), opt_rep.init(params)
     comp = init_dp_state(params, 2)
 
@@ -273,7 +276,7 @@ def dp_step_two_way_zero2():
     opt_z2 = mixed_optimizer("rmnp", constant(1e-2), constant(1e-2),
                              shard_axis="data", shard_size=2)
     opt_rep = mixed_optimizer("rmnp", constant(1e-2), constant(1e-2),
-                              fused_apply=True)
+                              fused=True)
     st_z2, st_rep = opt_z2.init(params), opt_rep.init(params)
     comp = init_dp_state(params, 2)
 
@@ -353,7 +356,7 @@ def dp_step_pipelined_four_way():
     opt = mixed_optimizer("rmnp", constant(1e-2), constant(1e-2),
                           shard_axis="data", shard_size=4)
     opt_rep = mixed_optimizer("rmnp", constant(1e-2), constant(1e-2),
-                              fused_apply=True)
+                              fused=True)
     st = opt.init(params)
     comp = init_dp_state(params, 4)
 
@@ -456,7 +459,6 @@ def rule_family_four_way():
     stepping — are bitwise the per-leaf reference optimizer
     (core/rules.py ``per_leaf_reference``), and pad slices stay
     identically zero in the momentum and in every slot."""
-    from repro.core.engine import matrix_optimizer
     from repro.core.rules import make_rule, per_leaf_reference, rule_names
 
     mesh = make_data_mesh(4)
@@ -464,8 +466,8 @@ def rule_family_four_way():
     sizes = None
     for name in rule_names():
         rule = make_rule(name, beta=0.9, ns_steps=2)
-        opt_sh = matrix_optimizer(rule, constant(0.1), fused_apply=True,
-                                  shard_axis="data", shard_size=4)
+        opt_sh = make_optimizer(name, lr_matrix=0.1, beta=0.9, ns_steps=2,
+                                shard_axis="data", shard_size=4)
         ref = per_leaf_reference(rule, constant(0.1))
         state = opt_sh.init(params)
         sizes = sizes or {b.key: b.size
@@ -553,7 +555,7 @@ def rule_family_overlap_report():
 def dp_step_shard_size_mismatch():
     """A ZeRO-2 optimizer built with the wrong shard_size is rejected up
     front, naming both numbers, instead of dying in a shape error inside
-    bucket_update_apply."""
+    the bucket apply."""
     from repro.configs import get_config
     from repro.models import init_params
     from repro.train.dp_step import make_dp_train_step
@@ -590,7 +592,8 @@ def two_phase_clip_bitwise():
               "norm/scale_1d": (33,), "head/bias_1d": (7,)}
     trees = [make(10 + r, shapes) for r in range(4)]
     stacked = {k: jnp.stack([t[k] for t in trees]) for k in trees[0]}
-    opt = rmnp(constant(0.1), beta=0.9, shard_axis="data", shard_size=4)
+    opt = make_optimizer("rmnp", lr_matrix=0.1, beta=0.9,
+                            shard_axis="data", shard_size=4)
     plan = opt.bucket_plan({k: v for k, v in make(0, shapes).items()
                             if v.ndim >= 2})
 
@@ -656,8 +659,6 @@ def elastic_phase(args):
     writer's mesh size differs — then train, checkpoint, and optionally
     SIGKILL itself mid-run or dump the final state."""
     from repro.checkpoint.manager import CheckpointManager
-    from repro.core.engine import matrix_optimizer
-    from repro.core.rules import make_rule
     from repro.distributed import compression, elastic
     from repro.distributed.compression import (
         compressed_reduce_scatter_leaf, init_compression_state)
@@ -667,9 +668,8 @@ def elastic_phase(args):
     mesh = make_data_mesh(n_dev)
 
     def build_opt(n):
-        return matrix_optimizer(make_rule(args.rule, beta=0.9, ns_steps=2),
-                                constant(0.05), fused_apply=True,
-                                shard_axis="data", shard_size=n)
+        return make_optimizer(args.rule, lr_matrix=0.05, beta=0.9,
+                              ns_steps=2, shard_axis="data", shard_size=n)
 
     opt = build_opt(n_dev)
     params = make(0)
@@ -881,17 +881,14 @@ def _ckpt_build(rule, n_dev=4):
     axis, EF residual sharded on its leading device axis.  Returns the
     pristine ``(params, state, comp)`` tuple (also the restore template)
     and an ``advance(state_tuple, t)`` closure running one real step."""
-    from repro.core.engine import matrix_optimizer
-    from repro.core.rules import make_rule
     from repro.distributed import compression
     from repro.distributed.compression import (
         compressed_reduce_scatter_leaf, init_compression_state)
 
     assert len(jax.devices()) >= n_dev, jax.devices()
     mesh = make_data_mesh(n_dev)
-    opt = matrix_optimizer(make_rule(rule, beta=0.9, ns_steps=2),
-                           constant(0.05), fused_apply=True,
-                           shard_axis="data", shard_size=n_dev)
+    opt = make_optimizer(rule, lr_matrix=0.05, beta=0.9, ns_steps=2,
+                         shard_axis="data", shard_size=n_dev)
     params = make(0)
     plan = opt.bucket_plan(params)
     state = opt.init(params)

@@ -40,7 +40,7 @@ BUCKET = BucketMeta(
 
 
 def _art(hlo="", combo=None, **kw):
-    return Artifacts(combo=combo or Combo("rmnp", "single-pass", "fp32"),
+    return Artifacts(combo=combo or Combo("rmnp", "fp32"),
                      hlo_text=hlo, **kw)
 
 
@@ -182,25 +182,25 @@ class TestFindings:
 class TestFramework:
     def test_combo_validation(self):
         with pytest.raises(ValueError):
-            Combo("rmnp", "zero3", "fp32")
+            Combo("rmnp", "fp16")
         with pytest.raises(ValueError):
-            Combo("rmnp", "bucketed", "fp16")
-        with pytest.raises(ValueError):
-            Combo("rmnp", "bucketed", "fp32", 0)
-        assert Combo("rmnp", "single-pass", "int8-ef", 4).id == \
-            "rmnp/single-pass/int8-ef/accum4"
+            Combo("rmnp", "fp32", 0)
+        assert Combo("rmnp", "int8-ef", 4).id == "rmnp/int8-ef/accum4"
+        assert Combo("rmnp", "fp32", guard=True).id == "rmnp/fp32/accum1/guard"
 
     def test_catalog_has_all_six_passes(self):
         names = {e["name"] for e in pass_catalog()}
         assert names == {"memory", "sharding", "donation", "overlap",
                          "kernel-lint", "conventions"}
 
-    def test_non_applicable_combo_gets_info_skip(self):
-        art = _art(GOOD_ZERO2, combo=Combo("rmnp", "bucketed", "fp32"),
-                   buckets=(BUCKET,))
-        fs = run_passes([art], only=["memory"])
-        assert [f.code for f in fs] == ["not-applicable"]
-        assert fs[0].severity is Severity.INFO
+    def test_unknown_pass_raises(self):
+        """Asking the runner for a pass nobody registered is an error that
+        lists what is registered, not an empty (green) report."""
+        art = _art(GOOD_ZERO2, buckets=(BUCKET,))
+        with pytest.raises(ValueError, match="unknown pass") as ei:
+            run_passes([art], only=["memory", "no-such-pass"])
+        assert "no-such-pass" in str(ei.value)
+        assert "memory" in str(ei.value)
 
 
 # ---------------------------------------------------------------------------
@@ -429,7 +429,8 @@ class TestKernelIntrospection:
 
         g = jnp.zeros((2, 64, 256), jnp.float32)
         launches = introspect.collect_kernel_launches(
-            lambda: ops.rmnp_bucket_update(g, g, beta=0.95))
+            lambda: ops.rmnp_bucket_update_apply(g, g, g, 0.1, 0.1,
+                                                 beta=0.95))
         assert len(launches) == 1
         ln = launches[0]
         assert ln.grid and all(isinstance(d, int) for d in ln.grid)
@@ -439,10 +440,11 @@ class TestKernelIntrospection:
         for b in blocks:
             assert introspect.block_coverage(ln, b)["covers"]
         # blocks double-buffered at their dtype: the block part of the
-        # kernel's own accounting (four fp32 (64, 256) blocks, no temps)
+        # kernel's own accounting (g, v, w in and v_new, w_new out: five
+        # fp32 (64, 256) blocks, no temps; the SMEM scalars are not VMEM)
         from repro.kernels.rmnp_update import stripe_vmem_bytes
-        assert ln.name == "rmnp_rownorm"
-        assert ln.vmem_block_bytes() == stripe_vmem_bytes(64, 256, [4] * 4, 0)
+        assert ln.name == "rmnp_rownorm_apply"
+        assert ln.vmem_block_bytes() == stripe_vmem_bytes(64, 256, [4] * 5, 0)
 
     def test_gappy_grid_detected(self):
         import jax.numpy as jnp

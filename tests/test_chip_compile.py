@@ -58,10 +58,15 @@ def _assert_kernel(compiled):
 @pytest.mark.parametrize("shape", GPT2_LARGE_BUCKETS,
                          ids=["x".join(map(str, s)) for s in GPT2_LARGE_BUCKETS])
 def test_rownorm_kernel_compiles(one_chip, shape):
-    g, v = _spec(shape, G_DT, one_chip), _spec(shape, V_DT, one_chip)
-    assert rmnp_update.rownorm_plan(g, v) is not None
-    compiled = rmnp_update.rmnp_momentum_rownorm_2d.lower(
-        g, v, beta=0.95, interpret=False).compile()
+    """The apply kernel with bf16 momentum storage (``momentum_dtype=
+    "bfloat16"``): half-width momentum blocks, the same lane plan rule."""
+    g = _spec(shape, G_DT, one_chip)
+    v, w = _spec(shape, jnp.bfloat16, one_chip), _spec(shape, W_DT, one_chip)
+    plan = rmnp_update.rownorm_apply_plan(g, v, w)
+    assert plan is not None and plan.block_n % rmnp_update.LANE == 0
+    scalars = _spec((2,), jnp.float32, one_chip)
+    compiled = rmnp_update.rmnp_rownorm_apply_2d.lower(
+        g, v, w, scalars, beta=0.95, interpret=False).compile()
     _assert_kernel(compiled)
 
 
@@ -97,7 +102,7 @@ def test_replicated_kernel_step_cannot_partition(topo, monkeypatch):
                        match="Mosaic kernels cannot be automatically "
                              "partitioned"):
         train("gpt2-60m", steps=2, batch=4, seq=16, reduced=True,
-              fused=True, fused_apply=True, use_kernel=True, log_every=0,
+              fused=True, use_kernel=True, log_every=0,
               devices=list(topo.devices))
 
 
@@ -187,7 +192,7 @@ def test_train_step_updates_donated_state_in_place(topo, monkeypatch):
     report = StepReport()
     with pytest.raises(Exception):
         train(cfg, steps=1, batch=1, seq=128, reduced=False, fused=True,
-              fused_apply=True, use_kernel=True, log_every=0,
+              use_kernel=True, log_every=0,
               devices=[topo.devices[0]], report=report)
     calls = re.findall(r"%rmnp_rownorm_apply[\w.]* = .* custom-call\(.*",
                        report.hlo_text)

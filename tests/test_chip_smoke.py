@@ -1,12 +1,13 @@
 """The checks of ``chip_smoke.py`` at a tiny size on the CPU.
 
-The kernel check runs the RMNP kernels in interpret mode here.  A sound
+The kernel check runs the RMNP kernel in interpret mode here.  A sound
 kernel passes it, and each planted fault in the single-pass kernel's
-weight update (or in the precondition-only kernel's direction) fails it.
+weight update or direction fails it.
 """
 import importlib.util
 from pathlib import Path
 
+import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ops
@@ -18,7 +19,6 @@ _spec.loader.exec_module(chip_smoke)
 
 SHAPES = [(2, 256, 128)]
 _apply = ops.rmnp_bucket_update_apply
-_precond = ops.rmnp_bucket_update
 
 
 def test_platform_check_names_the_platform():
@@ -63,9 +63,11 @@ def test_kernel_check_catches_planted_update_fault(monkeypatch, fault):
 
 
 def test_kernel_check_catches_unnormalized_direction(monkeypatch):
-    def unnormalized(g, v, *, beta):
-        v_new, _ = _precond(g, v, beta=beta)
-        return v_new, v_new
-    monkeypatch.setattr(ops, "rmnp_bucket_update", unnormalized)
-    with pytest.raises(chip_smoke.SmokeFailure, match="kernel d"):
+    def unnormalized(g, v, w, scale, wd, *, beta):
+        v_new, _ = _apply(g, v, w, scale, wd, beta=beta)
+        w32 = w.astype(jnp.float32)
+        w_new = w32 + (-scale) * (v_new + wd * w32)
+        return v_new, w_new.astype(w.dtype)
+    monkeypatch.setattr(ops, "rmnp_bucket_update_apply", unnormalized)
+    with pytest.raises(chip_smoke.SmokeFailure, match="kernel update"):
         chip_smoke.phase_kernel_check(SHAPES, seed=0)

@@ -23,9 +23,8 @@ import numpy as np
 import pytest
 
 from repro.checkpoint.manager import CheckpointManager
-from repro.core import bucketing, constant, mixed_optimizer
-from repro.core.engine import matrix_optimizer
-from repro.core.rules import make_rule, rule_names
+from repro.core import bucketing, constant, make_optimizer, mixed_optimizer
+from repro.core.rules import rule_names
 from repro.distributed import compression, elastic
 from repro.distributed.compression import init_compression_state
 from repro.distributed.monitor import HangGuard
@@ -43,9 +42,8 @@ def make(seed, shapes=None):
 
 
 def build_opt(rule, n):
-    return matrix_optimizer(make_rule(rule, beta=0.9, ns_steps=2),
-                            constant(0.05), fused_apply=True,
-                            shard_axis="data", shard_size=n)
+    return make_optimizer(rule, lr_matrix=0.05, beta=0.9, ns_steps=2,
+                          shard_axis="data", shard_size=n)
 
 
 def warm_state(opt, params, steps=2):
@@ -136,10 +134,10 @@ class TestReshardTransform:
         """FusedMixedState: stacked matrix buckets reshard, the per-leaf
         AdamW momenta pass through untouched."""
         opt8 = mixed_optimizer("normuon", constant(0.05), constant(0.01),
-                               ns_steps=2, fused=True, fused_apply=True,
+                               ns_steps=2, fused=True,
                                shard_axis="data", shard_size=8)
         opt2 = mixed_optimizer("normuon", constant(0.05), constant(0.01),
-                               ns_steps=2, fused=True, fused_apply=True,
+                               ns_steps=2, fused=True,
                                shard_axis="data", shard_size=2)
         params = {**make(0), "head/b": jnp.ones((16,), jnp.float32)}
         state8 = opt8.init(params)
@@ -356,14 +354,14 @@ class TestTrainElasticRestore:
 
         # re-lay the state out for a 4-way mesh and save it to a second dir
         from repro.configs import get_config
-        from repro.core import cosine_with_warmup, make_optimizer
+        from repro.core import cosine_with_warmup
         from repro.models import init_params
 
         def opt_for(n):
             return make_optimizer("rmnp", dict(
                 lr_matrix=cosine_with_warmup(2e-3, steps),
                 lr_adamw=cosine_with_warmup(1e-3, steps),
-                fused_apply=True, shard_axis="data", shard_size=n))
+                shard_axis="data", shard_size=n))
 
         opt1, opt4 = opt_for(1), opt_for(4)
         cfg = get_config(arch).reduced()

@@ -1,14 +1,14 @@
-"""Single-pass fused apply (the update folded into the RMNP kernel) and
+"""Single-pass apply (the update folded into the RMNP kernel) and
 ZeRO-1/2 sharding of the bucketed optimizer state and gradients.
 
 Invariants under test:
-  * the fused-apply path (``Optimizer.update_apply``) is bit-for-bit with
-    fp32 storage against the two-pass update + apply_updates reference,
-    jitted, on both the XLA and interpret-mode Pallas backends;
-  * it materializes strictly fewer full-bucket fp32 buffers than the
-    two-pass path, and its ``pallas_call`` no longer emits the fp32 ``d``
-    bucket (with bf16 momentum the kernel's only fp32 bucket-shaped output
-    is the updated weights);
+  * the bucketed engine's ``Optimizer.update_apply`` is bit-for-bit with
+    fp32 storage against the per-leaf references (``per_leaf_reference``
+    for matrix leaves, the per-leaf mixed path for the rest), on both the
+    XLA and interpret-mode Pallas backends;
+  * it materializes no full-bucket fp32 ``d`` buffer, and its
+    ``pallas_call`` emits no fp32 bucket-shaped output with bf16 momentum
+    and bf16 weights;
   * kernel launches stay one per shape bucket;
   * bf16 momentum storage drifts boundedly from fp32 storage over a ~50
     step fused-apply run;
@@ -33,11 +33,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import apply_updates, constant, mixed_optimizer
-from repro.core.bucketing import build_plan
-from repro.core.rmnp import rmnp
+from repro.core import (constant, make_optimizer, mixed_optimizer,
+                         optimizer_names)
+from repro.core.bucketing import build_plan, gather
+from repro.core.rules import RmnpRule, per_leaf_reference
 from repro.launch.mesh import make_data_mesh
-from repro.train.step import optimizer_fp32_buffers, optimizer_launches
+from repro.kernels.ops import count_buffer_eqns
+from repro.train.step import (_optimizer_call, optimizer_fp32_buffers,
+                              optimizer_launches)
 
 RAGGED_SHAPES = {
     "layer_0/w_in": (8, 16),
@@ -58,64 +61,85 @@ def make_tree(shapes, seed=0, with_vectors=False):
     return tree
 
 
+def bucketed(use_kernel=False, **kw):
+    """The bucketed RMNP engine through the registry."""
+    return make_optimizer("rmnp", dict(lr_matrix=0.1, fused=True,
+                                       use_kernel=use_kernel, **kw))
+
+
+def matrix_reference(use_kernel, beta=0.95):
+    """The per-leaf reference for RMNP's matrix leaves on one backend.  On
+    XLA it is the per-leaf mixed path, the paper's formulation written
+    apart from the rules and the kernel (``row_normalize``, then the
+    updates tree).  The interpret-mode kernel rounds its row norm
+    differently from that formulation (within 1e-5 of it,
+    tests/test_kernels.py), so its bit-for-bit reference launches the
+    same kernel once per leaf (``per_leaf_reference``)."""
+    if use_kernel:
+        return per_leaf_reference(RmnpRule(beta=beta), constant(0.1),
+                                  use_kernel=True)
+    return mixed_optimizer("rmnp", constant(0.1), constant(0.05), beta=beta)
+
+
 class TestSinglePassBitwise:
-    """Both paths jitted: the jit boundary is where they run in production,
-    and identical compilation granularity is what makes fp32 bit-parity a
-    fair claim (eagerly, XLA fuses the two-pass epilogue differently)."""
+    """The engine against the per-leaf references, op by op.  Eagerly
+    every operation is its own program, so stacking slices into a bucket
+    changes no value; jitted, XLA fuses the per-leaf and the stacked
+    programs differently and may contract a multiply-add differently
+    (ROADMAP D1).  The jitted engine, the one that runs in production,
+    agrees to FMA-contraction level."""
 
     @pytest.mark.parametrize("use_kernel", [False, True],
                              ids=["xla", "pallas-interpret"])
     def test_rmnp_matches_two_pass(self, use_kernel):
         params = make_tree(RAGGED_SHAPES)
-        two = rmnp(constant(0.1), beta=0.9, use_kernel=use_kernel, fused=True)
-        one = rmnp(constant(0.1), beta=0.9, use_kernel=use_kernel,
-                   fused_apply=True)
-
-        @jax.jit
-        def two_pass(g, s, p, step):
-            u, s2 = two.update(g, s, p, step)
-            return apply_updates(p, u), s2
-
-        one_pass = jax.jit(one.update_apply)
-        sr, sf = two.init(params), one.init(params)
-        pr, pf = params, params
+        ref = matrix_reference(use_kernel, beta=0.9)
+        eng = bucketed(use_kernel, beta=0.9)
+        plan = eng.bucket_plan(params)
+        jitted = jax.jit(eng.update_apply)
+        sr, sf, sj = ref.init(params), eng.init(params), eng.init(params)
+        pr, pf, pj = params, params, params
         for step in range(3):
             grads = make_tree(RAGGED_SHAPES, seed=100 + step)
-            pr, sr = two_pass(grads, sr, pr, jnp.int32(step))
-            pf, sf = one_pass(grads, sf, pf, jnp.int32(step))
+            pr, sr = ref.update_apply(grads, sr, pr, jnp.int32(step))
+            pf, sf = eng.update_apply(grads, sf, pf, jnp.int32(step))
+            pj, sj = jitted(grads, sj, pj, jnp.int32(step))
             for k in pr:
                 np.testing.assert_array_equal(
                     np.asarray(pr[k]), np.asarray(pf[k]),
                     err_msg=f"{k} (use_kernel={use_kernel}, step={step})")
-            for k in sr.buckets:
+                np.testing.assert_allclose(
+                    np.asarray(pj[k]), np.asarray(pf[k]), rtol=1e-6,
+                    atol=1e-7, err_msg=f"{k} jitted (step={step})")
+            ref_buckets = gather(plan, sr.momentum)
+            for k in sf.buckets:
                 np.testing.assert_array_equal(
-                    np.asarray(sr.buckets[k]), np.asarray(sf.buckets[k]))
+                    np.asarray(ref_buckets[k]), np.asarray(sf.buckets[k]))
 
     @pytest.mark.parametrize("use_kernel", [False, True],
                              ids=["xla", "pallas-interpret"])
     def test_mixed_matches_two_pass(self, use_kernel):
+        """Matrix leaves against ``matrix_reference``, AdamW leaves against
+        the per-leaf mixed path."""
         params = make_tree(RAGGED_SHAPES, with_vectors=True)
-        two = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
+        matrix = {k: v for k, v in params.items() if k in RAGGED_SHAPES}
+        leaf = mixed_optimizer("rmnp", constant(0.1), constant(0.05))
+        ref = matrix_reference(use_kernel)
+        eng = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
                               use_kernel=use_kernel, fused=True)
-        one = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
-                              use_kernel=use_kernel, fused_apply=True)
-
-        @jax.jit
-        def two_pass(g, s, p, step):
-            u, s2 = two.update(g, s, p, step)
-            return apply_updates(p, u), s2
-
-        one_pass = jax.jit(one.update_apply)
-        sr, sf = two.init(params), one.init(params)
-        pr, pf = params, params
+        sl, sr, sf = leaf.init(params), ref.init(matrix), eng.init(params)
+        pl_, pr, pf = params, matrix, params
         for step in range(3):
             grads = make_tree(RAGGED_SHAPES, seed=100 + step,
                               with_vectors=True)
-            pr, sr = two_pass(grads, sr, pr, jnp.int32(step))
-            pf, sf = one_pass(grads, sf, pf, jnp.int32(step))
-            for k in pr:
+            pl_, sl = leaf.update_apply(grads, sl, pl_, jnp.int32(step))
+            pr, sr = ref.update_apply({k: grads[k] for k in matrix}, sr, pr,
+                                      jnp.int32(step))
+            pf, sf = eng.update_apply(grads, sf, pf, jnp.int32(step))
+            for k in pf:
+                want = pr[k] if k in matrix else pl_[k]
                 np.testing.assert_array_equal(
-                    np.asarray(pr[k]), np.asarray(pf[k]),
+                    np.asarray(want), np.asarray(pf[k]),
                     err_msg=f"{k} (use_kernel={use_kernel}, step={step})")
 
     def test_mixed_dtype_bucket_keeps_leaf_dtypes(self):
@@ -125,46 +149,70 @@ class TestSinglePassBitwise:
         params = {"a/w": jnp.zeros((8, 16), jnp.bfloat16),
                   "b/w": jnp.zeros((8, 16), jnp.float32)}
         grads = make_tree({"a/w": (8, 16), "b/w": (8, 16)}, seed=3)
-        opt = rmnp(constant(0.1), fused_apply=True)
+        opt = bucketed()
         new_params, _ = jax.jit(opt.update_apply)(
             grads, opt.init(params), params, jnp.int32(0))
         assert new_params["a/w"].dtype == jnp.bfloat16
         assert new_params["b/w"].dtype == jnp.float32
 
     def test_fused_apply_implies_fused(self):
-        opt = rmnp(constant(0.1), fused_apply=True)
-        assert opt.update_apply is not None
+        """``fused`` selects the bucketed engine, whose one entry point is
+        ``update_apply``: no updates tree, stacked state."""
+        opt = bucketed()
+        assert opt.update_apply is not None and opt.update is None
         state = opt.init(make_tree(RAGGED_SHAPES))
         assert hasattr(state, "buckets")
-        # plain fused keeps the two-pass-only contract
-        assert rmnp(constant(0.1), fused=True).update_apply is None
+        # the per-leaf path keeps its updates tree, applied by update_apply
+        leaf = mixed_optimizer("rmnp", constant(0.1), constant(0.05))
+        assert leaf.update is not None and leaf.update_apply is not None
+        assert not hasattr(leaf.init(make_tree(RAGGED_SHAPES)), "buckets")
 
     def test_shard_axis_implies_fused_apply(self):
-        """shard_axis without update_apply would silently replicate the
-        state, so setting it must enable the single-pass path."""
-        assert rmnp(constant(0.1), shard_axis="data").update_apply is not None
+        """shard_axis outside the bucketed layout would silently replicate
+        the state, so setting it must select the bucketed engine."""
+        assert make_optimizer("rmnp", lr_matrix=0.1,
+                              shard_axis="data").bucket_plan is not None
         assert mixed_optimizer("rmnp", constant(0.1), constant(0.05),
-                               shard_axis="data").update_apply is not None
+                               shard_axis="data").bucket_plan is not None
+
+
+@pytest.mark.parametrize("name", optimizer_names())
+def test_use_kernel_needs_the_bucketed_engine(name):
+    """The per-leaf path is plain jax.numpy and runs no kernel, so asking it
+    for one raises, naming the fix, instead of falling back silently;
+    rules that exist only bucketed take the engine anyway."""
+    if name in ("rmnp", "muon", "adamw"):
+        with pytest.raises(ValueError, match="fused=True"):
+            make_optimizer(name, lr_matrix=0.1, use_kernel=True)
+    else:
+        assert make_optimizer(name, lr_matrix=0.1,
+                              use_kernel=True).bucket_plan is not None
+    opt = make_optimizer(name, lr_matrix=0.1, use_kernel=True, fused=True)
+    assert opt.bucket_plan is not None and opt.update is None
 
 
 class TestNoFp32Intermediate:
     """The single-pass engine's memory claim, verified by tracing."""
 
     def test_fewer_full_bucket_fp32_buffers(self):
-        params = make_tree({"a/w": (8, 16), "b/w": (8, 16), "c/w": (2, 8, 16)})
+        """With bf16 weights and bf16 momentum the kernel path's only
+        full-bucket fp32 buffer is the gathered gradient: no fp32 ``d``
+        bucket, no fp32 update."""
+        params = jax.tree_util.tree_map(
+            lambda p: p.astype(jnp.bfloat16),
+            make_tree({"a/w": (8, 16), "b/w": (8, 16), "c/w": (2, 8, 16)}))
         bucket_shape = (4, 8, 16)
-        two = optimizer_fp32_buffers(
-            rmnp(constant(0.1), use_kernel=True, fused=True), params,
-            bucket_shape)
-        one = optimizer_fp32_buffers(
-            rmnp(constant(0.1), use_kernel=True, fused_apply=True), params,
-            bucket_shape)
-        assert one < two, (one, two)
+        opt = bucketed(True, momentum_dtype="bfloat16")
+        one = optimizer_fp32_buffers(opt, params, bucket_shape)
+        # the one: the gradient gather (a concatenate of the fp32 leaves)
+        assert one == 1, one
+        fn, args = _optimizer_call(opt, params)
+        assert count_buffer_eqns(fn, bucket_shape, jnp.float32, *args,
+                                 exclude_prims=("concatenate",)) == 0
 
     def test_kernel_emits_no_fp32_d_bucket(self):
-        """With bf16 momentum AND bf16 params, the two-pass kernel's only
-        fp32 output is the ``d`` bucket; the fused-apply kernel must have no
-        fp32 bucket-shaped output at all."""
+        """With bf16 momentum AND bf16 params, the apply kernel must have no
+        fp32 bucket-shaped output at all (a ``d`` bucket would be one)."""
         from repro.kernels.ops import _walk_eqns
 
         shapes = {"a/w": (8, 16), "b/w": (8, 16), "c/w": (2, 8, 16)}
@@ -191,20 +239,16 @@ class TestNoFp32Intermediate:
 
             return _walk_eqns(closed.jaxpr, visit)
 
-        two = rmnp(constant(0.1), use_kernel=True, fused=True,
-                   momentum_dtype="bfloat16")
-        one = rmnp(constant(0.1), use_kernel=True, fused_apply=True,
-                   momentum_dtype="bfloat16")
-        assert pallas_fp32_outputs(two, "update") == 1      # the d bucket
+        one = bucketed(True, momentum_dtype="bfloat16")
         assert pallas_fp32_outputs(one, "update_apply") == 0
 
     def test_launches_stay_one_per_bucket(self):
         params = make_tree(RAGGED_SHAPES)
         n_buckets = len(build_plan(params).buckets)
-        one = rmnp(constant(0.1), use_kernel=True, fused_apply=True)
+        one = bucketed(True)
         assert optimizer_launches(one, params) == n_buckets == 3
         mixed = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
-                                use_kernel=True, fused_apply=True)
+                                use_kernel=True, fused=True)
         assert optimizer_launches(
             mixed, make_tree(RAGGED_SHAPES, with_vectors=True)) == 3
 
@@ -216,9 +260,9 @@ class TestBf16MomentumDrift:
         divergent — over a multi-step fused-apply run."""
         shapes = {"a/w": (8, 16), "b/w": (16, 8), "s/w": (2, 8, 16)}
         params = make_tree(shapes)
-        o32 = rmnp(constant(0.05), beta=0.9, fused_apply=True)
-        o16 = rmnp(constant(0.05), beta=0.9, fused_apply=True,
-                   momentum_dtype="bfloat16")
+        o32 = make_optimizer("rmnp", lr_matrix=0.05, beta=0.9, fused=True)
+        o16 = make_optimizer("rmnp", lr_matrix=0.05, beta=0.9, fused=True,
+                             momentum_dtype="bfloat16")
         s32, s16 = o32.init(params), o16.init(params)
         step32 = jax.jit(o32.update_apply)
         step16 = jax.jit(o16.update_apply)
@@ -267,10 +311,9 @@ class TestZeroSharding:
 
         mesh = make_data_mesh(1)
         cfg = get_config("gpt2-60m").reduced()
-        two_pass = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
-                                   fused=True)
-        with pytest.raises(ValueError, match="fused-apply"):
-            make_dp_train_step(cfg, two_pass, mesh, shard_state=True)
+        per_leaf = mixed_optimizer("rmnp", constant(0.1), constant(0.05))
+        with pytest.raises(ValueError, match="bucketed engine"):
+            make_dp_train_step(cfg, per_leaf, mesh, shard_state=True)
 
     def test_shard_state_requires_state_example(self):
         from repro.configs import get_config
@@ -279,7 +322,7 @@ class TestZeroSharding:
         mesh = make_data_mesh(1)
         cfg = get_config("gpt2-60m").reduced()
         opt = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
-                              fused_apply=True, shard_axis="data")
+                              shard_axis="data")
         with pytest.raises(ValueError, match="opt_state"):
             make_dp_train_step(cfg, opt, mesh, shard_state=True)
 
@@ -293,7 +336,7 @@ class TestZeroSharding:
         mesh = make_data_mesh(1)
         cfg = get_config("gpt2-60m").reduced()
         opt = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
-                              fused_apply=True)
+                              fused=True)
         state = jax.eval_shape(
             opt.init, {"a/w": jnp.zeros((8, 16), jnp.float32)})
         with pytest.raises(ValueError, match="update_apply_sharded"):
@@ -311,7 +354,7 @@ class TestZeroSharding:
         # 'conv' token routes this 3-D leaf to AdamW (full-shape mu/nu)
         params["rel_pos_buckets/conv"] = jnp.zeros((4, 3, 64))
         opt = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
-                              fused_apply=True)
+                              fused=True)
         state = opt.init(params)
         assert state.momentum["rel_pos_buckets/conv"].shape == (4, 3, 64)
         specs = bucket_specs(state, mesh)
@@ -325,7 +368,7 @@ class TestZeroSharding:
         from repro.distributed.sharding import bucket_specs
 
         mesh = make_data_mesh(1)
-        opt = rmnp(constant(0.1), fused_apply=True)
+        opt = bucketed()
         state = opt.init(make_tree(RAGGED_SHAPES))
         specs = bucket_specs(state, mesh)
         # size-1 mesh axis: every bucket falls back to replication
@@ -340,8 +383,9 @@ class TestPaddedBuckets:
 
     def test_padded_replicated_matches_unpadded(self):
         params = make_tree(RAGGED_SHAPES)
-        pad = rmnp(constant(0.1), beta=0.9, shard_axis="data", shard_size=4)
-        ref = rmnp(constant(0.1), beta=0.9, fused_apply=True)
+        pad = make_optimizer("rmnp", lr_matrix=0.1, beta=0.9,
+                             shard_axis="data", shard_size=4)
+        ref = bucketed(beta=0.9)
         sizes = {b.key: b.size for b in ref.bucket_plan(params).buckets}
         sp, sr = pad.init(params), ref.init(params)
         pp, pr = params, params
@@ -375,52 +419,50 @@ class TestPaddedBuckets:
 
     def test_shard_size_needs_axis(self):
         with pytest.raises(ValueError, match="shard_axis"):
-            rmnp(constant(0.1), shard_size=4)
+            make_optimizer("rmnp", lr_matrix=0.1, shard_size=4)
         with pytest.raises(ValueError, match="shard_axis"):
             mixed_optimizer("rmnp", constant(0.1), constant(0.05),
                             shard_size=4)
 
 
 class TestShardInference:
-    """bucket_update_apply must validate the momentum slice count instead of
-    inferring sharding from any size mismatch — a stale or mis-meshed buffer
-    would otherwise produce a garbage dynamic_slice."""
+    """BucketedEngine.bucket_apply must validate the momentum slice count
+    instead of inferring sharding from any size mismatch — a stale or
+    mis-meshed buffer would otherwise produce a garbage dynamic_slice."""
 
-    def test_missized_momentum_raises(self):
-        from repro.core.bucketing import bucket_update_apply, build_plan
+    @staticmethod
+    def _engine(shard_axis=None):
+        from repro.core.engine import BucketedEngine
 
         params = make_tree({"a/w": (8, 16), "b/w": (2, 8, 16), "c/w": (8, 16)})
-        (b,) = build_plan(params).buckets  # L=4
+        eng = BucketedEngine(RmnpRule(beta=0.9, weight_decay=0.0),
+                             constant(0.1), shard_axis=shard_axis)
+        (b,) = eng.plan(params).buckets  # L=4
+        return eng, b
+
+    def test_missized_momentum_raises(self):
+        eng, b = self._engine(shard_axis="data")
         g = jnp.zeros((4, 8, 16), jnp.float32)
         w = jnp.zeros((4, 8, 16), jnp.float32)
         v_bad = jnp.zeros((3, 8, 16), jnp.float32)  # 4 % 3 != 0
         with pytest.raises(ValueError) as ei:
-            bucket_update_apply(b, g, v_bad, w, scale=0.1, weight_decay=0.0,
-                                beta=0.9, eps=1e-8, shard_axis="data")
+            eng.bucket_apply(b, g, v_bad, {}, w, 0)
         msg = str(ei.value)
         assert "8x16" in msg and "3" in msg and "4" in msg
 
     def test_missized_operands_raise(self):
-        from repro.core.bucketing import bucket_update_apply, build_plan
-
-        params = make_tree({"a/w": (8, 16), "b/w": (2, 8, 16), "c/w": (8, 16)})
-        (b,) = build_plan(params).buckets
+        eng, b = self._engine()
         v = jnp.zeros((4, 8, 16), jnp.float32)
         g_bad = jnp.zeros((3, 8, 16), jnp.float32)
         with pytest.raises(ValueError, match="padded bucket"):
-            bucket_update_apply(b, g_bad, v, g_bad, scale=0.1,
-                                weight_decay=0.0, beta=0.9, eps=1e-8)
+            eng.bucket_apply(b, g_bad, v, {}, g_bad, 0)
 
     def test_sharded_without_axis_raises(self):
-        from repro.core.bucketing import bucket_update_apply, build_plan
-
-        params = make_tree({"a/w": (8, 16), "b/w": (2, 8, 16), "c/w": (8, 16)})
-        (b,) = build_plan(params).buckets
+        eng, b = self._engine()
         g = jnp.zeros((4, 8, 16), jnp.float32)
         v_shard = jnp.zeros((2, 8, 16), jnp.float32)
         with pytest.raises(ValueError, match="shard_axis"):
-            bucket_update_apply(b, g, v_shard, g, scale=0.1,
-                                weight_decay=0.0, beta=0.9, eps=1e-8)
+            eng.bucket_apply(b, g, v_shard, {}, g, 0)
 
 
 class TestPlanCache:
@@ -468,7 +510,7 @@ class TestPlanCache:
     def test_hit_on_reused_signature(self):
         """Two param trees with identical (path, shape) signatures share
         the cached plan object — values don't matter, metadata does."""
-        opt = rmnp(constant(0.1), fused_apply=True)
+        opt = bucketed()
         shapes = {"a/w": (8, 16), "b/w": (2, 8, 16)}
         plan1 = opt.bucket_plan(make_tree(shapes, seed=0))
         plan2 = opt.bucket_plan(make_tree(shapes, seed=9))
@@ -483,7 +525,7 @@ class TestPlanCache:
         """A jitted step whose plan gets evicted keeps working: the plan is
         baked into the existing trace, and a re-trace (new signature churn
         in between) just rebuilds it."""
-        opt = rmnp(constant(0.1), fused_apply=True)
+        opt = bucketed()
         shapes = {"w": (8, 16)}
         params = make_tree(shapes, seed=0)
         grads = make_tree(shapes, seed=1)
@@ -503,7 +545,7 @@ class TestPlanCache:
                                       np.asarray(p_after["w"]))
 
     def test_optimizer_plan_cache_bounded(self):
-        opt = rmnp(constant(0.1), fused_apply=True)
+        opt = bucketed()
         step = None
         for i in range(12):  # > PlanCache default maxsize
             shapes = {"w": (8, 16 + 8 * i)}
@@ -548,10 +590,16 @@ class TestTrainStepDispatch:
                                      for ln in report.routes.values())
 
     def test_pjit_step_uses_update_apply(self):
-        """make_train_step must route through update_apply when present:
-        the two optimizers share math, so one fused-apply step from the same
-        state must equal the two-pass step bit-for-bit (fp32 model)."""
+        """make_train_step routes every optimizer through update_apply: the
+        per-leaf path's update_apply is its update + apply_updates, the
+        bucketed engine's the single pass, and they share math, so one step
+        from the same state is equal bit for bit (fp32 model).  The two
+        steps are compared op by op: jitted, XLA fuses the per-leaf and the
+        stacked programs differently and may contract a multiply-add
+        differently (ROADMAP D1).  The jitted bucketed step, the one that
+        runs in production, agrees with them to FMA-contraction level."""
         from repro.configs import get_config
+        from repro.core.types import tree_paths
         from repro.models import init_params
         from repro.train.step import make_train_step
 
@@ -561,13 +609,19 @@ class TestTrainStepDispatch:
                                   cfg.vocab)
         batch = {"tokens": toks, "labels": toks}
         outs = {}
-        for name, kw in (("two", dict(fused=True)),
-                         ("one", dict(fused_apply=True))):
+        for name, kw in (("leaf", dict()),
+                         ("one", dict(fused=True))):
             opt = mixed_optimizer("rmnp", constant(1e-2), constant(1e-2), **kw)
-            step = jax.jit(make_train_step(cfg, opt, remat="none"))
+            step = make_train_step(cfg, opt, remat="none")
             outs[name] = step(params, opt.init(params), batch, jnp.int32(0))
-        from repro.core.types import tree_paths
-        for (k, a), (_, b) in zip(tree_paths(outs["two"][0]),
-                                  tree_paths(outs["one"][0]), strict=False):
+            if name == "one":
+                outs["jit"] = jax.jit(step)(params, opt.init(params), batch,
+                                            jnp.int32(0))
+        for (k, a), (_, b), (_, c) in zip(tree_paths(outs["leaf"][0]),
+                                          tree_paths(outs["one"][0]),
+                                          tree_paths(outs["jit"][0]),
+                                          strict=True):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=k)
+            np.testing.assert_allclose(np.asarray(c), np.asarray(b),
+                                       rtol=1e-6, atol=1e-7, err_msg=k)

@@ -3,11 +3,11 @@
 Invariants under test:
   * the leaf->bucket plan groups by trailing (d_in, d_out) with leading
     scan/expert axes flattened, and gather/scatter round-trip exactly;
-  * fused updates match the per-leaf path bit-for-bit in fp32, on both the
-    XLA and the interpret-mode Pallas backends, across ragged shape mixes,
-    padding remainders, and leading axes;
+  * bucketed steps match the per-leaf references bit-for-bit in fp32, on
+    both the XLA and the interpret-mode Pallas backends, across ragged
+    shape mixes, padding remainders, and leading axes;
   * kernel launches per optimizer step equal the number of shape buckets
-    (fused) vs the number of matrix leaves (per-leaf);
+    (bucketed) vs the number of matrix leaves (per-leaf reference);
   * plan_stripes' one VMEM accounting picks a lane block of at least 128
     lanes, the launch's VMEM limit, and the kernel-or-XLA routing.
 """
@@ -17,12 +17,12 @@ import numpy as np
 import pytest
 from _hypothesis_support import given, settings, st
 
-from repro.core import apply_updates, constant, mixed_optimizer
+from repro.core import constant, make_optimizer, mixed_optimizer
 from repro.core.bucketing import build_plan, gather, init_buckets, scatter
-from repro.core.rmnp import rmnp
+from repro.core.rules import MuonRule, RmnpRule, per_leaf_reference
 from repro.kernels.rmnp_update import (APPLY_TEMPS, LANE, MAX_BLOCK_N,
-                                       PRECOND_TEMPS, VMEM_LIMIT_CAP,
-                                       plan_stripes, stripe_vmem_bytes)
+                                       VMEM_LIMIT_CAP, plan_stripes,
+                                       stripe_vmem_bytes)
 from repro.train.step import optimizer_launches
 
 # ragged mix: two shared buckets (8x16 with a scan stack, 16x8) + a loner,
@@ -74,9 +74,13 @@ class TestBucketPlan:
         assert bufs["8x16"].shape == (5, 8, 16)
         assert all(b.dtype == jnp.bfloat16 for b in bufs.values())
 
-    def test_strict_rejects_vectors(self):
-        with pytest.raises(ValueError, match="matrix leaves"):
-            build_plan({"w": jnp.ones((4, 4)), "b": jnp.ones((4,))}, strict=True)
+    def test_plan_leaves_out_vectors(self):
+        """The default predicate buckets matrices only; a vector stays out
+        of the plan and out of the gathered buckets."""
+        tree = {"w": jnp.ones((4, 4)), "b": jnp.ones((4,))}
+        plan = build_plan(tree)
+        assert [e.path for b in plan.buckets for e in b.entries] == ["w"]
+        assert set(gather(plan, tree)) == {"4x4"}
 
     def test_shape_change_detected(self):
         tree = make_tree(RAGGED_SHAPES)
@@ -118,20 +122,30 @@ class TestBucketPlan:
                                           np.asarray(tree[k]))
 
 
-def _run_pair(shapes, use_kernel, steps=3, seed=0, **kw):
-    """(per-leaf updates, fused updates) trajectories over a few steps."""
+def _run_pair(shapes, use_kernel, steps=3, seed=0):
+    """(per-leaf params, bucketed params) trajectories over a few steps,
+    op by op (jitted, XLA fuses the per-leaf and the stacked programs
+    differently — ROADMAP D1).  On XLA the reference is the per-leaf mixed
+    path, the paper's formulation written apart from the rules
+    (``row_normalize``, then the updates tree).  The interpret-mode kernel
+    rounds its row norm differently from it (within 1e-5, tests/
+    test_kernels.py), so its bit-for-bit reference launches the kernel
+    once per leaf."""
     params = make_tree(shapes, seed)
-    ref = rmnp(constant(0.1), beta=0.9, use_kernel=use_kernel, **kw)
-    fus = rmnp(constant(0.1), beta=0.9, use_kernel=use_kernel, fused=True, **kw)
+    ref = (per_leaf_reference(RmnpRule(beta=0.9), constant(0.1),
+                              use_kernel=True) if use_kernel
+           else mixed_optimizer("rmnp", constant(0.1), constant(0.1),
+                                beta=0.9))
+    fus = make_optimizer("rmnp", lr_matrix=0.1, beta=0.9,
+                         use_kernel=use_kernel, fused=True)
     sr, sf = ref.init(params), fus.init(params)
     pr, pf = params, params
     outs = []
     for step in range(steps):
         grads = make_tree(shapes, seed=seed + 100 + step)
-        ur, sr = ref.update(grads, sr, pr, step)
-        uf, sf = fus.update(grads, sf, pf, step)
-        pr, pf = apply_updates(pr, ur), apply_updates(pf, uf)
-        outs.append((ur, uf))
+        pr, sr = ref.update_apply(grads, sr, pr, step)
+        pf, sf = fus.update_apply(grads, sf, pf, step)
+        outs.append((pr, pf))
     return outs
 
 
@@ -139,55 +153,64 @@ class TestFusedMatchesPerLeaf:
     @pytest.mark.parametrize("use_kernel", [False, True],
                              ids=["xla", "pallas-interpret"])
     def test_bitwise_fp32_ragged_mix(self, use_kernel):
-        for ur, uf in _run_pair(RAGGED_SHAPES, use_kernel):
-            for k in ur:
+        for pr, pf in _run_pair(RAGGED_SHAPES, use_kernel):
+            for k in pr:
                 np.testing.assert_array_equal(
-                    np.asarray(ur[k]), np.asarray(uf[k]),
+                    np.asarray(pr[k]), np.asarray(pf[k]),
                     err_msg=f"{k} (use_kernel={use_kernel})")
 
     def test_xla_vs_kernel_allclose(self):
         """Cross-backend agreement stays a loose allclose (reduction order
         differs); the bitwise claim above is within-backend."""
-        for (ur, _), (uk, _) in zip(_run_pair(RAGGED_SHAPES, False),
+        for (pr, _), (pk, _) in zip(_run_pair(RAGGED_SHAPES, False),
                                     _run_pair(RAGGED_SHAPES, True), strict=False):
-            for k in ur:
-                np.testing.assert_allclose(np.asarray(ur[k]), np.asarray(uk[k]),
+            for k in pr:
+                np.testing.assert_allclose(np.asarray(pr[k]), np.asarray(pk[k]),
                                            atol=1e-5)
 
     def test_mixed_optimizer_fused_matches(self):
+        """The mixed bucketed optimizer against the per-leaf mixed path
+        (AdamW leaves, and matrix leaves on XLA) and against the per-leaf
+        kernel reference (matrix leaves on the kernel)."""
         shapes = dict(RAGGED_SHAPES, norm=(8,), bias=(16,))
         params = make_tree(shapes)
+        matrix = {k: params[k] for k in RAGGED_SHAPES}
         for use_kernel in (False, True):
-            ref = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
-                                  use_kernel=use_kernel)
+            leaf = mixed_optimizer("rmnp", constant(0.1), constant(0.05))
+            ref = per_leaf_reference(RmnpRule(), constant(0.1),
+                                     use_kernel=use_kernel)
             fus = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
                                   use_kernel=use_kernel, fused=True)
-            sr, sf = ref.init(params), fus.init(params)
-            pr, pf = params, params
+            sl, sr, sf = leaf.init(params), ref.init(matrix), fus.init(params)
+            pl_, pr, pf = params, matrix, params
             for step in range(3):
                 grads = make_tree(shapes, seed=7 + step)
-                ur, sr = ref.update(grads, sr, pr, step)
-                uf, sf = fus.update(grads, sf, pf, step)
+                pl_, sl = leaf.update_apply(grads, sl, pl_, step)
+                pr, sr = ref.update_apply({k: grads[k] for k in matrix}, sr,
+                                          pr, step)
+                pf, sf = fus.update_apply(grads, sf, pf, step)
                 for k in params:
+                    want = (pr[k] if use_kernel and k in matrix
+                            else pl_[k])
                     np.testing.assert_array_equal(
-                        np.asarray(ur[k]), np.asarray(uf[k]), err_msg=k)
-                pr, pf = apply_updates(pr, ur), apply_updates(pf, uf)
+                        np.asarray(want), np.asarray(pf[k]), err_msg=k)
 
     def test_bf16_momentum_storage(self):
         params = make_tree(RAGGED_SHAPES)
-        opt = rmnp(constant(0.1), fused=True, momentum_dtype="bfloat16")
+        opt = make_optimizer("rmnp", lr_matrix=0.1, fused=True,
+                             momentum_dtype="bfloat16")
         state = opt.init(params)
         assert all(b.dtype == jnp.bfloat16 for b in state.buckets.values())
         grads = make_tree(RAGGED_SHAPES, seed=5)
-        upd, state = opt.update(grads, state, params, 0)
+        new, state = opt.update_apply(grads, state, params, 0)
         assert all(b.dtype == jnp.bfloat16 for b in state.buckets.values())
         # math is fp32: vs the fp32-state path the only error is bf16 storage
-        ref = rmnp(constant(0.1), fused=True)
+        ref = make_optimizer("rmnp", lr_matrix=0.1, fused=True)
         sref = ref.init(params)
-        uref, _ = ref.update(grads, sref, params, 0)
+        new_ref, _ = ref.update_apply(grads, sref, params, 0)
         for k in params:
-            np.testing.assert_allclose(np.asarray(upd[k]), np.asarray(uref[k]),
-                                       atol=1e-5)
+            np.testing.assert_allclose(np.asarray(new[k]),
+                                       np.asarray(new_ref[k]), atol=1e-5)
 
     @given(st.lists(st.tuples(st.integers(2, 24), st.integers(2, 24),
                               st.integers(0, 3)),
@@ -198,11 +221,11 @@ class TestFusedMatchesPerLeaf:
         shapes = {}
         for i, (d_in, d_out, lead) in enumerate(dims):
             shapes[f"p{i}/w"] = (lead, d_in, d_out) if lead else (d_in, d_out)
-        for ur, uf in _run_pair(shapes, use_kernel, steps=2,
+        for pr, pf in _run_pair(shapes, use_kernel, steps=2,
                                 seed=sum(d_in for d_in, _, _ in dims)):
-            for k in ur:
-                np.testing.assert_array_equal(np.asarray(ur[k]),
-                                              np.asarray(uf[k]), err_msg=k)
+            for k in pr:
+                np.testing.assert_array_equal(np.asarray(pr[k]),
+                                              np.asarray(pf[k]), err_msg=k)
 
 
 class TestLaunchCounts:
@@ -210,8 +233,9 @@ class TestLaunchCounts:
         params = make_tree(RAGGED_SHAPES)
         n_buckets = len(build_plan(params).buckets)
         n_leaves = len(params)
-        fused = rmnp(constant(0.1), use_kernel=True, fused=True)
-        leaf = rmnp(constant(0.1), use_kernel=True)
+        fused = make_optimizer("rmnp", lr_matrix=0.1, use_kernel=True,
+                               fused=True)
+        leaf = per_leaf_reference(RmnpRule(), constant(0.1), use_kernel=True)
         assert optimizer_launches(fused, params) == n_buckets == 3
         assert optimizer_launches(leaf, params) == n_leaves == 5
 
@@ -220,13 +244,16 @@ class TestLaunchCounts:
         params = make_tree(shapes)
         fused = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
                                 use_kernel=True, fused=True)
-        leaf = mixed_optimizer("rmnp", constant(0.1), constant(0.05),
-                               use_kernel=True)
+        leaf = mixed_optimizer("rmnp", constant(0.1), constant(0.05))
         assert optimizer_launches(fused, params) == 3   # buckets, not leaves
-        assert optimizer_launches(leaf, params) == 5    # matrix leaves only
+        assert optimizer_launches(leaf, params) == 0    # plain jnp reference
         assert optimizer_launches(
             mixed_optimizer("rmnp", constant(0.1), constant(0.05), fused=True),
             params) == 0                                # XLA fallback: no pallas
+        # the per-leaf path runs no kernel, so asking it for one is an error
+        with pytest.raises(ValueError, match="fused=True"):
+            mixed_optimizer("rmnp", constant(0.1), constant(0.05),
+                            use_kernel=True)
 
     def test_muon_fused_batches_ns_over_buckets(self):
         """Fused Muon batches Newton-Schulz over each bucket's stacked L
@@ -237,11 +264,11 @@ class TestLaunchCounts:
         params = make_tree(shapes)
         fused = mixed_optimizer("muon", constant(0.1), constant(0.05),
                                 use_kernel=True, fused=True, ns_steps=2)
-        leaf = mixed_optimizer("muon", constant(0.1), constant(0.05),
-                               use_kernel=True, ns_steps=2)
+        leaf = per_leaf_reference(MuonRule(ns_steps=2), constant(0.1),
+                                  use_kernel=True)
         # RAGGED_SHAPES: 5 matrix leaves in 3 shape buckets
         assert optimizer_launches(fused, params) == 4 * 2 * 3
-        assert optimizer_launches(leaf, params) == 4 * 2 * 5
+        assert optimizer_launches(leaf, make_tree(RAGGED_SHAPES)) == 4 * 2 * 5
 
 
 class TestPickBlockN:
@@ -250,7 +277,9 @@ class TestPickBlockN:
     fp32 temporaries.  Blocks never drop below 128 lanes; a stripe that
     cannot fit the scoped-VMEM cap even at 128 lanes is routed to XLA."""
 
-    PRECOND = ([4, 4, 4, 4], PRECOND_TEMPS)      # g, v in; v_new, d out
+    # a stripe kernel with two fp32 blocks in, two out and one fp32 body
+    # temporary (a momentum-and-direction kernel), beside the apply kernel
+    PRECOND = ([4, 4, 4, 4], 1)
     APPLY = ([4, 4, 4, 4, 4], APPLY_TEMPS)       # g, v, w in; v_new, w_new
 
     def _plan(self, d_in, n, kind=PRECOND):
@@ -300,10 +329,10 @@ class TestPickBlockN:
     @pytest.mark.parametrize("d_in,n", [(64, 1024), (1024, 4096),
                                         (8192, 512), (32768, 4096)])
     def test_stripe_count_parameterizes_budget(self, d_in, n):
-        """The fused-apply kernel holds more VMEM per lane (a third input
-        and output block, one more fp32 temporary) than the precondition-
-        only kernel, so its block is never wider and it is routed to XLA
-        no later."""
+        """The apply kernel holds more VMEM per lane (a third input and
+        output block, one more fp32 temporary) than a two-in two-out
+        stripe, so its block is never wider and it is routed to XLA no
+        later."""
         pre = self._plan(d_in, n, self.PRECOND)
         app = self._plan(d_in, n, self.APPLY)
         if pre is None:
@@ -314,8 +343,8 @@ class TestPickBlockN:
                                                        *self.APPLY)
 
     def test_stripe_budget_shrinks_block(self):
-        # at a 14336 fan-in the precondition-only stripe still fits the
-        # cap at 128 lanes but the apply kernel's extra residency does not:
+        # at a 14336 fan-in the two-in two-out stripe still fits the cap
+        # at 128 lanes but the apply kernel's extra residency does not:
         # the routing rule sends the apply launch to XLA first
         assert self._plan(14336, 4096, self.PRECOND).block_n == LANE
         assert self._plan(14336, 4096, self.APPLY) is None
@@ -339,8 +368,8 @@ class TestDominanceParity:
         ref = mixed_optimizer("rmnp", constant(0.1), constant(0.05))
         fus = mixed_optimizer("rmnp", constant(0.1), constant(0.05), fused=True)
         sr, sf = ref.init(params), fus.init(params)
-        _, sr = ref.update(grads, sr, params, 0)
-        _, sf = fus.update(grads, sf, params, 0)
+        _, sr = ref.update_apply(grads, sr, params, 0)
+        _, sf = fus.update_apply(grads, sf, params, 0)
         dom_r = global_dominance(momentum_for_diagnostics(sr, params))
         dom_f = global_dominance(momentum_for_diagnostics(sf, params))
         for k in dom_r:

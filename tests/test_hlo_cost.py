@@ -265,15 +265,15 @@ def test_overlap_report_on_real_sharded_update():
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.core import constant
+    from repro.core import make_optimizer
     from repro.core.bucketing import gather_chunks
-    from repro.core.rmnp import rmnp
     from repro.distributed.compression import exact_reduce_scatter
     from repro.launch.hlo_cost import collective_overlap_report
     from repro.launch.mesh import make_data_mesh
 
     mesh = make_data_mesh(1)
-    opt = rmnp(constant(0.1), beta=0.9, shard_axis="data", shard_size=1)
+    opt = make_optimizer("rmnp", lr_matrix=0.1, beta=0.9,
+                         shard_axis="data", shard_size=1)
     params = {"a/w": jnp.ones((4, 8, 16), jnp.float32),
               "b/w": jnp.ones((2, 8, 24), jnp.float32)}
     grads = {k: jnp.full_like(v, 0.5) for k, v in params.items()}

@@ -8,83 +8,95 @@ from _hypothesis_support import given, settings, st
 
 from repro.kernels import ops
 from repro.kernels.matmul import matmul as pallas_matmul
-from repro.kernels.ref import (
-    matmul_ref, ns_step_ref, rmnp_momentum_rownorm_ref,
-)
-from repro.kernels.rmnp_update import rmnp_momentum_rownorm_2d
+from repro.kernels.ref import matmul_ref, ns_step_ref, rmnp_rownorm_apply_ref
+from repro.kernels.rmnp_update import rmnp_rownorm_apply_2d
 
 _NS = (3.4445, -4.7750, 2.0315)
 
 
+def _gvw(shape, seed):
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(k1, shape), jax.random.normal(k2, shape),
+            jax.random.normal(k3, shape))
+
+
 class TestRmnpKernel:
+    """The single-pass apply kernel against its jnp reference: momentum
+    EMA, row normalize and the weight update in one pass.  At scale 1 and
+    no weight decay ``w_new = w - d``, so the weights carry the direction
+    at its own size and the 1e-5 bound holds ``d`` itself; the cells'
+    small scale and weight decay are checked on the same operands."""
+
+    @staticmethod
+    def _check(g, v, w, beta, apply=None):
+        apply = apply or (lambda g, v, w, scale, wd: ops.rmnp_bucket_update_apply(
+            g, v, w, scale, wd, beta=beta))
+        for scale, wd in ((1.0, 0.0), (0.01, 0.1)):
+            vn, wn = apply(g, v, w, scale, wd)
+            vr, wr = rmnp_rownorm_apply_ref(g, v, w, scale, wd, beta=beta)
+            np.testing.assert_allclose(np.array(vn), np.array(vr), atol=1e-5)
+            np.testing.assert_allclose(np.array(wn), np.array(wr), atol=1e-5,
+                                       err_msg=f"scale={scale} wd={wd}")
+
     @pytest.mark.parametrize("shape", [(8, 8), (64, 128), (128, 64),
                                        (300, 257), (1024, 96), (33, 9)])
     def test_matches_ref(self, shape):
-        k1, k2 = jax.random.split(jax.random.PRNGKey(shape[0] * shape[1]))
-        g = jax.random.normal(k1, shape)
-        v = jax.random.normal(k2, shape)
-        vn, d = rmnp_momentum_rownorm_2d(g, v, beta=0.95, interpret=True)
-        vr, dr = rmnp_momentum_rownorm_ref(g, v, beta=0.95)
-        np.testing.assert_allclose(np.array(vn), np.array(vr), atol=1e-5)
-        np.testing.assert_allclose(np.array(d), np.array(dr), atol=1e-5)
+        g, v, w = _gvw(shape, shape[0] * shape[1])
+
+        def apply(g, v, w, scale, wd):
+            sc = jnp.array([scale, wd], jnp.float32)
+            return rmnp_rownorm_apply_2d(g, v, w, sc, beta=0.95,
+                                         interpret=True)
+        self._check(g, v, w, 0.95, apply)
 
     @given(st.integers(2, 200), st.integers(2, 200),
            st.sampled_from([0.0, 0.5, 0.9, 0.95, 0.99]))
     @settings(max_examples=12, deadline=None)
     def test_property_sweep(self, m, n, beta):
-        k1, k2 = jax.random.split(jax.random.PRNGKey(m * 211 + n))
-        g = jax.random.normal(k1, (m, n))
-        v = jax.random.normal(k2, (m, n))
-        vn, d = ops.rmnp_momentum_rownorm(g, v, beta=beta)
-        vr, dr = rmnp_momentum_rownorm_ref(g, v, beta=beta)
-        np.testing.assert_allclose(np.array(vn), np.array(vr), atol=1e-5)
-        np.testing.assert_allclose(np.array(d), np.array(dr), atol=1e-5)
+        self._check(*_gvw((m, n), m * 211 + n), beta)
 
     def test_batched_stack(self):
         g = jax.random.normal(jax.random.PRNGKey(0), (4, 32, 48))
         v = jnp.zeros((4, 32, 48))
-        vn, d = ops.rmnp_momentum_rownorm(g, v, beta=0.9)
-        vr, dr = rmnp_momentum_rownorm_ref(g, v, beta=0.9)
-        np.testing.assert_allclose(np.array(d), np.array(dr), atol=1e-5)
+        w = jax.random.normal(jax.random.PRNGKey(1), (4, 32, 48))
+        self._check(g, v, w, 0.9)
 
     def test_output_columns_unit_norm(self):
+        """At scale 1 and no weight decay the step is the direction: each
+        column of ``w - w_new`` is a unit-norm row-normalized momentum."""
         g = jax.random.normal(jax.random.PRNGKey(0), (128, 256))
         v = jax.random.normal(jax.random.PRNGKey(1), (128, 256))
-        _, d = ops.rmnp_momentum_rownorm(g, v, beta=0.5)
+        w = jax.random.normal(jax.random.PRNGKey(2), (128, 256))
+        _, wn = ops.rmnp_bucket_update_apply(g, v, w, 1.0, 0.0, beta=0.5)
         np.testing.assert_allclose(
-            np.linalg.norm(np.array(d), axis=0), 1.0, atol=1e-4)
+            np.linalg.norm(np.array(w - wn), axis=0), 1.0, atol=1e-4)
 
 
 class TestRmnpKernelAliasing:
-    """The stripe kernels write v_new (and w_new) over their v (and w)
-    operands.  The values are those of the jitted reference, bit for bit;
-    a caller that does not donate keeps its arrays, one that donates gets
-    the same values back."""
+    """The apply kernel writes v_new and w_new over its v and w operands.
+    The values are those of the jitted reference, bit for bit; a caller
+    that does not donate keeps its arrays, one that donates gets the same
+    values back.  Weights in bf16 (the cells' mixed precision) and fp32."""
 
     @staticmethod
-    def _kernel_and_ref(kernel):
-        from repro.kernels import rmnp_update as rm
-        from repro.kernels.ref import rmnp_rownorm_apply_ref
-        if kernel == "apply":
-            return (lambda g, v, w, sc: rm.rmnp_rownorm_apply_2d(
-                        g, v, w, sc, beta=0.95, interpret=True),
-                    lambda g, v, w, sc: rmnp_rownorm_apply_ref(
-                        g, v, w, sc[0], sc[1], beta=0.95))
-        return (lambda g, v, w, sc: rm.rmnp_momentum_rownorm_2d(
-                    g, v, beta=0.95, interpret=True),
-                lambda g, v, w, sc: rmnp_momentum_rownorm_ref(
-                    g, v, beta=0.95))
+    def fn(g, v, w, sc):
+        return rmnp_rownorm_apply_2d(g, v, w, sc, beta=0.95, interpret=True)
 
-    @pytest.mark.parametrize("kernel", ["apply", "precondition"])
+    @staticmethod
+    def ref(g, v, w, sc):
+        return rmnp_rownorm_apply_ref(g, v, w, sc[0], sc[1], beta=0.95)
+
+    @pytest.mark.parametrize("wdtype", [jnp.bfloat16, jnp.float32],
+                             ids=["bf16w", "fp32w"])
     @pytest.mark.parametrize("d_out", [256, 200], ids=["unpadded", "padded"])
     @pytest.mark.parametrize("mdtype", [jnp.float32, jnp.bfloat16],
                              ids=["fp32", "bf16"])
-    def test_aliased_state(self, kernel, d_out, mdtype):
-        fn, ref = self._kernel_and_ref(kernel)
+    def test_aliased_state(self, wdtype, d_out, mdtype):
+        fn, ref = self.fn, self.ref
         k1, k2, k3 = jax.random.split(jax.random.PRNGKey(d_out), 3)
         g = jax.random.normal(k1, (2, 64, d_out))
         v = jax.random.normal(k2, (2, 64, d_out)).astype(mdtype)
-        w = jax.random.normal(k3, (2, 64, d_out)).astype(jnp.bfloat16)
+        w = jax.random.normal(k3, (2, 64, d_out)).astype(wdtype)
         sc = jnp.array([0.01, 0.1], jnp.float32)
         before = [np.array(x) for x in (g, v, w)]
         want = [np.array(x) for x in jax.jit(ref)(g, v, w, sc)]
@@ -94,8 +106,7 @@ class TestRmnpKernelAliasing:
             np.testing.assert_array_equal(a, b)
         for a, b in zip((g, v, w), before, strict=True):
             np.testing.assert_array_equal(np.array(a), b)
-        donate = (1, 2) if kernel == "apply" else (1,)
-        donated = jax.jit(fn, donate_argnums=donate)(
+        donated = jax.jit(fn, donate_argnums=(1, 2))(
             g, jnp.array(v, copy=True), jnp.array(w, copy=True), sc)
         for a, b in zip(donated, want, strict=True):
             np.testing.assert_array_equal(np.array(a), b)
@@ -150,14 +161,17 @@ class TestNewtonSchulzKernel:
 
 class TestOptimizerKernelPath:
     def test_mixed_rmnp_kernel_equals_jnp_path(self):
+        """The bucketed kernel path against the per-leaf jnp path."""
         from repro.core import constant, mixed_optimizer
         params = {"w": jax.random.normal(jax.random.PRNGKey(0), (32, 64))}
         grads = {"w": jax.random.normal(jax.random.PRNGKey(1), (32, 64))}
         o1 = mixed_optimizer("rmnp", constant(0.1), constant(0.1))
-        o2 = mixed_optimizer("rmnp", constant(0.1), constant(0.1), use_kernel=True)
-        u1, _ = o1.update(grads, o1.init(params), params, 0)
-        u2, _ = o2.update(grads, o2.init(params), params, 0)
-        np.testing.assert_allclose(np.array(u1["w"]), np.array(u2["w"]), atol=1e-5)
+        o2 = mixed_optimizer("rmnp", constant(0.1), constant(0.1),
+                             use_kernel=True, fused=True)
+        p1, _ = o1.update_apply(grads, o1.init(params), params, 0)
+        p2, _ = o2.update_apply(grads, o2.init(params), params, 0)
+        np.testing.assert_allclose(np.array(p1["w"]), np.array(p2["w"]),
+                                   atol=1e-5)
 
 
 class TestFlashAttentionKernel:

@@ -8,7 +8,7 @@ from _hypothesis_support import given, settings, st
 from repro.core import (
     adamw, apply_updates, clip_by_global_norm, constant, cosine_with_warmup,
     dominance_ratios, global_dominance, is_matrix_param, mixed_optimizer,
-    muon, newton_schulz, rmnp, rms_lr_scale, row_normalize,
+    newton_schulz, rms_lr_scale, row_normalize,
 )
 
 

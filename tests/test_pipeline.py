@@ -11,7 +11,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
-from repro.core import constant, mixed_optimizer
+from repro.core import constant, make_optimizer, mixed_optimizer
 from repro.core.bucketing import (
     accumulate_chunks, build_plan, gather_chunks, init_chunk_acc,
 )
@@ -114,7 +114,6 @@ class TestTwoPhaseClip:
         """On a 1-way axis every leaf is rank-contained, so gnorm and scale
         are bit-for-bit clip_by_global_norm's — with the clip active."""
         from repro.core.mixed import clip_by_global_norm
-        from repro.core.rmnp import rmnp
         from repro.distributed.compression import exact_reduce_scatter
         from repro.train.pipeline import two_phase_clip
 
@@ -122,7 +121,8 @@ class TestTwoPhaseClip:
         shapes = {"a/w": (2, 8, 16), "b/w": (8, 16), "c/w": (3, 8, 24)}
         grads = _tree(shapes, seed=2)
         grads["norm_1d"] = jax.random.normal(jax.random.PRNGKey(7), (11,))
-        opt = rmnp(constant(0.1), shard_axis="data", shard_size=1)
+        opt = make_optimizer("rmnp", lr_matrix=0.1, shard_axis="data",
+                             shard_size=1)
         plan = opt.bucket_plan({k: v for k, v in grads.items()
                                 if v.ndim >= 2})
 
@@ -218,11 +218,11 @@ class TestUpdateApplyBucketContract:
         update_apply_sharded with the same clip_scale — the loop form and
         the per-bucket form cannot drift apart."""
         from repro.core.bucketing import scatter
-        from repro.core.rmnp import rmnp
         from repro.distributed.compression import exact_reduce_scatter
 
         mesh = make_data_mesh(1)
-        opt = rmnp(constant(0.1), beta=0.9, shard_axis="data", shard_size=1)
+        opt = make_optimizer("rmnp", lr_matrix=0.1, beta=0.9,
+                             shard_axis="data", shard_size=1)
         shapes = {"a/w": (2, 8, 16), "b/w": (8, 16), "c/w": (3, 8, 24)}
         params = _tree(shapes, seed=0)
         grads = _tree(shapes, seed=1)

@@ -198,7 +198,7 @@ class TestSingleDeviceGuard:
         cfg = get_config("gpt2-60m").reduced()
         params = init_params(cfg, jax.random.PRNGKey(0))
         opt = mixed_optimizer("rmnp", constant(1e-2), constant(1e-2),
-                              fused_apply=True)
+                              fused=True)
         toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                   cfg.vocab)
         batch = {"tokens": toks, "labels": toks}

@@ -9,13 +9,14 @@ Invariants under test:
   * every registered rule run through the bucketed engine — uneven and
     padded buckets included — matches its per-leaf reference optimizer
     bitwise over multiple steps (slots and bias corrections stepping);
-  * every registered rule's single-pass ``update_apply`` equals the
-    two-pass ``update`` + ``apply_updates`` — bitwise for additive rules,
-    allclose for Muown's multiplicative norm control (its two-pass form
-    re-associates the final add);
-  * the uniform ``BucketedState`` layout (momentum buckets + slot stripes)
-    round-trips through the checkpoint manager for every rule, and the
-    mixed four-field state does too.
+  * every registered rule's engine ``update_apply`` agrees with its
+    per-leaf reference's ``update`` + ``apply_updates`` — momentum and slot
+    stripes bitwise, params to FMA-contraction level (allclose for Muown's
+    multiplicative norm control, whose updates-tree form re-associates the
+    final add);
+  * the bucketed state layout (momentum buckets + slot stripes)
+    round-trips through the checkpoint manager for every rule, AdamW
+    leaves included.
 
 The 4-device ZeRO-2 equivalences for the same family run in
 tests/_zero_shard_worker.py.
@@ -26,8 +27,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint.manager import CheckpointManager
-from repro.core import apply_updates, constant
-from repro.core.engine import matrix_optimizer
+from repro.core import apply_updates, constant, make_optimizer
 from repro.core.muon import newton_schulz
 from repro.core.rules import make_rule, per_leaf_reference, rule_names
 from repro.core.types import tree_paths
@@ -36,6 +36,20 @@ from repro.core.types import tree_paths
 # single matrix on the transpose (d_in > d_out) Newton-Schulz path
 SHAPES = {"a/w": (2, 8, 16), "b/w": (8, 16), "c/w": (3, 8, 24),
           "d/w": (16, 8)}
+
+
+def _engine(name, **kw):
+    """The bucketed engine for rule ``name`` through the registry."""
+    return make_optimizer(name, dict(lr_matrix=0.1, beta=0.9, ns_steps=2,
+                                     fused=True, **kw))
+
+
+def _stacked(plan, tree):
+    """The per-leaf reference's leaf-shaped state (momentum or slot
+    stripes) stacked in the plan's bucket layout."""
+    return {b.key: jnp.concatenate(
+        [tree[e.path].reshape((e.lead,) + tree[e.path].shape[-2:])
+         for e in b.entries]) for b in plan.buckets}
 
 
 def _tree(shapes, seed=0, dtype=jnp.float32):
@@ -95,9 +109,9 @@ class TestEngineMatchesPerLeafReference:
     def test_bitwise_two_steps(self, name, pad):
         rule = make_rule(name, beta=0.9, ns_steps=2)
         # shard_size pads the buckets without sharding them (the momentum
-        # stays full, so no mesh axis is needed): pad slices must be inert
-        eng = matrix_optimizer(rule, constant(0.1), fused_apply=True,
-                               shard_size=pad)
+        # stays full, so the mesh axis is never consulted): pad slices must
+        # be inert
+        eng = _engine(name, shard_axis="data", shard_size=pad)
         ref = per_leaf_reference(rule, constant(0.1))
         params = _tree(SHAPES, seed=0)
         pe, se = params, eng.init(params)
@@ -126,8 +140,7 @@ class TestEngineMatchesPerLeafReference:
         """The batched multi-launch NS transform (kernels path) over uneven
         buckets equals the per-leaf kernel reference bitwise."""
         rule = make_rule("muon", beta=0.9, ns_steps=2)
-        eng = matrix_optimizer(rule, constant(0.1), fused_apply=True,
-                               use_kernel=True)
+        eng = _engine("muon", use_kernel=True)
         ref = per_leaf_reference(rule, constant(0.1), use_kernel=True)
         params = _tree(SHAPES, seed=3)
         grads = _tree(SHAPES, seed=4)
@@ -141,50 +154,69 @@ class TestEngineMatchesPerLeafReference:
 
 
 class TestUpdateApplyConsistency:
-    """Property: for every registered rule the fused single-pass
-    ``update_apply`` and the two-pass ``update`` + ``apply_updates`` agree.
-    Momentum and slot stripes are bitwise (identical expressions).  Params
-    of additive rules share the canonical op order, but the two jitted
-    programs fuse the preconditioner chain into its consumers differently
-    (LLVM FMA contraction), so the end-to-end guarantee across separately
-    jitted programs is FMA-contraction-tight (atol 1e-7), not bitwise.
-    The non-additive Muown re-associates the final add in its two-pass
-    form and gets the looser tolerance."""
+    """Property: for every registered rule the engine's single-pass
+    ``update_apply`` agrees with a per-leaf updates tree (``update`` +
+    ``apply_updates``).  For RMNP and Muon the reference is the per-leaf
+    mixed path, written apart from the rules (``row_normalize``,
+    ``newton_schulz``); the bucketed-only rules take
+    ``per_leaf_reference``.  Both sides jitted — the jit boundary is where
+    they run in production — params agree to FMA-contraction level (atol
+    1e-7): the two programs fuse the chain into its consumers differently
+    (LLVM FMA contraction), so not bitwise.  Muown's multiplicative norm
+    control re-associates the final add in the updates-tree form and gets
+    the looser tolerance.  Momentum and slot stripes are identical
+    expressions and bitwise op by op; jitted, the per-leaf and the stacked
+    programs may contract the momentum EMA's multiply-add differently
+    (ROADMAP D1), so they are compared on the op-by-op run."""
 
     @pytest.mark.parametrize("name", rule_names())
     def test_two_pass_matches_fused(self, name):
-        rule = make_rule(name, beta=0.9, ns_steps=2)
-        opt = matrix_optimizer(rule, constant(0.1), fused_apply=True)
+        from repro.core.mixed import mixed_optimizer
 
-        @jax.jit
+        opt = _engine(name)
+        if name in ("rmnp", "muon"):
+            ref = mixed_optimizer(name, constant(0.1), constant(0.1),
+                                  beta=0.9, ns_steps=2)
+        else:
+            ref = per_leaf_reference(make_rule(name, beta=0.9, ns_steps=2),
+                                     constant(0.1))
+
         def two_pass(g, s, p, step):
-            u, s2 = opt.update(g, s, p, step)
+            u, s2 = ref.update(g, s, p, step)
             return apply_updates(p, u), s2
 
         params = _tree(SHAPES, seed=5)
-        p1, s1 = params, opt.init(params)
-        p2, s2 = params, opt.init(params)
-        for step in range(2):
-            grads = _tree(SHAPES, seed=20 + step)
-            p1, s1 = jax.jit(opt.update_apply)(grads, s1, p1,
-                                               jnp.int32(step))
-            p2, s2 = two_pass(grads, s2, p2, jnp.int32(step))
-            for k in params:
-                tol = (dict(rtol=1e-6, atol=1e-6) if not rule.additive
-                       else dict(rtol=1e-6, atol=1e-7))
-                np.testing.assert_allclose(
-                    np.asarray(p1[k]), np.asarray(p2[k]), **tol,
-                    err_msg=f"{name} step {step}: {k}")
-            for bk in s1.buckets:
-                np.testing.assert_array_equal(
-                    np.asarray(s1.buckets[bk]), np.asarray(s2.buckets[bk]),
-                    err_msg=f"{name} momentum {bk}")
-            for slot in s1.slots:
-                for bk in s1.slots[slot]:
+        plan = opt.bucket_plan(params)
+        tol = (dict(rtol=1e-6, atol=1e-6) if name == "muown"
+               else dict(rtol=1e-6, atol=1e-7))
+        for jit in (True, False):
+            wrap = jax.jit if jit else (lambda f: f)
+            one, two = wrap(opt.update_apply), wrap(two_pass)
+            p1, s1 = params, opt.init(params)
+            p2, s2 = params, ref.init(params)
+            for step in range(2):
+                grads = _tree(SHAPES, seed=20 + step)
+                p1, s1 = one(grads, s1, p1, jnp.int32(step))
+                p2, s2 = two(grads, s2, p2, jnp.int32(step))
+                for k in params:
+                    np.testing.assert_allclose(
+                        np.asarray(p1[k]), np.asarray(p2[k]), **tol,
+                        err_msg=f"{name} step {step} jit={jit}: {k}")
+                if jit:
+                    continue
+                ref_buckets = _stacked(plan, s2.momentum)
+                for bk in s1.buckets:
                     np.testing.assert_array_equal(
-                        np.asarray(s1.slots[slot][bk]),
-                        np.asarray(s2.slots[slot][bk]),
-                        err_msg=f"{name} slot {slot}/{bk}")
+                        np.asarray(s1.buckets[bk]),
+                        np.asarray(ref_buckets[bk]),
+                        err_msg=f"{name} momentum {bk}")
+                for slot in s1.slots:
+                    ref_slots = _stacked(plan, s2.slots[slot])
+                    for bk in s1.slots[slot]:
+                        np.testing.assert_array_equal(
+                            np.asarray(s1.slots[slot][bk]),
+                            np.asarray(ref_slots[bk]),
+                            err_msg=f"{name} slot {slot}/{bk}")
 
 
 class TestStateCheckpointRoundTrip:
@@ -194,8 +226,7 @@ class TestStateCheckpointRoundTrip:
 
     @pytest.mark.parametrize("name", rule_names())
     def test_bucketed_state_roundtrip(self, name, tmp_path):
-        rule = make_rule(name, beta=0.9, ns_steps=2)
-        opt = matrix_optimizer(rule, constant(0.1), fused_apply=True)
+        opt = _engine(name)
         params = _tree(SHAPES, seed=6)
         _, state = opt.update_apply(_tree(SHAPES, seed=7), opt.init(params),
                                     params, jnp.int32(0))
@@ -219,7 +250,7 @@ class TestStateCheckpointRoundTrip:
         shapes = dict(SHAPES, norm=(8,), bias=(16,))
         params = _tree(shapes, seed=8)
         opt = mixed_optimizer("normuon", constant(0.1), constant(0.05),
-                              fused_apply=True, ns_steps=2)
+                              fused=True, ns_steps=2)
         _, state = opt.update_apply(_tree(shapes, seed=9), opt.init(params),
                                     params, jnp.int32(0))
         assert state.slots["nu"], "normuon must carry nu stripes"
